@@ -261,7 +261,7 @@ def kernel_bench():
         x = jnp.asarray(rng.standard_normal((8, 512)), jnp.bfloat16)
         qw = quantize_weights(get_scheme(scheme_name), w)
         t0 = time.perf_counter()
-        out_k = packed_matmul(x, qw, bm=8, bn=128, bk=256, interpret=True)
+        out_k = packed_matmul(x, qw, bm=8, bn=128, bk=256)
         out_k.block_until_ready()
         t_k = (time.perf_counter() - t0) * 1e6
         out_r = ref.packed_matmul_ref(x, qw)

@@ -178,7 +178,8 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 # (repro.launch.cli is deliberately jax-free at module level).
 import numpy as np
 
-from repro.launch.cli import force_host_devices, serving_mesh
+from repro.launch.cli import (enable_compile_cache, force_host_devices,
+                              serving_mesh)
 
 
 def parse_priority_mix(spec):
@@ -788,6 +789,7 @@ def main():
 
     force_host_devices(args.force_host_devices)
     import jax
+    enable_compile_cache()
     from repro.configs import get_config
     from repro.models.common import QuantMaker
     from repro.models import transformer as T

@@ -52,7 +52,7 @@ from repro.quant.schemes import get_scheme, quantize_weights
 w = rng.standard_normal((256, 128)).astype(np.float32) * 0.1
 qw = quantize_weights(get_scheme("awq_int4"), w)
 x = jnp.asarray(rng.standard_normal((4, 256)), jnp.bfloat16)
-out_kernel = packed_matmul(x, qw, bm=4, bn=128, bk=256, interpret=True)
+out_kernel = packed_matmul(x, qw, bm=4, bn=128, bk=256)
 out_ref = packed_matmul_ref(x, qw)
 err = float(jnp.max(jnp.abs(out_kernel - out_ref)))
 print(f"\npacked GEMV kernel vs oracle: max abs err {err:.2e}  "

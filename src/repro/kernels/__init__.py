@@ -1,3 +1,18 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
+"""Pallas TPU kernels of the serving hot path, their jnp oracles
+(``ref.py``) and the dispatch the model layer calls (``ops.py``)."""
+from __future__ import annotations
+
+
+def resolve_interpret(interpret=None) -> bool:
+    """Whether a Pallas kernel runs in interpret mode — the one place that
+    decides it.  ``None`` follows the platform: interpret on a backend that
+    is not a TPU (the CPU test backend), compiled on a TPU.  An explicit
+    ``False`` compiles for the TPU (the cross-compile tests); interpret
+    mode is refused on a TPU."""
+    import jax
+    on_tpu = jax.default_backend() == "tpu"
+    if interpret is None:
+        return not on_tpu
+    if interpret and on_tpu:
+        raise ValueError("Pallas interpret mode never runs on a TPU")
+    return bool(interpret)

@@ -6,15 +6,21 @@ score einsum, a softmax and a value einsum per layer per token — and, with
 a quantized pool (DESIGN.md §9), additionally materializes a dequantized
 [B, S, H, D] copy of the cache.  This kernel fuses the whole thing:
 
-  grid (B, Hk, Sk/bk) — one program per (slot row, KV-head group, KV block)
-  * stream one packed KV block [bk, D/4] int32 + scales [bk] (or a bf16
-    block) from the pool slab into VMEM,
-  * dequantize in-kernel — arithmetic shift/mask decode with DAZ +
-    implicit-one restore (XtraMAC Stage-1 semantics; no gathers, the same
-    decode ``packed_matmul`` uses for weights),
-  * one split-KV online-softmax update (running max / normalizer / f32
-    accumulator) — the flash-decode recurrence over the block grid axis,
-  * final block normalizes and writes the [rep, D] output tile.
+  grid (B, Sk/bk) — one program per (slot row, KV block)
+  * stream one KV block of ALL the slot's KV heads — packed
+    [bk, Hk, D/4] int32 + scales [bk, Hk] (or bf16 [bk, Hk, D]) — from the
+    pool slab into VMEM,
+  * per KV head: dequantize in-kernel — arithmetic shift/mask decode with
+    DAZ + implicit-one restore (XtraMAC Stage-1 semantics; no gathers, the
+    same decode ``packed_matmul`` uses for weights) — and run one split-KV
+    online-softmax update (running max / normalizer / f32 accumulator), the
+    flash-decode recurrence over the block grid axis,
+  * final block normalizes and writes the [Hk, rep, D] output tile.
+
+Block shapes follow the TPU tiling rule (the last two block dims divisible
+by (8, 128) or equal to the array's): KV blocks span the whole
+(Hk, D) tail, scales the whole Hk tail, and the per-slot valid lengths sit
+in SMEM, read by program id.
 
 Numerics are f32 end-to-end after the bf16 loads: strictly more accurate
 than the einsum path (which rounds scores and probabilities through bf16
@@ -26,8 +32,8 @@ path, which agrees to bf16 rounding tolerance (DESIGN.md §9).
 The running (m, l) carries live in two small revisited output tiles rather
 than scratch, matching ``packed_matmul``'s revisiting-accumulate pattern
 (TPU grids iterate the last axis innermost, so all Sk blocks of one
-(B, Hk) pair run consecutively).  Validated under interpret=True on CPU;
-the TPU-target path is the same kernel compiled.
+slot row run consecutively).  Validated under interpret mode on CPU and
+compiled for a v5e in ``tests/test_tpu_compile.py``.
 """
 from __future__ import annotations
 
@@ -37,7 +43,9 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels import resolve_interpret
 from repro.quant.kv_cache import QuantizedKV
 from repro.quant.schemes import kv_unpack_codes
 
@@ -55,11 +63,11 @@ def _flash_update(m, l, acc, q, k, v, kpos, length):
     """One online-softmax block update.
 
     m, l [rep, 1]; acc [rep, dh]; q [rep, dh]; k, v [bk, dh] (all f32);
-    kpos [bk] absolute cache positions; length: scalar valid count.
+    kpos [1, bk] absolute cache positions; length: scalar valid count.
     """
     s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)   # [rep, bk]
-    s = jnp.where(kpos[None, :] < length, s, _NEG)
+    s = jnp.where(kpos < length, s, _NEG)
     m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
     corr = jnp.exp(m - m_new)
     p = jnp.exp(s - m_new)
@@ -70,22 +78,24 @@ def _flash_update(m, l, acc, q, k, v, kpos, length):
 
 
 def _block_positions(blk, bk: int):
-    """Absolute cache positions [bk] of KV block ``blk`` (2-D iota: TPU)."""
-    return blk * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)[:, 0]
+    """Absolute cache positions [1, bk] of KV block ``blk`` (2-D iota along
+    lanes, the layout of the score row it masks)."""
+    return blk * bk + jax.lax.broadcasted_iota(jnp.int32, (1, bk), 1)
 
 
 def _dequant_block(scheme_name: str, packed, scales):
-    """One KV block: packed [bk, dh/4] int32 + scales [bk] -> f32 [bk, dh].
+    """One KV head's block: packed [bk, dh/4] int32 + scales [bk, 1] ->
+    f32 [bk, dh].
 
-    Unpacks with the shared ``kv_unpack_codes`` codec (shift/mask only —
-    Pallas-safe), then decodes arithmetically (two's complement / E4M3 with
-    DAZ, NaN-as-zero) — identical values to the quant.schemes LUT path,
-    gather-free in-kernel.
+    Unpacks with the shared ``kv_unpack_codes`` codec (shift/mask and a
+    lane concatenation — Pallas-safe), then decodes arithmetically (two's
+    complement / E4M3 with DAZ, NaN-as-zero) — identical values to the
+    quant.schemes LUT path, gather-free in-kernel.
     """
     codes = kv_unpack_codes(packed)
     vals = _decode_int(codes, 8) if scheme_name == "int8" \
         else _decode_fp8_e4m3(codes)
-    return vals * scales[:, None]
+    return vals * scales
 
 
 def _prep_queries(q, hk: int):
@@ -113,8 +123,10 @@ def _pick_bk(sk: int, bk=None) -> int:
 # ---------------------------------------------------------------------------
 # Kernel bodies (bf16 slab / packed-quantized slab)
 # ---------------------------------------------------------------------------
-def _decode_step(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, bk: int):
-    blk = pl.program_id(2)
+def _decode_step(len_ref, q_ref, head_kv, o_ref, m_ref, l_ref, bk: int):
+    """One KV block of one slot row: the flash update of every KV head.
+    ``head_kv(h)`` loads head h's (k, v) block as f32 [bk, dh]."""
+    row, blk = pl.program_id(0), pl.program_id(1)
 
     @pl.when(blk == 0)
     def _init():
@@ -122,43 +134,51 @@ def _decode_step(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, bk: int):
         l_ref[...] = jnp.zeros_like(l_ref)
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    m, l, acc = _flash_update(m_ref[0, 0], l_ref[0, 0], o_ref[0, 0],
-                              q_ref[0, 0], k, v,
-                              _block_positions(blk, bk), len_ref[0, 0])
-    m_ref[0, 0] = m
-    l_ref[0, 0] = l
-    o_ref[0, 0] = acc
+    kpos = _block_positions(blk, bk)
+    length = len_ref[row]
+    for h in range(q_ref.shape[1]):
+        k, v = head_kv(h)
+        m, l, acc = _flash_update(m_ref[0, h], l_ref[0, h], o_ref[0, h],
+                                  q_ref[0, h], k, v, kpos, length)
+        m_ref[0, h] = m
+        l_ref[0, h] = l
+        o_ref[0, h] = acc
 
-    @pl.when(blk == pl.num_programs(2) - 1)
+    @pl.when(blk == pl.num_programs(1) - 1)
     def _normalize():
         o_ref[...] = o_ref[...] / jnp.maximum(l_ref[...], 1e-30)
 
 
 def _decode_bf16_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, *,
                         bk: int):
-    k = k_ref[0, :, 0, :].astype(jnp.float32)        # [bk, dh]
-    v = v_ref[0, :, 0, :].astype(jnp.float32)
-    _decode_step(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, bk)
+    def head_kv(h):
+        return (k_ref[0, :, h, :].astype(jnp.float32),      # [bk, dh]
+                v_ref[0, :, h, :].astype(jnp.float32))
+    _decode_step(len_ref, q_ref, head_kv, o_ref, m_ref, l_ref, bk)
 
 
 def _decode_quant_kernel(len_ref, q_ref, kp_ref, ks_ref, vp_ref, vs_ref,
                          o_ref, m_ref, l_ref, *, bk: int, scheme_name: str):
-    k = _dequant_block(scheme_name, kp_ref[0, :, 0, :], ks_ref[0, :, 0])
-    v = _dequant_block(scheme_name, vp_ref[0, :, 0, :], vs_ref[0, :, 0])
-    _decode_step(len_ref, q_ref, k, v, o_ref, m_ref, l_ref, bk)
+    def head_kv(h):
+        return (_dequant_block(scheme_name, kp_ref[0, :, h, :],
+                               ks_ref[0, :, h:h + 1]),
+                _dequant_block(scheme_name, vp_ref[0, :, h, :],
+                               vs_ref[0, :, h:h + 1]))
+    _decode_step(len_ref, q_ref, head_kv, o_ref, m_ref, l_ref, bk)
 
 
 # ---------------------------------------------------------------------------
 # Entry point
 # ---------------------------------------------------------------------------
 def gqa_decode_attention(q, k_cache, v_cache, kv_valid_len, *, bk=None,
-                         interpret: bool = True):
+                         interpret=None):
     """Fused decode attention over a (possibly quantized) KV pool slab.
 
     q [B, 1, H, Dh] bf16; k_cache/v_cache either bf16 [B, Sk, Hk, Dh] or
     ``QuantizedKV`` (packed [B, Sk, Hk, Dh/4] int32 + scales [B, Sk, Hk]);
     kv_valid_len [B] committed positions per slot (the just-written token
-    included).  Returns [B, 1, H, Dh] in q.dtype.
+    included).  Returns [B, 1, H, Dh] in q.dtype.  ``interpret``: None
+    follows the platform (``repro.kernels.resolve_interpret``).
     """
     b, sq, h, dh = q.shape
     quant = isinstance(k_cache, QuantizedKV)
@@ -169,28 +189,28 @@ def gqa_decode_attention(q, k_cache, v_cache, kv_valid_len, *, bk=None,
     rep = h // hk
     qg = _prep_queries(q, hk)
     bk = _pick_bk(sk, bk)
-    grid = (b, hk, sk // bk)
-    lens = jnp.asarray(kv_valid_len, jnp.int32).reshape(b, 1)
+    grid = (b, sk // bk)
+    lens = jnp.asarray(kv_valid_len, jnp.int32).reshape(b)
 
-    len_spec = pl.BlockSpec((1, 1), lambda bi, hi, ki: (bi, 0))
-    q_spec = pl.BlockSpec((1, 1, rep, dh), lambda bi, hi, ki: (bi, hi, 0, 0))
-    o_spec = pl.BlockSpec((1, 1, rep, dh), lambda bi, hi, ki: (bi, hi, 0, 0))
-    ml_spec = pl.BlockSpec((1, 1, rep, 1), lambda bi, hi, ki: (bi, hi, 0, 0))
+    len_spec = pl.BlockSpec(memory_space=pltpu.SMEM)
+    q_spec = pl.BlockSpec((1, hk, rep, dh), lambda bi, ki: (bi, 0, 0, 0))
+    o_spec = pl.BlockSpec((1, hk, rep, dh), lambda bi, ki: (bi, 0, 0, 0))
+    ml_spec = pl.BlockSpec((1, hk, rep, 1), lambda bi, ki: (bi, 0, 0, 0))
     out_shape = (jax.ShapeDtypeStruct((b, hk, rep, dh), jnp.float32),
                  jax.ShapeDtypeStruct((b, hk, rep, 1), jnp.float32),
                  jax.ShapeDtypeStruct((b, hk, rep, 1), jnp.float32))
 
     if quant:
         dw = k_cache.packed.shape[-1]
-        kv_spec = pl.BlockSpec((1, bk, 1, dw), lambda bi, hi, ki: (bi, ki, hi, 0))
-        sc_spec = pl.BlockSpec((1, bk, 1), lambda bi, hi, ki: (bi, ki, hi))
+        kv_spec = pl.BlockSpec((1, bk, hk, dw), lambda bi, ki: (bi, ki, 0, 0))
+        sc_spec = pl.BlockSpec((1, bk, hk), lambda bi, ki: (bi, ki, 0))
         kernel = functools.partial(_decode_quant_kernel, bk=bk,
                                    scheme_name=k_cache.scheme_name)
         in_specs = [len_spec, q_spec, kv_spec, sc_spec, kv_spec, sc_spec]
         args = (lens, qg, k_cache.packed, k_cache.scales,
                 v_cache.packed, v_cache.scales)
     else:
-        kv_spec = pl.BlockSpec((1, bk, 1, dh), lambda bi, hi, ki: (bi, ki, hi, 0))
+        kv_spec = pl.BlockSpec((1, bk, hk, dh), lambda bi, ki: (bi, ki, 0, 0))
         kernel = functools.partial(_decode_bf16_kernel, bk=bk)
         in_specs = [len_spec, q_spec, kv_spec, kv_spec]
         args = (lens, qg, k_cache, v_cache)
@@ -198,7 +218,7 @@ def gqa_decode_attention(q, k_cache, v_cache, kv_valid_len, *, bk=None,
     out, _, _ = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs,
         out_specs=(o_spec, ml_spec, ml_spec), out_shape=out_shape,
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(*args)
     return out.reshape(b, 1, h, dh).astype(q.dtype)
 
@@ -234,7 +254,7 @@ def decode_attention_shard_specs(mesh, b: int, hk: int, quant: bool):
 
 
 def sharded_gqa_decode_attention(q, k_cache, v_cache, kv_valid_len, *, mesh,
-                                 bk=None, interpret: bool = True):
+                                 bk=None, interpret=None):
     """``gqa_decode_attention`` under ``shard_map`` over the serving mesh.
 
     Each shard runs the unmodified kernel on its local
@@ -250,7 +270,6 @@ def sharded_gqa_decode_attention(q, k_cache, v_cache, kv_valid_len, *, mesh,
     partitioner's choices around it (observed as ulp-level drift in the
     surrounding matmuls at tp=8).
     """
-    from jax.experimental.shard_map import shard_map
     b, _, h, dh = q.shape
     quant = isinstance(k_cache, QuantizedKV)
     hk = (k_cache.packed if quant else k_cache).shape[2]
@@ -262,9 +281,9 @@ def sharded_gqa_decode_attention(q, k_cache, v_cache, kv_valid_len, *, mesh,
     if quant:  # carry the real scheme name so spec/cache trees match
         kv_spec = QuantizedKV(kv_spec.packed, kv_spec.scales,
                               k_cache.scheme_name)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(gqa_decode_attention, bk=bk, interpret=interpret),
         mesh=mesh,
         in_specs=(q_spec, kv_spec, kv_spec, len_spec),
-        out_specs=out_spec, check_rep=False)
+        out_specs=out_spec, check_vma=False)
     return fn(q, k_cache, v_cache, jnp.asarray(kv_valid_len, jnp.int32))

@@ -4,8 +4,8 @@
 the kernel (or the pure-jnp reference path) from the layer's quantization
 scheme plus the active *execution policy*.  The jnp path is mathematically
 identical — packed weights either way, so HBM traffic (the roofline memory
-term) is the same; the Pallas path is the TPU-target fast path validated
-under interpret=True.
+term) is the same; the Pallas path is the TPU fast path, run in interpret
+mode on any other backend (``repro.kernels.resolve_interpret``).
 
 Execution policy (DESIGN.md §12, §14).  Dispatch is driven by ONE
 module-level execution record:
@@ -25,8 +25,10 @@ on a single device (the bit-exact baseline) and to pallas under a mesh.
 What remains of the downgrade is PER-SITE: a call site whose shard-local
 shapes cannot tile the kernel legally — or that has no registered
 sharding spec (stacked-expert leaves, ad-hoc callers) — falls back to the
-jnp path with a warning keyed by the site (once per site per process;
-other sites in the same trace keep the kernel).  ``partitioned=True``
+jnp path with a ``KernelFallbackWarning`` keyed by the site (once per
+site per process; other sites in the same trace keep the kernel) — a
+driver that must run every site on the kernel turns that category into
+an error.  ``partitioned=True``
 without a mesh (the legacy shim spelling) still downgrades every site:
 with no mesh object there is nothing to shard_map over.
 
@@ -59,6 +61,17 @@ _EXEC = {"mode": "auto", "mesh": None, "partitioned": False}
 # for the shard_map'd weight kernels (partitioning.serve_weight_kernel_specs)
 _WSPECS = {"map": None}
 _WARNED_SITES: set = set()
+
+
+class KernelFallbackWarning(UserWarning):
+    """A kernel site took the jnp path although the policy asked for
+    Pallas; ``site`` names it."""
+
+    def __init__(self, site: str, msg: str):
+        super().__init__(
+            f"kernel site {site!r} falls back to the jnp path: {msg} "
+            "(same math, packed weights either way; warned once per site)")
+        self.site = site
 
 
 def declare_execution(*, kernel: Optional[str] = None,
@@ -133,10 +146,7 @@ def _warn_site(site: str, msg: str) -> None:
     if site in _WARNED_SITES:
         return
     _WARNED_SITES.add(site)
-    warnings.warn(
-        f"kernel site {site!r} falls back to the jnp path: {msg} "
-        "(same math, packed weights either way; warned once per site)",
-        stacklevel=3)
+    warnings.warn(KernelFallbackWarning(site, msg), stacklevel=3)
 
 
 def reset_site_warnings() -> None:
@@ -213,7 +223,7 @@ def _axis_size(mesh, ax) -> int:
 
 
 def _mesh_quantized_matmul(x2, qw: QuantizedLinearWeights, mesh,
-                           interpret: bool, site: str):
+                           interpret, site: str):
     """The packed kernel under ``shard_map`` over the declared mesh, with
     specs from the registered per-leaf map; None when this site must fall
     back to the jnp path (no spec / illegal shard-local shapes).
@@ -228,7 +238,6 @@ def _mesh_quantized_matmul(x2, qw: QuantizedLinearWeights, mesh,
     over the model axis — ``ref.sharded_packed_matmul_ref`` is the
     matching oracle.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     scheme = qw.scheme
@@ -252,12 +261,12 @@ def _mesh_quantized_matmul(x2, qw: QuantizedLinearWeights, mesh,
         if nsz == 1:   # nothing shards: bare kernel (GSPMD replicates it)
             return w8a8_matmul(x_codes, x_scale, qw.packed, qw.scales,
                                interpret=interpret)
-        fn = shard_map(
+        fn = jax.shard_map(
             lambda xc, xs, wc, ws: w8a8_matmul(xc, xs, wc, ws,
                                                interpret=interpret),
             mesh=mesh,
             in_specs=(P(None, None), P(), P(None, n_ax), P(None, n_ax)),
-            out_specs=P(None, n_ax), check_rep=False)
+            out_specs=P(None, n_ax), check_vma=False)
         return fn(x_codes, x_scale, qw.packed, qw.scales)
 
     if not packed_shapes_legal(x2.shape[0], k // ksz, n // nsz, scheme):
@@ -277,22 +286,23 @@ def _mesh_quantized_matmul(x2, qw: QuantizedLinearWeights, mesh,
             x2, qloc, interpret=interpret)
         return jax.lax.psum(out, k_ax) if k_ax is not None else out
 
-    fn = shard_map(local_mm, mesh=mesh,
-                   in_specs=(P(None, k_ax), P(k_ax, n_ax), P(sk_ax, n_ax)),
-                   out_specs=P(None, n_ax), check_rep=False)
+    fn = jax.shard_map(local_mm, mesh=mesh,
+                       in_specs=(P(None, k_ax), P(k_ax, n_ax), P(sk_ax, n_ax)),
+                       out_specs=P(None, n_ax), check_vma=False)
     return fn(x2, qw.packed, qw.scales)
 
 
 def quantized_matmul(x, qw: QuantizedLinearWeights, *,
                      use_kernel: Optional[bool] = None,
-                     interpret: bool = True, out_dtype=jnp.bfloat16):
+                     interpret=None, out_dtype=jnp.bfloat16):
     """x [..., K] @ quantized W [K, N] -> [..., N] in ``out_dtype``.
 
     ``use_kernel=None`` (the model layer's call) dispatches on the active
     execution policy — shard_map'd over the declared mesh, falling back
     per-site; an explicit bool overrides the mode but is downgraded under
-    partitioned execution (``kernel_allowed``).  Scheme dispatch (paper
-    Table I):
+    partitioned execution (``kernel_allowed``).  ``interpret``: None
+    follows the platform (``repro.kernels.resolve_interpret``).  Scheme
+    dispatch (paper Table I):
       awq_int4 / mxfp4 : INTx/FP4 x BF16 -> packed sub-byte kernel
       fp8              : FP8 weights (per-channel scale) -> packed kernel
       w8a8             : INT8 x INT8 -> INT32 (activations quantized here)

@@ -15,7 +15,8 @@ Kernels:
   * ``w8a8_matmul``    int8 x int8 -> int32 MXU accumulate -> scale epilogue
 
 Block shapes are MXU/VMEM aligned by default (bn multiple of 128, bk
-multiple of the packing group) and validated under interpret=True on CPU.
+multiple of the packing group), validated under interpret mode on CPU and
+compiled for a v5e in ``tests/test_tpu_compile.py``.
 """
 from __future__ import annotations
 
@@ -26,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import resolve_interpret
 from repro.quant.schemes import (
     QuantScheme, QuantizedLinearWeights, effective_group,
 )
@@ -126,9 +128,17 @@ def packed_block_plan(m: int, k: int, n: int, scheme: QuantScheme, *,
     group = effective_group(scheme.group_size, k)
     per = 32 // scheme.weight_bits
     # K blocks land on scale-group boundaries when the matrix has several
-    # groups, else (per-channel: one global scale row) on word boundaries
-    quantum = group if group < k else per
-    return (_fit_block(m, bm), _fit_block(n, bn), _fit_block(k, bk, quantum))
+    # groups — eight at a time, so a block's scale rows fill the TPU's
+    # 8-row tile (or the block spans all of K) — else (per-channel: one
+    # global scale row) on word boundaries.  N blocks keep to the 128-lane
+    # tile when N allows it.
+    if group < k:
+        quantum = 8 * group
+        bk = max(bk, quantum)
+    else:
+        quantum = per
+    return (_fit_block(m, bm), _fit_block(n, bn, 128 if n % 128 == 0 else 1),
+            _fit_block(k, bk, quantum))
 
 
 def packed_shapes_legal(m: int, k: int, n: int, scheme: QuantScheme) -> bool:
@@ -176,7 +186,7 @@ def _packed_matmul_impl(x, packed, scales, *, scheme_name: str, k: int, n: int,
 
 
 def packed_matmul(x, qw: QuantizedLinearWeights, *, bm: int = 128, bn: int = 128,
-                  bk: int = 512, interpret: bool = False):
+                  bk: int = 512, interpret=None):
     """x [M, K] (bf16) @ packed W [K, N] -> f32 [M, N]."""
     k, n = qw.shape
     m = x.shape[0]
@@ -187,12 +197,12 @@ def packed_matmul(x, qw: QuantizedLinearWeights, *, bm: int = 128, bn: int = 128
     assert m % bm == 0 and n % bn == 0 and k % bk == 0, (m, n, k, bm, bn, bk)
     return _packed_matmul_impl(
         x, qw.packed, qw.scales, scheme_name=scheme.name, k=k, n=n,
-        bm=bm, bn=bn, bk=bk, interpret=interpret,
+        bm=bm, bn=bn, bk=bk, interpret=resolve_interpret(interpret),
     )
 
 
 def packed_gemv(x, qw: QuantizedLinearWeights, *, bn: int = 256, bk: int = 1024,
-                interpret: bool = False):
+                interpret=None):
     """Decode-shape GEMV: x [B, K] with small B (the paper's Section VI-C)."""
     return packed_matmul(x, qw, bm=x.shape[0], bn=bn, bk=bk, interpret=interpret)
 
@@ -234,7 +244,7 @@ def _w8a8_impl(x_codes, w_codes, *, bm, bn, bk, interpret):
 
 
 def w8a8_matmul(x_codes, x_scale, w_codes, w_scales, *, bm: int = 128,
-                bn: int = 128, bk: int = 512, interpret: bool = False):
+                bn: int = 128, bk: int = 512, interpret=None):
     """INT8 codes x INT8 codes -> exact INT32 accumulate -> f32 descale.
 
     x_codes [M, K] int8 (per-tensor scale x_scale), w_codes [K, N] int8
@@ -244,5 +254,6 @@ def w8a8_matmul(x_codes, x_scale, w_codes, w_scales, *, bm: int = 128,
     n = w_codes.shape[1]
     bm, bn, bk = _fit_block(m, bm), _fit_block(n, bn), _fit_block(k, bk)
     assert m % bm == 0 and n % bn == 0 and k % bk == 0
-    acc = _w8a8_impl(x_codes, w_codes, bm=bm, bn=bn, bk=bk, interpret=interpret)
+    acc = _w8a8_impl(x_codes, w_codes, bm=bm, bn=bn, bk=bk,
+                     interpret=resolve_interpret(interpret))
     return acc.astype(jnp.float32) * (w_scales * x_scale)

@@ -27,6 +27,16 @@ def packed_gemv_ref(x, qw: QuantizedLinearWeights):
     return packed_matmul_ref(x, qw)
 
 
+# Kernel vs ``packed_matmul_tiled_ref`` agree to this share of the output's
+# largest magnitude.  Interpret mode compiles the kernel body as one XLA
+# CPU program, which may fold a scale that is constant along K out of the
+# dot and reorder a tile's f32 sums, while the oracle runs op by op; a
+# shard-local tile shape compiles to yet another program.  Observed
+# differences reach about 2^-20 of the largest output (K = 512, sharded);
+# a wrong plan, scale or decode is off by orders of magnitude more.
+TILED_REF_ATOL = 2.0 ** -16
+
+
 def packed_matmul_tiled_ref(x, qw: QuantizedLinearWeights, *, bm: int = 128,
                             bn: int = 128, bk: int = 512):
     """BIT-exact oracle for ``packed_matmul``: replays the kernel's grid.
@@ -177,10 +187,10 @@ def decode_attention_ref(q, k_cache, v_cache, kv_valid_len, *, bk=None):
                 if quant:
                     k = _dequant_block(k_cache.scheme_name,
                                        k_cache.packed[bi, sl, hi],
-                                       k_cache.scales[bi, sl, hi])
+                                       k_cache.scales[bi, sl, hi][:, None])
                     v = _dequant_block(v_cache.scheme_name,
                                        v_cache.packed[bi, sl, hi],
-                                       v_cache.scales[bi, sl, hi])
+                                       v_cache.scales[bi, sl, hi][:, None])
                 else:
                     k = k_cache[bi, sl, hi].astype(jnp.float32)
                     v = v_cache[bi, sl, hi].astype(jnp.float32)

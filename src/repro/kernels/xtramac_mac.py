@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.packing import LanePlan
+from repro.kernels import resolve_interpret
 
 
 def _wide_multiply_limbs(a, b):
@@ -101,10 +102,10 @@ def _vdsp_impl(a_mags, b_mags, *, plan: LanePlan, bt: int, interpret: bool):
 
 
 def virtual_dsp_multiply(a_mags, b_mags, plan: LanePlan, *, bt: int = 1024,
-                         interpret: bool = False):
+                         interpret=None):
     """Packed lane products [T, P] from magnitudes [T, n_a] x [T, n_b]."""
     t = a_mags.shape[0]
     bt = min(bt, t)
     assert t % bt == 0, (t, bt)
     return _vdsp_impl(jnp.asarray(a_mags, jnp.int32), jnp.asarray(b_mags, jnp.int32),
-                      plan=plan, bt=bt, interpret=interpret)
+                      plan=plan, bt=bt, interpret=resolve_interpret(interpret))

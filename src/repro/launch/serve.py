@@ -19,7 +19,8 @@ import argparse
 import json
 import time
 
-from repro.launch.cli import force_host_devices, serving_mesh
+from repro.launch.cli import (enable_compile_cache, force_host_devices,
+                              serving_mesh)
 
 
 def main():
@@ -66,6 +67,7 @@ def main():
     import jax
     import numpy as np
 
+    enable_compile_cache()
     from repro.configs import get_config
     from repro.models.common import QuantMaker
     from repro.models import transformer as T

@@ -3,8 +3,9 @@
 Central idea: every architecture's parameter tree is built by ONE structure
 walker driven by a ``Maker``.  Four makers produce, from the same walk:
   * InitMaker      real bf16 dense parameters (training / smoke tests)
-  * QuantMaker     real quantized parameters (packed codes + scales) via the
-                   offline numpy quantizer — mixed-precision serving
+  * QuantMaker     real quantized parameters (packed codes + scales),
+                   drawn and quantized on the device one layer slice at a
+                   time — mixed-precision serving
   * AbstractMaker  jax.ShapeDtypeStruct trees (dry-run: zero allocation)
   * PspecMaker     jax.sharding.PartitionSpec trees (pjit annotations)
 so parameter structure, quantization plan, and sharding can never drift.
@@ -15,6 +16,7 @@ static aux = (scheme name, logical shape) — jit/scan/pjit-safe.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
@@ -24,7 +26,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.kernels.ops import quantized_matmul
 from repro.quant.schemes import (
-    QuantScheme, QuantizedLinearWeights, get_scheme, quantize_weights,
+    QuantScheme, QuantizedLinearWeights, get_scheme, quantize_weights_jnp,
 )
 
 
@@ -165,12 +167,11 @@ class InitMaker(Maker):
         return sub
 
     def dense(self, name, stack, k, n, scheme=None):
-        w = jax.random.normal(self._next(), stack + (k, n), jnp.float32)
-        return (w / np.sqrt(k)).astype(self.dtype)
+        return _draw_dense(self._next(), stack + (k, n), self.dtype)
 
     def table(self, name, stack, rows, cols, scale=0.02):
-        return (jax.random.normal(self._next(), stack + (rows, cols),
-                                  jnp.float32) * scale).astype(self.dtype)
+        return _draw_table(self._next(), stack + (rows, cols), scale,
+                           self.dtype)
 
     def norm(self, name, stack, dim):
         return jnp.ones(stack + (dim,), jnp.float32)
@@ -180,7 +181,14 @@ class InitMaker(Maker):
 
 
 class QuantMaker(InitMaker):
-    """Real quantized parameters: dense init -> offline numpy quantizer.
+    """Real quantized parameters, drawn and quantized on the device.
+
+    Each quantized leaf is built one slice of its leading stack dim (one
+    layer, with all its experts) at a time: a jitted step draws the slice's
+    float weights from ``fold_in(leaf key, slice)``, quantizes them with
+    ``quantize_weights_jnp`` (bit-identical to the numpy quantizer) and
+    writes the codes and scales into the donated stack in place.  Peak
+    memory is the packed stack plus one float slice, whatever the depth.
 
     ``plan``: optional per-leaf scheme overrides, keyed by the leaf's
     logical name ("attn.wo", "ffn.w_down", "moe.w_up", ...) — the same
@@ -188,33 +196,105 @@ class QuantMaker(InitMaker):
     ``scheme=``; 'bf16' (or None) keeps the leaf dense.  Sharding specs for
     a plan-built checkpoint must be built with the same plan
     (``partitioning.param_specs(..., plan=...)``) or the trees diverge.
+
+    ``specs``: optional spec maker (``partitioning.spec_maker`` with
+    ``train=False`` and the same plan) — every leaf, and every slice of
+    it, is then created directly in its mesh shards.  Without it leaves
+    land on the default device.
     """
 
     def __init__(self, key, plan: Optional[Dict[str, str]] = None,
-                 dtype=jnp.bfloat16):
+                 dtype=jnp.bfloat16, specs=None):
         super().__init__(key, dtype)
         self.plan = dict(plan or {})
+        self.specs = specs
+
+    def _sharding(self, spec):
+        if self.specs is None:
+            return None
+        from jax.sharding import NamedSharding
+        return jax.tree_util.tree_map(
+            lambda p: NamedSharding(self.specs.mesh, p), spec,
+            is_leaf=lambda x: isinstance(x, P))
+
+    def _make(self, fn, spec):
+        return jax.jit(fn, out_shardings=self._sharding(spec))()
 
     def dense(self, name, stack, k, n, scheme=None):
         scheme = self.plan.get(name, scheme)
         scheme = scheme if scheme is not None else "bf16"
+        spec = None if self.specs is None \
+            else self.specs.dense(name, stack, k, n, scheme)
+        key = self._next()
         if scheme == "bf16":
-            return super().dense(name, stack, k, n)
-        w = np.asarray(
-            jax.random.normal(self._next(), stack + (k, n), jnp.float32)
-        ) / np.sqrt(k)
-        if stack:
-            flat = w.reshape((-1, k, n))
-            qws = [quantize_weights(get_scheme(scheme), flat[i])
-                   for i in range(flat.shape[0])]
-            packed = jnp.stack([q.packed for q in qws]).reshape(
-                stack + qws[0].packed.shape)
-            scales = jnp.stack([q.scales for q in qws]).reshape(
-                stack + qws[0].scales.shape)
-        else:
-            q = quantize_weights(get_scheme(scheme), w)
-            packed, scales = q.packed, q.scales
+            return self._make(
+                lambda: _draw_dense(key, stack + (k, n), self.dtype), spec)
+        s = get_scheme(scheme)
+        abstract = AbstractMaker().dense(name, stack, k, n, scheme)
+        sh = self._sharding(spec)
+        sh_p, sh_s = (None, None) if sh is None else (sh.packed, sh.scales)
+        if not stack:
+            packed, scales = jax.jit(
+                lambda: _draw_quantized(key, s, (), k, n),
+                out_shardings=(sh_p, sh_s))()
+            return QLinear(packed, scales, scheme, (k, n), name)
+        packed = jnp.zeros(abstract.packed.shape, abstract.packed.dtype,
+                           device=sh_p)
+        scales = jnp.zeros(abstract.scales.shape, jnp.float32, device=sh_s)
+        for i in range(stack[0]):
+            packed, scales = _write_quantized_slice(
+                packed, scales, key, jnp.int32(i), scheme_name=scheme, k=k,
+                n=n)
         return QLinear(packed, scales, scheme, (k, n), name)
+
+    def table(self, name, stack, rows, cols, scale=0.02):
+        key = self._next()
+        return self._make(
+            lambda: _draw_table(key, stack + (rows, cols), scale, self.dtype),
+            None if self.specs is None
+            else self.specs.table(name, stack, rows, cols, scale))
+
+    def norm(self, name, stack, dim):
+        return jnp.ones(stack + (dim,), jnp.float32, device=self._sharding(
+            None if self.specs is None else self.specs.norm(name, stack, dim)))
+
+    def vector(self, name, stack, dim, init=0.0):
+        return jnp.full(stack + (dim,), init, jnp.float32,
+                        device=self._sharding(
+                            None if self.specs is None
+                            else self.specs.vector(name, stack, dim, init)))
+
+
+def _draw_dense(key, shape, dtype):
+    """N(0, 1/k) weights [..., k, n] in ``dtype``."""
+    w = jax.random.normal(key, shape, jnp.float32)
+    return (w / np.sqrt(shape[-2])).astype(dtype)
+
+
+def _draw_table(key, shape, scale: float, dtype):
+    return (jax.random.normal(key, shape, jnp.float32) * scale).astype(dtype)
+
+
+def _draw_quantized(key, scheme: QuantScheme, stack, k: int, n: int):
+    """Draw N(0, 1/k) weights [*stack, k, n] and quantize each [k, n]."""
+    w = jax.random.normal(key, stack + (k, n), jnp.float32) / np.sqrt(k)
+    quantize = lambda m: quantize_weights_jnp(scheme, m)   # noqa: E731
+    for _ in stack:
+        quantize = jax.vmap(quantize)
+    return quantize(w)
+
+
+@functools.partial(jax.jit, static_argnames=("scheme_name", "k", "n"),
+                   donate_argnums=(0, 1))
+def _write_quantized_slice(packed, scales, key, i, *, scheme_name: str,
+                           k: int, n: int):
+    """Draw slice ``i`` of the leading stack dim from ``fold_in(key, i)``
+    and quantize it into the donated stacks (their shardings carry over)."""
+    p, s = _draw_quantized(jax.random.fold_in(key, i),
+                           get_scheme(scheme_name), packed.shape[1:-2], k, n)
+    start = (i,) + (0,) * p.ndim
+    return (jax.lax.dynamic_update_slice(packed, p[None], start),
+            jax.lax.dynamic_update_slice(scales, s[None], start))
 
 
 class AbstractMaker(Maker):

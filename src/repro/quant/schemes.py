@@ -11,8 +11,10 @@ weights are quantized into it:
 
 The dequant LUTs are generated from core.formats codecs, so kernel-side
 decode is bit-identical to the XtraMAC Stage-1 mapping semantics (DAZ,
-implicit-one restore).  quantize() lives in numpy (offline, checkpoint
-prep); dequantize() has a jnp path used inside models and kernels.
+implicit-one restore).  ``quantize_weights`` is the numpy quantizer
+(offline checkpoint prep, and the oracle); ``quantize_weights_jnp`` is its
+traceable twin, which builds serving weights on the device slice by slice;
+dequantize() has a jnp path used inside models and kernels.
 """
 from __future__ import annotations
 
@@ -24,7 +26,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import formats as F
-from .pack import codes_per_word, pack_codes_np, unpack_codes
+from .pack import codes_per_word, pack_codes, pack_codes_np, unpack_codes
 
 
 @dataclasses.dataclass(frozen=True)
@@ -130,19 +132,21 @@ def quantize_weights(scheme: QuantScheme, w: np.ndarray) -> QuantizedLinearWeigh
 
     g = effective_group(scheme.group_size, k)
     absmax = np.maximum(_group_absmax(w, g), 1e-12)          # [K/G, N]
+    # scales multiply by the f32 reciprocal of the code range: XLA turns a
+    # division by a constant into exactly that, so ``quantize_weights_jnp``
+    # and this quantizer agree bit for bit
 
     if scheme.weight_format.startswith("int"):
         qmax = (1 << (scheme.weight_bits - 1)) - 1           # symmetric
-        scales = absmax / qmax
+        scales = absmax * np.float32(1.0 / qmax)
         wg = w.reshape(k // g, g, n)
         q = np.rint(wg / scales[:, None, :]).clip(-qmax - 1, qmax)
         codes = (q.astype(np.int64) & ((1 << scheme.weight_bits) - 1)).reshape(k, n)
     else:
         fmt = F.get_format(scheme.weight_format)
+        scales = absmax * np.float32(1.0 / fmt.max_finite)
         if scheme.scale_pow2:  # UE8M0: scale = 2^ceil(log2(absmax / max_finite))
-            scales = np.exp2(np.ceil(np.log2(absmax / fmt.max_finite)))
-        else:
-            scales = absmax / fmt.max_finite
+            scales = np.exp2(np.ceil(np.log2(scales)))
         wg = w.reshape(k // g, g, n) / scales[:, None, :]
         codes = F.quantize_f64(fmt, wg.astype(np.float64)).reshape(k, n)
 
@@ -154,6 +158,38 @@ def quantize_weights(scheme: QuantScheme, w: np.ndarray) -> QuantizedLinearWeigh
     return QuantizedLinearWeights(
         scheme, jnp.asarray(packed), jnp.asarray(scales, jnp.float32), (k, n)
     )
+
+
+def quantize_weights_jnp(scheme: QuantScheme, w):
+    """Traceable twin of ``quantize_weights``: float [K, N] -> (packed,
+    scales) as jnp arrays, the same codes and scales as the numpy quantizer
+    (pinned in tests/test_quant_properties.py).  Runs inside a jit, so a
+    serving checkpoint is drawn and quantized on the device, one layer
+    slice at a time, without a float copy of the whole stack on the host."""
+    w = w.astype(jnp.float32)
+    k, n = w.shape
+    g = effective_group(scheme.group_size, k)
+    wg = w.reshape(k // g, g, n)
+    absmax = jnp.maximum(jnp.abs(wg).max(axis=1), 1e-12)      # [K/G, N]
+    if scheme.weight_format.startswith("int"):
+        qmax = (1 << (scheme.weight_bits - 1)) - 1           # symmetric
+        scales = absmax * jnp.float32(1.0 / qmax)
+        q = jnp.clip(jnp.round(wg / scales[:, None, :]), -qmax - 1, qmax)
+        codes = q.astype(jnp.int32) & ((1 << scheme.weight_bits) - 1)
+    else:
+        fmt = F.get_format(scheme.weight_format)
+        scales = absmax * jnp.float32(1.0 / fmt.max_finite)
+        if scheme.scale_pow2:
+            scales = jnp.exp2(jnp.ceil(jnp.log2(scales)))
+        codes = _encode_minifloat(fmt, jnp.clip(
+            wg / scales[:, None, :], -fmt.max_finite, fmt.max_finite))
+    codes = codes.reshape(k, n)
+    if scheme.packed:
+        packed = pack_codes(codes, scheme.weight_bits)
+    else:
+        packed = codes.astype(jnp.int8) \
+            if scheme.weight_format.startswith("int") else codes.astype(jnp.uint8)
+    return packed, scales.astype(jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -222,26 +258,32 @@ def get_kv_scheme(kv_dtype) -> Optional[KVQuantScheme]:
 
 
 def kv_pack_codes(codes):
-    """8-bit codes [..., D] -> int32 words [..., D/4] (little-endian), jnp."""
+    """8-bit codes [..., D] -> int32 words [..., D/4], jnp.
+
+    Planar layout: byte i of word w holds channel ``i * D/4 + w``, so
+    unpacking is four shift/mask planes concatenated along the channel
+    axis — a lane concatenation inside the decode kernel, where the
+    interleaved (channel ``4w + i``) layout would need a lane shuffle."""
     d = codes.shape[-1]
     assert d % 4 == 0, f"trailing dim {d} not divisible by 4 (KV packing)"
-    c = (codes.astype(jnp.int32) & 0xFF).reshape(codes.shape[:-1] + (d // 4, 4))
-    return c[..., 0] | (c[..., 1] << 8) | (c[..., 2] << 16) | (c[..., 3] << 24)
+    c = (codes.astype(jnp.int32) & 0xFF).reshape(codes.shape[:-1] + (4, d // 4))
+    return c[..., 0, :] | (c[..., 1, :] << 8) | (c[..., 2, :] << 16) \
+        | (c[..., 3, :] << 24)
 
 
 def kv_unpack_codes(words):
     """int32 words [..., Dw] -> unsigned 8-bit codes [..., Dw*4], jnp."""
-    parts = [(words >> (8 * i)) & 0xFF for i in range(4)]
-    return jnp.stack(parts, axis=-1).reshape(words.shape[:-1] + (-1,))
+    return jnp.concatenate([(words >> (8 * i)) & 0xFF for i in range(4)],
+                           axis=-1)
 
 
-def _encode_fp8_e4m3(x):
-    """jnp RN-even E4M3 encode of f32 (already clipped to max finite) ->
-    uint codes, FTZ on underflow — bit-identical to
-    ``core.formats.quantize_f64`` on this domain.  NOT the XLA float8 cast:
-    that double-rounds through f16 on CPU, flipping round-to-nearest-even
-    ties (e.g. 61.99 -> 64 instead of 60)."""
-    fmt = F.FP8_E4M3
+def _encode_minifloat(fmt, x):
+    """jnp RN-even encode of f32 (already clipped to the format's max
+    finite) -> uint codes of the minifloat ``fmt`` (E4M3, E2M1), FTZ on
+    underflow — bit-identical to ``core.formats.quantize_f64`` on this
+    domain.  NOT the XLA float8 cast: that double-rounds through f16 on
+    CPU, flipping round-to-nearest-even ties (e.g. 61.99 -> 64 instead of
+    60)."""
     xf = x.astype(jnp.float32)
     sign = jnp.signbit(xf).astype(jnp.int32)
     mag = jnp.abs(xf)
@@ -254,9 +296,10 @@ def _encode_fp8_e4m3(x):
     m = jnp.where(carry, m >> 1, m)
     e_unb = e_unb + carry
     underflow = (e_unb < fmt.min_unbiased_exp) | (mag == 0)
-    code = (sign << 7) | ((e_unb + fmt.bias) << fmt.man_bits) \
+    sign = sign << (fmt.bits - 1)
+    code = sign | ((e_unb + fmt.bias) << fmt.man_bits) \
         | (m & ((1 << fmt.man_bits) - 1))
-    return jnp.where(underflow, sign << 7, code)    # FTZ: signed zero
+    return jnp.where(underflow, sign, code)         # FTZ: signed zero
 
 
 def kv_quantize(scheme: KVQuantScheme, x):
@@ -278,7 +321,7 @@ def kv_quantize(scheme: KVQuantScheme, x):
         scales = absmax / jnp.float32(fmt.max_finite)
         scaled = jnp.clip(xf / scales[..., None],
                           -fmt.max_finite, fmt.max_finite)
-        codes = _encode_fp8_e4m3(scaled)
+        codes = _encode_minifloat(fmt, scaled)
     return kv_pack_codes(codes), scales
 
 

@@ -245,6 +245,18 @@ def param_specs(cfg: ModelConfig, mesh: Mesh, *, train: bool,
         if plan is not None:
             raise ValueError("give either plan= or policy=, not both")
         plan = policy.resolved_plan(cfg)
+    return build_params(cfg, spec_maker(cfg, mesh, train=train,
+                                        quantize=quantize, plan=plan))
+
+
+def spec_maker(cfg: ModelConfig, mesh: Mesh, *, train: bool,
+               quantize: Optional[bool] = None,
+               plan: Optional[Dict[str, str]] = None) -> PspecMaker:
+    """The Maker behind ``param_specs``, answering one leaf at a time (its
+    ``mesh`` attribute names the mesh).  ``QuantMaker(..., specs=
+    spec_maker(cfg, mesh, train=False, plan=plan))`` creates every leaf
+    directly in the shards the serving engine expects (give both the same
+    plan)."""
     rules = rules_from_mesh(mesh, train=train)
     sizes = _collect_dim_sizes(cfg, plan)
     if rules.fsdp_axis is not None:
@@ -255,6 +267,7 @@ def param_specs(cfg: ModelConfig, mesh: Mesh, *, train: bool,
     class Maker(PspecMaker):
         def __init__(self):
             super().__init__(rule=rule, quantize=q)
+            self.mesh = mesh
 
         def dense(self, name, stack, k, n, scheme=None):
             if plan:
@@ -271,7 +284,7 @@ def param_specs(cfg: ModelConfig, mesh: Mesh, *, train: bool,
                     for p in parts[len(stack_ax):]]
             return P(*parts)
 
-    return build_params(cfg, Maker())
+    return Maker()
 
 
 def serve_weight_kernel_specs(cfg: ModelConfig, mesh: Mesh, *,

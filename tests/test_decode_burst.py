@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models.common import InitMaker, QuantMaker
 from repro.models import transformer as T
 from repro.serve import (Request, RequestState, SamplingParams, ServeConfig,
@@ -315,7 +316,7 @@ def test_mesh_single_device_burst_bit_identical(engine):
     cfg, params = engine.cfg, engine.params
     prompts = _prompts(engine, 3, [9, 6], seed=10)
     ref, _ = _run(engine, prompts, max_burst=8)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     eng = ServingEngine(cfg, params, ServeConfig(
         max_len=48, n_slots=4, prefill_chunk=8, max_burst=8, mesh=mesh))
     got, sched = _run(eng, prompts, max_burst=8)
@@ -327,7 +328,10 @@ def test_mesh_single_device_burst_bit_identical(engine):
 def test_burst_dp2_tp4_bit_identical():
     """Bursts under the dp=2 x tp=4 mesh (8 forced host devices), quantized
     weights + int8 KV pool, mid-flight admission included: bit-identical to
-    the single-device single-step run, with strictly fewer dispatches."""
+    the single-device single-step run, with strictly fewer dispatches.
+    Both run the Pallas kernels: ``kernel='auto'`` would pick jnp meshless
+    and the kernels under the mesh, which round differently."""
+    from repro.quant.policy import PrecisionPolicy
     cfg = get_config("granite-8b", smoke=True)
     params = T.build_params(cfg, QuantMaker(jax.random.PRNGKey(0)))
     rng = np.random.default_rng(11)
@@ -336,11 +340,11 @@ def test_burst_dp2_tp4_bit_identical():
 
     def engine(mesh):
         return ServingEngine(cfg, params, ServeConfig(
-            max_len=32, n_slots=8, prefill_chunk=8, kv_dtype="int8",
-            max_burst=8, mesh=mesh))
+            max_len=32, n_slots=8, prefill_chunk=8, max_burst=8, mesh=mesh,
+            policy=PrecisionPolicy(kv="int8", kernel="pallas")))
 
     ref, s1 = _run(engine(None), prompts, max_burst=1, midflight=True)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     got, s8 = _run(engine(mesh), prompts, max_burst=8, midflight=True)
     assert got == ref
     assert s8.n_decode_dispatches < s1.n_decode_dispatches

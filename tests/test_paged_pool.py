@@ -15,6 +15,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models.common import InitMaker
 from repro.models import transformer as T
 from repro.serve import (PageAllocator, Request, SamplingParams, ServeConfig,
@@ -191,7 +192,7 @@ def test_paged_bit_identical_dp2_tp4(cfg, params):
     the page arena shards where the slab's slot axis did and the table
     rides the data axis, so GSPMD's gather/scatter reassembles exactly
     the meshless bytes."""
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     slab = ServingEngine(cfg, params, ServeConfig(
         max_len=48, n_slots=4, prefill_chunk=8, mesh=mesh))
     paged = ServingEngine(cfg, params, ServeConfig(

@@ -25,6 +25,7 @@ import pytest
 from jax.sharding import AbstractMesh, PartitionSpec as P
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models.common import QuantMaker
 from repro.models import transformer as T
 from repro.quant.policy import PrecisionPolicy, validate_kv_tier
@@ -38,7 +39,7 @@ multi_device = pytest.mark.skipif(
 
 
 def _amesh(dp, tp):
-    return AbstractMesh((("data", dp), ("model", tp)))
+    return AbstractMesh((dp, tp), ("data", "model"))
 
 
 # ---------------------------------------------------------------------------
@@ -237,7 +238,7 @@ def test_plan_adapter_bit_identical_to_policy_weights_under_mesh():
     params = T.build_params(cfg, QuantMaker(jax.random.PRNGKey(0), plan=plan))
     batch = {"tokens": np.random.default_rng(12).integers(
         1, cfg.vocab, (2, 7)).astype(np.int32)}
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     via_plan = ServingEngine(cfg, params, ServeConfig(
         max_len=32, n_slots=2, prefill_chunk=8, mesh=mesh), plan=plan)
     via_policy = ServingEngine(cfg, params, ServeConfig(
@@ -376,7 +377,7 @@ def test_legacy_kv_dtype_adapter_bit_identical_dp2_tp4():
         1, cfg.vocab, (4, 9)).astype(np.int32)}
 
     def build(**kw):
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = make_mesh((2, 4), ("data", "model"))
         return ServingEngine(cfg, params, ServeConfig(
             max_len=32, n_slots=8, prefill_chunk=8, mesh=mesh, **kw))
 
@@ -412,7 +413,7 @@ def test_mixed_tier_engine_bit_identical_per_tier_dp2_tp4():
         return _run_tiered(eng, jobs_ref)[0]
 
     ref = {t: single(t) for t in ("bf16", "int8")}
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     eng = ServingEngine(cfg, params, ServeConfig(
         max_len=32, n_slots=8, prefill_chunk=8, mesh=mesh))
     tiers = ("bf16", "int8", "int8", "bf16")

@@ -83,3 +83,25 @@ def test_mac_commutes_with_float_math(data):
     # int4*bf16 product is exact in fp32; + bf16 exact in fp32 window
     expect = np.float32(a_v * b_v) + np.float32(c_v)
     np.testing.assert_allclose(out, expect, rtol=1e-6)
+
+
+@pytest.mark.parametrize("scheme_name", ["awq_int4", "w8a8", "fp8", "mxfp4"])
+@given(data=st.data())
+@settings(max_examples=10, deadline=None)
+def test_quantize_weights_jnp_matches_numpy(scheme_name, data):
+    """The traceable quantizer (serving weights built on the device) emits
+    the numpy quantizer's codes and scales bit for bit."""
+    import jax
+    from repro.quant.schemes import quantize_weights_jnp
+    scheme = get_scheme(scheme_name)
+    rng = np.random.default_rng(data.draw(st.integers(0, 2**31)))
+    g = 128 if scheme.group_size == -1 else scheme.group_size
+    k = g * data.draw(st.integers(1, 3))
+    n = data.draw(st.integers(1, 6)) * 8
+    w = (rng.standard_normal((k, n))
+         * 10.0 ** data.draw(st.integers(-3, 2))).astype(np.float32)
+    want = quantize_weights(scheme, w)
+    packed, scales = jax.jit(
+        lambda w: quantize_weights_jnp(scheme, w))(w)
+    np.testing.assert_array_equal(np.asarray(packed), np.asarray(want.packed))
+    np.testing.assert_array_equal(np.asarray(scales), np.asarray(want.scales))

@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models.common import InitMaker, QuantMaker
 from repro.models import transformer as T
 from repro.serve import (Request, RequestState, SamplingParams, Scheduler,
@@ -164,7 +165,7 @@ def test_preempt_resume_bit_identical_dp2_tp4(setup):
     base.run(max_steps=500)
     ref = _outputs(base)
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     s = _contended_run(engine(mesh), prompts, 0.0)
     assert sum(r.n_preemptions for r in s.finished) >= 1
     assert _outputs(s) == ref

@@ -16,6 +16,7 @@ import numpy as np
 import pytest
 
 from repro.configs import get_config
+from repro.launch.mesh import make_mesh
 from repro.models.common import InitMaker, QuantMaker
 from repro.models import transformer as T
 from repro.serve import (Request, SamplingParams, ServeConfig,
@@ -129,7 +130,7 @@ def test_spec_bit_identical_mesh_1x1(setup, engine):
     shardings, donation) — fast-loop coverage of the §10 plumbing."""
     prompts = _prompts(engine, 2, [9, 6], seed=4)
     ref, _ = _run(engine, prompts, max_new=13)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     meng = make_engine(setup, mesh=mesh)
     got, s = _run(meng, prompts, max_new=13, spec=SpecConfig())
     assert got == ref
@@ -179,7 +180,7 @@ def test_spec_dp2_tp4_bit_identical():
     def eng():
         return ServingEngine(cfg, params, ServeConfig(
             max_len=32, n_slots=8, prefill_chunk=8, kv_dtype="int8",
-            max_burst=8, mesh=jax.make_mesh((2, 4), ("data", "model"))))
+            max_burst=8, mesh=make_mesh((2, 4), ("data", "model"))))
 
     for temp, seed in ((0.0, 0), (0.7, 5)):
         ref, _ = _run(eng(), prompts, max_new=13,

@@ -61,12 +61,12 @@ def test_compression_error_feedback_unbiased():
 
 
 def test_compressed_psum_agrees_with_mean():
+    from repro.launch.mesh import make_mesh
     from repro.optim import compressed_psum, init_compression
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     if len(jax.devices()) < 1:
         pytest.skip("no devices")
-    mesh = jax.make_mesh((1,), ("pod",))
+    mesh = make_mesh((1,), ("pod",))
     g = jnp.asarray(np.random.default_rng(1).standard_normal((16,)),
                     jnp.float32)
     state = init_compression({"g": g})
@@ -76,7 +76,8 @@ def test_compressed_psum_agrees_with_mean():
             {"g": err}), "pod")
         return out["g"], new_state.error["g"]
 
-    fn = shard_map(body, mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(P(), P()),
+                       out_specs=(P(), P()))
     out, _ = fn(g, state.error["g"])
     np.testing.assert_allclose(np.asarray(out), np.asarray(g), atol=0.05)
 

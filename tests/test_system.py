@@ -10,6 +10,7 @@ import numpy as np
 import pytest
 
 from repro.configs import ARCH_IDS, get_config
+from repro.launch.mesh import make_mesh
 from repro.models.common import InitMaker, QuantMaker
 from repro.models import transformer as T
 
@@ -62,7 +63,7 @@ def test_param_specs_match_tree(arch):
     both the dense (train) and quantized (serve) parameterizations."""
     from repro.runtime import partitioning as PT
     from repro.launch.steps import abstract_params
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     for train_mode in (True, False):
         params = abstract_params(get_config(arch), quantize=not train_mode)
         specs = PT.param_specs(get_config(arch), mesh, train=train_mode)
@@ -78,12 +79,13 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import get_config
 from repro.configs.shapes import ShapeSpec
+from repro.launch.mesh import make_mesh
 from repro.launch.steps import build_cell
 from repro.models.common import InitMaker
 from repro.models import transformer as T
 from repro.optim import AdamWConfig, adamw_init
 cfg = get_config("granite-8b", smoke=True)
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 shape = ShapeSpec("t", 32, 8, "train")
 fn, args, in_sh, out_sh, donate = build_cell(cfg, shape, mesh)
 params = T.build_params(cfg, InitMaker(jax.random.PRNGKey(0)))
@@ -109,5 +111,27 @@ def test_pjit_train_step_runs_on_8_devices():
     r = subprocess.run([sys.executable, "-c", _SUBPROCESS_PJIT],
                        capture_output=True, text=True, timeout=900,
                        env={**os.environ, "PYTHONPATH": "src"},
-                       cwd="/root/repo")
+                       cwd=os.path.dirname(os.path.dirname(
+                           os.path.abspath(__file__))))
     assert "SUBPROCESS_OK" in r.stdout, r.stderr[-2000:]
+
+
+def test_compile_cache_dir_env_or_fixed_checkout_path(monkeypatch, tmp_path):
+    """The persistent compile cache goes where JAX_COMPILATION_CACHE_DIR
+    says, else to one fixed, git-ignored directory in the checkout."""
+    import os
+    from repro.launch.cli import enable_compile_cache
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert enable_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        path = enable_compile_cache()
+        assert path == os.path.join(root, ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+        with open(os.path.join(root, ".gitignore")) as f:
+            assert ".jax_cache/" in f.read().split()
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

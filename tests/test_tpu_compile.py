@@ -1,0 +1,118 @@
+"""The serving kernels compile for a TPU v5e at real model widths.
+
+Interpret mode (the CPU test backend) accepts block shapes and in-kernel
+layouts that Mosaic, the TPU kernel compiler, refuses.  These tests lower
+the kernels with ``interpret=False`` against a described (not attached)
+v5e and compile them, so a kernel the chip would refuse fails here.
+Nothing runs: the tests check that the compiled program holds the kernel
+(``tpu_custom_call``), not what it computes.
+
+The topology is described inside a module-scoped fixture, never at import
+time: only one process may load the TPU library, and every test worker
+imports this file.
+"""
+from __future__ import annotations
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.kernels.decode_attention import gqa_decode_attention
+from repro.kernels.packed_matmul import packed_gemv, packed_matmul
+from repro.quant.kv_cache import QuantizedKV
+from repro.quant.schemes import QuantizedLinearWeights, get_scheme
+from repro.serve.engine import ServeConfig
+
+GRANITE = get_config("granite-8b")
+QWEN_MOE = get_config("qwen3-moe-30b-a3b")
+DECODE_SLOTS = 8
+DECODE_CONTEXT = 2048
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:   # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip is written to the persistent cache but
+    # cannot be read back without one: keep the cache out of these tests
+    enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", enabled)
+
+
+def _compile(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("kv", ["bf16", "int8"])
+@pytest.mark.parametrize("heads", [
+    (GRANITE.n_heads, GRANITE.n_kv_heads, GRANITE.d_head),
+    (QWEN_MOE.n_heads, QWEN_MOE.n_kv_heads, QWEN_MOE.d_head),
+    # one shard of qwen3-moe on a 4-way model axis
+    (QWEN_MOE.n_heads // 4, QWEN_MOE.n_kv_heads // 4, QWEN_MOE.d_head),
+], ids=["granite-8b", "qwen3-moe", "qwen3-moe-tp4-shard"])
+def test_decode_attention_compiles(one_chip, heads, kv):
+    h, hk, dh = heads
+    b, sk = DECODE_SLOTS, DECODE_CONTEXT
+    q = ((b, 1, h, dh), jnp.bfloat16)
+    lens = ((b,), jnp.int32)
+    if kv == "bf16":
+        cache = ((b, sk, hk, dh), jnp.bfloat16)
+        hlo = _compile(lambda q, k, v, n: gqa_decode_attention(
+            q, k, v, n, interpret=False), one_chip, q, cache, cache, lens)
+    else:
+        packed = ((b, sk, hk, dh // 4), jnp.int32)
+        scales = ((b, sk, hk), jnp.float32)
+
+        def fn(q, kp, ks, vp, vs, n):
+            return gqa_decode_attention(
+                q, QuantizedKV(kp, ks, kv), QuantizedKV(vp, vs, kv), n,
+                interpret=False)
+        hlo = _compile(fn, one_chip, q, packed, scales, packed, scales, lens)
+    assert "tpu_custom_call" in hlo
+
+
+# granite-8b projection widths (K, N): wq/wo, wk/wv, w_gate/w_up, w_down
+GRANITE_WIDTHS = [
+    (GRANITE.d_model, GRANITE.n_heads * GRANITE.d_head),
+    (GRANITE.d_model, GRANITE.n_kv_heads * GRANITE.d_head),
+    (GRANITE.d_model, GRANITE.d_ff),
+    (GRANITE.d_ff, GRANITE.d_model),
+]
+
+
+def _packed_weight_shapes(scheme_name: str, k: int, n: int):
+    s = get_scheme(scheme_name)
+    return (((k // (32 // s.weight_bits), n), jnp.int32),
+            ((k // s.group_size, n), jnp.float32))
+
+
+@pytest.mark.parametrize("k,n", GRANITE_WIDTHS,
+                         ids=[f"{k}x{n}" for k, n in GRANITE_WIDTHS])
+@pytest.mark.parametrize("m", [DECODE_SLOTS, ServeConfig().prefill_chunk],
+                         ids=["decode", "prefill-chunk"])
+def test_packed_awq_int4_compiles(one_chip, m, k, n):
+    """The decode GEMV (M <= 8) and the prefill-chunk matmul, at every
+    granite-8b projection width, as ``quantized_matmul`` dispatches them."""
+    scheme = get_scheme("awq_int4")
+    kernel = packed_gemv if m <= 8 else packed_matmul
+
+    def fn(x, packed, scales):
+        qw = QuantizedLinearWeights(scheme, packed, scales, (k, n))
+        return kernel(x, qw, interpret=False)
+    hlo = _compile(fn, one_chip, ((m, k), jnp.bfloat16),
+                   *_packed_weight_shapes("awq_int4", k, n))
+    assert "tpu_custom_call" in hlo
