@@ -209,35 +209,32 @@ class QuantMaker(InitMaker):
         self.plan = dict(plan or {})
         self.specs = specs
 
-    def _sharding(self, spec):
+    def _placement(self, kind, *args):
+        """NamedShardings of the leaf the spec maker's ``kind`` method
+        describes, or None without a spec maker (the default device)."""
         if self.specs is None:
             return None
         from jax.sharding import NamedSharding
         return jax.tree_util.tree_map(
-            lambda p: NamedSharding(self.specs.mesh, p), spec,
+            lambda p: NamedSharding(self.specs.mesh, p),
+            getattr(self.specs, kind)(*args),
             is_leaf=lambda x: isinstance(x, P))
-
-    def _make(self, fn, spec):
-        return jax.jit(fn, out_shardings=self._sharding(spec))()
 
     def dense(self, name, stack, k, n, scheme=None):
         scheme = self.plan.get(name, scheme)
         scheme = scheme if scheme is not None else "bf16"
-        spec = None if self.specs is None \
-            else self.specs.dense(name, stack, k, n, scheme)
+        sh = self._placement("dense", name, stack, k, n, scheme)
         key = self._next()
         if scheme == "bf16":
-            return self._make(
-                lambda: _draw_dense(key, stack + (k, n), self.dtype), spec)
-        s = get_scheme(scheme)
-        abstract = AbstractMaker().dense(name, stack, k, n, scheme)
-        sh = self._sharding(spec)
+            return jax.jit(lambda: _draw_dense(key, stack + (k, n), self.dtype),
+                           out_shardings=sh)()
         sh_p, sh_s = (None, None) if sh is None else (sh.packed, sh.scales)
         if not stack:
             packed, scales = jax.jit(
-                lambda: _draw_quantized(key, s, (), k, n),
+                lambda: _draw_quantized(key, get_scheme(scheme), (), k, n),
                 out_shardings=(sh_p, sh_s))()
             return QLinear(packed, scales, scheme, (k, n), name)
+        abstract = AbstractMaker().dense(name, stack, k, n, scheme)
         packed = jnp.zeros(abstract.packed.shape, abstract.packed.dtype,
                            device=sh_p)
         scales = jnp.zeros(abstract.scales.shape, jnp.float32, device=sh_s)
@@ -249,20 +246,19 @@ class QuantMaker(InitMaker):
 
     def table(self, name, stack, rows, cols, scale=0.02):
         key = self._next()
-        return self._make(
+        return jax.jit(
             lambda: _draw_table(key, stack + (rows, cols), scale, self.dtype),
-            None if self.specs is None
-            else self.specs.table(name, stack, rows, cols, scale))
+            out_shardings=self._placement("table", name, stack, rows, cols,
+                                          scale))()
 
     def norm(self, name, stack, dim):
-        return jnp.ones(stack + (dim,), jnp.float32, device=self._sharding(
-            None if self.specs is None else self.specs.norm(name, stack, dim)))
+        return jnp.ones(stack + (dim,), jnp.float32,
+                        device=self._placement("norm", name, stack, dim))
 
     def vector(self, name, stack, dim, init=0.0):
         return jnp.full(stack + (dim,), init, jnp.float32,
-                        device=self._sharding(
-                            None if self.specs is None
-                            else self.specs.vector(name, stack, dim, init)))
+                        device=self._placement("vector", name, stack, dim,
+                                               init))
 
 
 def _draw_dense(key, shape, dtype):
