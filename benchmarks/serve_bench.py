@@ -73,13 +73,10 @@ the bf16 slots from the same budget, served from the same engine.
 ``PrecisionPolicy`` instead of the legacy flags (which keep working and
 print their policy equivalent).
 
-Every point carries a ``model_measured`` block (DESIGN.md §13): per-
-step-shape and per-KV-tier model/measured ratios joining each dispatch's
-host wall against the analytical decode model (perfmodel/analytical.py)
-priced at the pool tier's KV bytes/token.  ``--trace-dir`` additionally
-writes per-point Chrome traces (Perfetto-loadable), Prometheus-style
-expositions and registry snapshots; ``--hlo-cost`` joins trip-count-aware
-FLOP/byte counts of the compiled step.
+``--trace-dir`` writes per-point Chrome traces (Perfetto-loadable),
+Prometheus-style expositions and registry snapshots (DESIGN.md §13);
+``--hlo-cost`` joins trip-count-aware FLOP/byte counts of the compiled
+step.
 
 **Adversarial workloads + SLO (DESIGN.md §16)**: ``--arrival bursty
 --burst-size B`` replaces the smooth Poisson process with Poisson-spaced
@@ -437,14 +434,11 @@ def run_point(args, cfg, engine, kv_dtype, tiers=None, arm_fault=None):
     requests assigned tiers round-robin (``Request.kv_policy``) so
     bf16/int8/fp8 traffic interleaves, mid-flight admission included.
 
-    Every point runs with the model-vs-measured profiler attached
-    (DESIGN.md §13) — the sweep JSON carries per-tier and per-step-shape
-    model/measured ratios, which is what makes a KV-tier sweep comparable
-    against the analytical model rather than only against itself.  With
-    ``--trace-dir`` the point additionally writes a Chrome trace, a
-    Prometheus-style exposition and periodic registry snapshots."""
+    With ``--trace-dir`` the point also writes a Chrome trace, a
+    Prometheus-style exposition and periodic registry snapshots
+    (DESIGN.md §13)."""
     from repro.obs import (MetricsRegistry, Observability, SnapshotWriter,
-                           StepProfiler, Tracer)
+                           Tracer)
     from repro.serve import Request, SamplingParams, Scheduler
     arrivals, prompts, priorities = make_workload(args, cfg.vocab)
     spec = build_spec(args)
@@ -458,17 +452,17 @@ def run_point(args, cfg, engine, kv_dtype, tiers=None, arm_fault=None):
     if slo is not None:
         print(f"== slo: {json.dumps(slo.snapshot())}")
 
-    obs = Observability(profiler=StepProfiler(cfg))
-    stem = None
+    obs = stem = None
     if args.trace_dir:
         os.makedirs(args.trace_dir, exist_ok=True)
         stem = os.path.join(args.trace_dir,
                             point_label(cfg, kv_dtype, tiers, args.max_burst,
                                         args.weight_kernel, args.paged,
                                         args=args))
-        obs.tracer = Tracer()
-        obs.registry = MetricsRegistry()
-        obs.snapshots = SnapshotWriter(obs.registry, stem + ".metrics.jsonl")
+        registry = MetricsRegistry()
+        obs = Observability(tracer=Tracer(), registry=registry,
+                            snapshots=SnapshotWriter(
+                                registry, stem + ".metrics.jsonl"))
     sched = Scheduler(engine, tiers=tiers, obs=obs, slo=slo, spec=spec)
     for tier, pool in sorted(sched.pools.items()):
         print(f"== pool[{tier}]: {pool.n_slots} slots x {pool.max_len} "
@@ -623,12 +617,10 @@ def run_point(args, cfg, engine, kv_dtype, tiers=None, arm_fault=None):
                                       if dpool is not None else None))
     if args.cache_budget_mb:
         rep["cache_budget_mb"] = args.cache_budget_mb
-    # model-vs-measured join (always on): per step shape and per KV tier
-    rep["model_measured"] = obs.profiler.report()
     if args.hlo_cost:
         # static compiled-step costs per pool (trip-count-aware HLO walk);
         # offline lowering — never touches the timed run above
-        from repro.obs import compiled_step_cost
+        from repro.launch.hlo_analysis import compiled_step_cost
         rep["compiled_step_cost"] = {
             t: compiled_step_cost(engine, p)
             for t, p in sorted(sched.pools.items())}

@@ -218,7 +218,7 @@ def gqa_decode_attention(q, k_cache, v_cache, kv_valid_len, *, bk=None,
     out, _, _ = pl.pallas_call(
         kernel, grid=grid, in_specs=in_specs,
         out_specs=(o_spec, ml_spec, ml_spec), out_shape=out_shape,
-        interpret=resolve_interpret(interpret),
+        interpret=resolve_interpret(interpret), name="decode_attention",
     )(*args)
     return out.reshape(b, 1, h, dh).astype(q.dtype)
 

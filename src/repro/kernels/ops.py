@@ -317,9 +317,21 @@ def quantized_matmul(x, qw: QuantizedLinearWeights, *,
         out = jnp.dot(x2.astype(jnp.bfloat16), qw.packed)
         return out.reshape(*lead, -1).astype(out_dtype)
 
+    # every op of a quantized weight's product, whatever implements it
+    # (jnp dequantize-and-dot, the Pallas kernel, its shard_map), carries
+    # this scope in its op_name: the benchmark reads device time by it
+    with jax.named_scope("qmatmul"):
+        out = _quantized_dot(x2, qw, use_kernel, interpret)
+        return out.reshape(*lead, -1).astype(out_dtype)
+
+
+def _quantized_dot(x2, qw: QuantizedLinearWeights, use_kernel, interpret):
+    """x2 [M, K] @ quantized W -> [M, N] (f32 or bf16) for a non-bf16
+    scheme: the dispatch of ``quantized_matmul``."""
+    scheme = qw.scheme
     if use_kernel is None:
         use_kernel = resolved_kernel_mode() == "pallas"
-        site = qw.name or f"<{scheme.name} linear K={k}>"
+        site = qw.name or f"<{scheme.name} linear K={x2.shape[-1]}>"
         if use_kernel:
             mesh = _EXEC["mesh"]
             if _EXEC["partitioned"] and mesh is None:
@@ -329,7 +341,7 @@ def quantized_matmul(x, qw: QuantizedLinearWeights, *,
             elif mesh is not None and mesh.size > 1:
                 out = _mesh_quantized_matmul(x2, qw, mesh, interpret, site)
                 if out is not None:
-                    return out.reshape(*lead, -1).astype(out_dtype)
+                    return out
                 use_kernel = False
     else:
         use_kernel = kernel_allowed(use_kernel)
@@ -337,21 +349,17 @@ def quantized_matmul(x, qw: QuantizedLinearWeights, *,
     if scheme.name == "w8a8":
         x_codes, x_scale = quantize_activations_int8(x2)
         if use_kernel:
-            out = w8a8_matmul(x_codes, x_scale, qw.packed, qw.scales,
-                              interpret=interpret)
-        else:
-            out = ref.w8a8_matmul_ref(x_codes, x_scale, qw.packed, qw.scales)
-    elif scheme.packed:  # awq_int4 / mxfp4 / fp8 — sub-byte/byte packed words
+            return w8a8_matmul(x_codes, x_scale, qw.packed, qw.scales,
+                               interpret=interpret)
+        return ref.w8a8_matmul_ref(x_codes, x_scale, qw.packed, qw.scales)
+    if scheme.packed:  # awq_int4 / mxfp4 / fp8 — sub-byte/byte packed words
         if use_kernel:
             fn = packed_gemv if x2.shape[0] <= 8 else packed_matmul
-            out = fn(x2, qw, interpret=interpret)
-        else:
-            # jnp fallback: dequantize INTO bf16 — exactly the paper's
-            # Stage-1 mapping (the INTxFP product's FP side is BF16); the
-            # Pallas kernel keeps the fused f32-accumulate version
-            from repro.quant.schemes import dequantize
-            w = dequantize(qw, dtype=jnp.bfloat16)
-            out = jnp.dot(x2.astype(jnp.bfloat16), w)
-    else:
-        raise ValueError(scheme.name)
-    return out.reshape(*lead, -1).astype(out_dtype)
+            return fn(x2, qw, interpret=interpret)
+        # jnp fallback: dequantize INTO bf16 — exactly the paper's
+        # Stage-1 mapping (the INTxFP product's FP side is BF16); the
+        # Pallas kernel keeps the fused f32-accumulate version
+        from repro.quant.schemes import dequantize
+        w = dequantize(qw, dtype=jnp.bfloat16)
+        return jnp.dot(x2.astype(jnp.bfloat16), w)
+    raise ValueError(scheme.name)

@@ -153,10 +153,12 @@ def packed_shapes_legal(m: int, k: int, n: int, scheme: QuantScheme) -> bool:
 
 @functools.partial(
     jax.jit,
-    static_argnames=("scheme_name", "k", "n", "bm", "bn", "bk", "interpret"),
+    static_argnames=("scheme_name", "k", "n", "bm", "bn", "bk", "interpret",
+                     "name"),
 )
 def _packed_matmul_impl(x, packed, scales, *, scheme_name: str, k: int, n: int,
-                        bm: int, bn: int, bk: int, interpret: bool):
+                        bm: int, bn: int, bk: int, interpret: bool,
+                        name: str):
     from repro.quant.schemes import get_scheme
     scheme = get_scheme(scheme_name)
     m = x.shape[0]
@@ -182,12 +184,14 @@ def _packed_matmul_impl(x, packed, scales, *, scheme_name: str, k: int, n: int,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
+        name=name,
     )(x, packed, scales)
 
 
 def packed_matmul(x, qw: QuantizedLinearWeights, *, bm: int = 128, bn: int = 128,
-                  bk: int = 512, interpret=None):
-    """x [M, K] (bf16) @ packed W [K, N] -> f32 [M, N]."""
+                  bk: int = 512, interpret=None, name: str = "packed_matmul"):
+    """x [M, K] (bf16) @ packed W [K, N] -> f32 [M, N].  ``name`` is the
+    kernel's stable name in profiles and op metadata."""
     k, n = qw.shape
     m = x.shape[0]
     scheme = qw.scheme
@@ -198,13 +202,15 @@ def packed_matmul(x, qw: QuantizedLinearWeights, *, bm: int = 128, bn: int = 128
     return _packed_matmul_impl(
         x, qw.packed, qw.scales, scheme_name=scheme.name, k=k, n=n,
         bm=bm, bn=bn, bk=bk, interpret=resolve_interpret(interpret),
+        name=name,
     )
 
 
 def packed_gemv(x, qw: QuantizedLinearWeights, *, bn: int = 256, bk: int = 1024,
                 interpret=None):
     """Decode-shape GEMV: x [B, K] with small B (the paper's Section VI-C)."""
-    return packed_matmul(x, qw, bm=x.shape[0], bn=bn, bk=bk, interpret=interpret)
+    return packed_matmul(x, qw, bm=x.shape[0], bn=bn, bk=bk,
+                         interpret=interpret, name="packed_gemv")
 
 
 # ---------------------------------------------------------------------------
@@ -240,6 +246,7 @@ def _w8a8_impl(x_codes, w_codes, *, bm, bn, bk, interpret):
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         interpret=interpret,
+        name="w8a8_matmul",
     )(x_codes, w_codes)
 
 

@@ -98,6 +98,7 @@ def _vdsp_impl(a_mags, b_mags, *, plan: LanePlan, bt: int, interpret: bool):
         out_specs=pl.BlockSpec((bt, plan.parallelism), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((t, plan.parallelism), jnp.int32),
         interpret=interpret,
+        name="xtramac_mac",
     )(a_mags, b_mags)
 
 

@@ -394,3 +394,52 @@ def top_bytes_contributors(hlo_text: str, n: int = 25):
         visit(entry, 1)
     rows.sort(reverse=True)
     return rows[:n]
+
+
+def compiled_step_cost(engine, pool, k: int = 1) -> Dict:
+    """Trip-count-aware FLOP/byte counts of the COMPILED decode step for
+    ``pool``'s geometry (``analyze`` over the post-optimization HLO text):
+    what the program does per dispatch, independent of how long the host
+    took.
+
+    ``k > 1`` analyzes the K-step burst scan (the scan body is multiplied
+    by its known trip count).  This lowers and compiles the step outside
+    the engine's jit cache, so it is an offline/diagnostic call, not a
+    hot-path one.
+    """
+    import jax
+    import jax.numpy as jnp
+
+    n = pool.n_slots
+    f32 = jnp.float32
+
+    def spec(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    cache = jax.tree_util.tree_map(
+        lambda a: spec(a.shape, a.dtype), pool.cache)
+    row_i32 = spec((n,), jnp.int32)
+    paged = getattr(pool, "paged", False)
+    table = (spec(pool.page_table.shape, jnp.int32),) if paged else ()
+    if k <= 1:
+        fn = engine._decode_slots_paged_fn if paged \
+            else engine._decode_slots_fn
+        lowered = jax.jit(fn).lower(
+            engine.params, spec((n, 1), jnp.int32), cache, row_i32,
+            spec((n, 2), jnp.uint32), spec((n,), f32), *table)
+    else:
+        fn = engine._decode_burst_paged_fn if paged \
+            else engine._decode_burst_fn
+        lowered = jax.jit(fn).lower(
+            engine.params, cache, row_i32, row_i32, spec((n,), jnp.bool_),
+            row_i32, spec((k, n, 2), jnp.uint32), spec((n,), f32), row_i32,
+            jnp.int32(pool.max_len), *table)
+    cost = analyze(lowered.compile().as_text())
+    steps = k * n
+    return {"k": k, "n_slots": n, "kv_dtype": pool.kv_dtype,
+            "paged": paged,
+            **({"n_pages": pool.n_pages} if paged else {}),
+            "flops": cost.flops, "hbm_bytes": cost.hbm_bytes,
+            "collective_bytes": cost.collective_bytes,
+            "flops_per_token_step": round(cost.flops / steps, 1),
+            "hbm_bytes_per_token_step": round(cost.hbm_bytes / steps, 1)}

@@ -110,19 +110,17 @@ def main():
         batch["frames"] = jnp.full((args.batch, cfg.n_frames, cfg.d_model),
                                    0.02, jnp.bfloat16)
 
-    # observability bundle for the timed run (DESIGN.md §13): tracing,
-    # registry + snapshots, and the model-vs-measured profiler.  Only
-    # built when a sink was requested — otherwise the scheduler runs its
-    # zero-overhead disabled path.
+    # observability bundle for the timed run (DESIGN.md §13): tracing and
+    # registry + snapshots.  Only built when a sink was requested —
+    # otherwise the scheduler runs its zero-overhead disabled path.
     obs = None
     if args.trace or args.metrics_out:
         from repro.obs import (MetricsRegistry, Observability,
-                               SnapshotWriter, StepProfiler, Tracer)
+                               SnapshotWriter, Tracer)
         registry = MetricsRegistry() if args.metrics_out else None
         obs = Observability(
             tracer=Tracer() if args.trace else None,
             registry=registry,
-            profiler=StepProfiler(cfg),
             snapshots=SnapshotWriter(registry, args.metrics_out + ".jsonl")
             if registry is not None else None)
 
@@ -167,8 +165,6 @@ def main():
         if obs.tracer is not None and len(obs.tracer):
             obs.tracer.write(args.trace)
             print(f"trace: {args.trace} ({len(obs.tracer)} events)")
-        if obs.profiler is not None and obs.profiler.n_records:
-            report["model_measured"] = obs.profiler.report()
         if obs.registry is not None:
             with open(args.metrics_out, "w") as f:
                 f.write(obs.registry.expose())

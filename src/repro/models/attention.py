@@ -223,7 +223,8 @@ def gqa_forward(params, cfg: AttnConfig, x, *, positions=None,
     arena = None
     if cache is not None and page_table is not None:
         arena = cache
-        cache = tuple(gather_pages(a, page_table) for a in arena)
+        with jax.named_scope("attn_core"):
+            cache = tuple(gather_pages(a, page_table) for a in arena)
     q = shard_act(apply_linear(params["wq"], x).reshape(b, s, h, dh), "bthd")
     k = shard_act(apply_linear(params["wk"], x).reshape(b, s, hk, dh), "bthd")
     v = shard_act(apply_linear(params["wv"], x).reshape(b, s, hk, dh), "bthd")
@@ -237,42 +238,44 @@ def gqa_forward(params, cfg: AttnConfig, x, *, positions=None,
         q = apply_rope(q, positions, cfg.rope_theta)
         k = apply_rope(k, positions, cfg.rope_theta)
 
-    new_cache = None
-    if cache is not None:
-        k_cache, v_cache = cache
-        if per_row:
-            assert s == 1, "per-row cache_index is a decode-only path"
-            rows = jnp.arange(b)
-            k_cache = cache_write_rows(k_cache, k, rows, cache_index)
-            v_cache = cache_write_rows(v_cache, v, rows, cache_index)
+    # cache write and read, scores, softmax, PV: up to the output projection
+    with jax.named_scope("attn_core"):
+        new_cache = None
+        if cache is not None:
+            k_cache, v_cache = cache
+            if per_row:
+                assert s == 1, "per-row cache_index is a decode-only path"
+                rows = jnp.arange(b)
+                k_cache = cache_write_rows(k_cache, k, rows, cache_index)
+                v_cache = cache_write_rows(v_cache, v, rows, cache_index)
+            else:
+                k_cache = cache_write_slice(k_cache, k, cache_index)
+                v_cache = cache_write_slice(v_cache, v, cache_index)
+            new_cache = (k_cache, v_cache)
+
+        if cache is None or attend_local:
+            out = attend(q, k, v, causal=cfg.causal, q_offset=0,
+                         kv_chunk=cfg.kv_chunk)
         else:
-            k_cache = cache_write_slice(k_cache, k, cache_index)
-            v_cache = cache_write_slice(v_cache, v, cache_index)
-        new_cache = (k_cache, v_cache)
+            k_cache, v_cache = new_cache
+            valid = jnp.broadcast_to(
+                jnp.asarray(cache_index + s, jnp.int32), (b,))
+            out = None
+            if s == 1 and cfg.causal:
+                # fused flash-decode when the execution policy selects it:
+                # streams (packed) KV blocks straight from the pool slab,
+                # dequantizes in-kernel, no [B,S,H,D] copy — shard_map'd over
+                # a declared mesh (slots on 'data', KV heads on 'model')
+                from repro.kernels.ops import fused_decode_attention
+                out = fused_decode_attention(q, k_cache, v_cache, valid)
+            if out is None:
+                out = attend(q, cache_read(k_cache), cache_read(v_cache),
+                             causal=cfg.causal, q_offset=cache_index,
+                             kv_chunk=cfg.kv_chunk, kv_valid_len=valid)
 
-    if cache is None or attend_local:
-        out = attend(q, k, v, causal=cfg.causal, q_offset=0,
-                     kv_chunk=cfg.kv_chunk)
-    else:
-        k_cache, v_cache = new_cache
-        valid = jnp.broadcast_to(
-            jnp.asarray(cache_index + s, jnp.int32), (b,))
-        out = None
-        if s == 1 and cfg.causal:
-            # fused flash-decode when the execution policy selects it:
-            # streams (packed) KV blocks straight from the pool slab,
-            # dequantizes in-kernel, no [B,S,H,D] copy — shard_map'd over
-            # a declared mesh (slots on 'data', KV heads on 'model')
-            from repro.kernels.ops import fused_decode_attention
-            out = fused_decode_attention(q, k_cache, v_cache, valid)
-        if out is None:
-            out = attend(q, cache_read(k_cache), cache_read(v_cache),
-                         causal=cfg.causal, q_offset=cache_index,
-                         kv_chunk=cfg.kv_chunk, kv_valid_len=valid)
-
-    if arena is not None and new_cache is not None:
-        new_cache = tuple(scatter_pages(a, page_table, v)
-                          for a, v in zip(arena, new_cache))
+        if arena is not None and new_cache is not None:
+            new_cache = tuple(scatter_pages(a, page_table, v)
+                              for a, v in zip(arena, new_cache))
     out = out.reshape(b, s, h * dh)
     return apply_linear(params["wo"], out), new_cache
 
@@ -381,7 +384,8 @@ def mla_forward(params, cfg: MLAConfig, x, *, cache=None, cache_index=None,
     arena = None
     if cache is not None and page_table is not None:
         arena = cache
-        cache = tuple(gather_pages(a, page_table) for a in arena)
+        with jax.named_scope("attn_core"):
+            cache = tuple(gather_pages(a, page_table) for a in arena)
     per_row = cache_index is not None and jnp.ndim(cache_index) == 1
     if positions is None:
         base = jnp.asarray(0 if cache_index is None else cache_index)
@@ -394,34 +398,36 @@ def mla_forward(params, cfg: MLAConfig, x, *, cache=None, cache_index=None,
     c_kv = rms_norm(c_kv, params["kv_norm"])
     k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
 
-    new_cache = None
-    valid = None
-    q_off = 0
-    if cache is not None:
-        c_cache, r_cache = cache
-        if per_row:
-            assert s == 1, "per-row cache_index is a decode-only path"
-            rows = jnp.arange(b)
-            new_cache = (cache_write_rows(c_cache, c_kv, rows, cache_index),
-                         cache_write_rows(r_cache, k_rope, rows, cache_index))
-        else:
-            new_cache = (cache_write_slice(c_cache, c_kv, cache_index),
-                         cache_write_slice(r_cache, k_rope, cache_index))
-        if not attend_local:   # attend over the cache (decode / chunked fill)
-            c_kv, k_rope = new_cache
-            valid = jnp.broadcast_to(
-                jnp.asarray(cache_index + s, jnp.int32), (b,))
-            q_off = cache_index
+    with jax.named_scope("attn_core"):
+        new_cache = None
+        valid = None
+        q_off = 0
+        if cache is not None:
+            c_cache, r_cache = cache
+            if per_row:
+                assert s == 1, "per-row cache_index is a decode-only path"
+                rows = jnp.arange(b)
+                new_cache = (
+                    cache_write_rows(c_cache, c_kv, rows, cache_index),
+                    cache_write_rows(r_cache, k_rope, rows, cache_index))
+            else:
+                new_cache = (cache_write_slice(c_cache, c_kv, cache_index),
+                             cache_write_slice(r_cache, k_rope, cache_index))
+            if not attend_local:   # attend over the cache (decode / chunks)
+                c_kv, k_rope = new_cache
+                valid = jnp.broadcast_to(
+                    jnp.asarray(cache_index + s, jnp.int32), (b,))
+                q_off = cache_index
 
-    if s == 1 and cache is not None:
-        out = _mla_decode_absorbed(params, cfg, q_nope, q_rope, c_kv, k_rope,
-                                   valid, q_off)
-    else:
-        out = _mla_expanded(params, cfg, q_nope, q_rope, c_kv, k_rope, valid,
-                            q_off, s)
-    if arena is not None and new_cache is not None:
-        new_cache = tuple(scatter_pages(a, page_table, v)
-                          for a, v in zip(arena, new_cache))
+        if s == 1 and cache is not None:
+            out = _mla_decode_absorbed(params, cfg, q_nope, q_rope, c_kv,
+                                       k_rope, valid, q_off)
+        else:
+            out = _mla_expanded(params, cfg, q_nope, q_rope, c_kv, k_rope,
+                                valid, q_off, s)
+        if arena is not None and new_cache is not None:
+            new_cache = tuple(scatter_pages(a, page_table, v)
+                              for a, v in zip(arena, new_cache))
     return apply_linear(params["wo"], out.reshape(b, s, h * cfg.d_head_v)), new_cache
 
 

@@ -208,30 +208,27 @@ def _tf_block_apply(cfg: ModelConfig, p, x, *, cache=None, cache_index=None,
                     positions=None, enc=None, causal=True, moe_groups=None,
                     attend_local=False, page_table=None):
     """One transformer block.  Returns (x, new_cache, aux)."""
-    h = _apply_norm(cfg, p["ln1"], x)
-    if cfg.use_mla:
-        attn_out, new_cache = A.mla_forward(p["attn"], cfg.mla_cfg(), h,
-                                            cache=cache, cache_index=cache_index,
-                                            positions=positions,
-                                            attend_local=attend_local,
-                                            page_table=page_table)
-    else:
-        attn_out, new_cache = A.gqa_forward(p["attn"], cfg.attn_cfg(causal), h,
-                                            cache=cache, cache_index=cache_index,
-                                            positions=positions,
-                                            attend_local=attend_local,
-                                            page_table=page_table)
+    with jax.named_scope("attn"):
+        h = _apply_norm(cfg, p["ln1"], x)
+        fwd, acfg = (A.mla_forward, cfg.mla_cfg()) if cfg.use_mla \
+            else (A.gqa_forward, cfg.attn_cfg(causal))
+        attn_out, new_cache = fwd(p["attn"], acfg, h, cache=cache,
+                                  cache_index=cache_index, positions=positions,
+                                  attend_local=attend_local,
+                                  page_table=page_table)
     x = x + attn_out
     if enc is not None and "xattn" in p:
         hx = _apply_norm(cfg, p["ln_x"], x)
         x = x + A.cross_attn_forward(p["xattn"], cfg.attn_cfg(False), hx, enc)
-    h2 = _apply_norm(cfg, p["ln2"], x)
     aux = jnp.float32(0.0)
     if "moe" in p:
-        out, aux = MOE.moe_forward(p["moe"], cfg.moe_cfg(), h2,
-                                   n_groups=moe_groups)
+        with jax.named_scope("moe"):
+            h2 = _apply_norm(cfg, p["ln2"], x)
+            out, aux = MOE.moe_forward(p["moe"], cfg.moe_cfg(), h2,
+                                       n_groups=moe_groups)
     else:
-        out = _ffn_apply(cfg, p["ffn"], h2)
+        with jax.named_scope("ffn"):
+            out = _ffn_apply(cfg, p["ffn"], _apply_norm(cfg, p["ln2"], x))
     return x + out, new_cache, aux
 
 
@@ -291,6 +288,7 @@ def _embed(cfg: ModelConfig, params, tokens):
     return params["embed"][tokens].astype(jnp.bfloat16)
 
 
+@jax.named_scope("lm_head")
 def _logits(cfg: ModelConfig, params, x):
     x = _apply_norm(cfg, params["ln_f"], x)
     if cfg.tie_embeddings:

@@ -1,27 +1,27 @@
-"""Serving observability (DESIGN.md §13): structured tracing, a metrics
-registry, and a model-vs-measured profiler for the continuous-batching
-engine.
+"""Serving observability (DESIGN.md §13): spans, structured tracing and a
+metrics registry for the continuous-batching engine.
 
-The three pieces are independent and individually optional; the
-``Observability`` bundle is what the scheduler takes (``Scheduler(engine,
-obs=...)``).  ``obs=None`` (the default) is a strict no-op: the scheduler
-makes zero extra clock calls, zero extra host syncs and zero extra
-dispatches — pinned by tests/test_obs.py.
+``span`` marks the phases of a scheduling round in the ``jax.profiler``
+trace and, when a ``Tracer`` is attached, as Chrome events on the
+scheduler's clock.  The ``Observability`` bundle is what the scheduler
+takes (``Scheduler(engine, obs=...)``).  ``obs=None`` (the default) adds
+nothing observable: the scheduler makes zero extra clock calls, zero extra
+host syncs and zero extra dispatches — pinned by tests/test_obs.py.
 """
 from __future__ import annotations
 
 import dataclasses
 from typing import Optional
 
-from .profiler import StepProfiler, compiled_step_cost
 from .registry import (DEFAULT_TIME_BUCKETS, Counter, Gauge, Histogram,
                        MetricsRegistry, SnapshotWriter)
+from .span import span
 from .trace import PID_REQUESTS, PID_SCHEDULER, Tracer
 
 __all__ = [
     "Counter", "DEFAULT_TIME_BUCKETS", "Gauge", "Histogram",
     "MetricsRegistry", "Observability", "PID_REQUESTS", "PID_SCHEDULER",
-    "SnapshotWriter", "StepProfiler", "Tracer", "compiled_step_cost",
+    "SnapshotWriter", "Tracer", "span",
 ]
 
 
@@ -32,7 +32,6 @@ class Observability:
     without a registry costs nothing registry-shaped."""
     tracer: Optional[Tracer] = None
     registry: Optional[MetricsRegistry] = None
-    profiler: Optional[StepProfiler] = None
     # periodic JSONL snapshots of ``registry`` (scheduler clock timebase)
     snapshots: Optional[SnapshotWriter] = None
 

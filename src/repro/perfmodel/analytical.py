@@ -80,7 +80,7 @@ def gemv_engine_for(scheme: str, fpga: FPGAProfile = V80) -> GemvEngineConfig:
 @functools.lru_cache(maxsize=64)
 def _param_split(cfg: ModelConfig) -> Dict[str, float]:
     """Active parameter counts by role: {'proj': N, 'head': N} per layer sum.
-    Memoized (ModelConfig is frozen/hashable): the serving profiler calls
+    Memoized (ModelConfig is frozen/hashable): the SLO planner calls
     ``decode_latency`` once per distinct step shape and the abstract
     param-tree walk is the dominant cost of each call."""
     from repro.launch.roofline import model_params
@@ -127,8 +127,7 @@ def decode_latency(cfg: ModelConfig, scheme: str, *, batch: int, context: int,
     ``kv_bytes_per_token`` overrides the default bf16 KV storage cost
     (2 slabs x 2 B x Hk x dh x L per cached position) — quantized KV
     tiers (DESIGN.md §9) stream fewer bytes per context position, which
-    is how the serving profiler (obs/profiler.py) prices a pool tier
-    into the prediction.
+    is how a pool tier is priced into the prediction.
 
     ``engine_model`` routes the compute phase through the channel-
     streaming GEMV engine (``gemv_engine_for``) instead of the fabric
@@ -136,7 +135,7 @@ def decode_latency(cfg: ModelConfig, scheme: str, *, batch: int, context: int,
     lane count for the scheme's weight bits, attention at the (4x
     sparser) bf16 lane count, and the memory phase is derated by the
     engine's measured HBM utilization.  This is the per-datatype MAC
-    pricing the serving profiler joins against measurements; the Fig. 14
+    pricing ``serve/slo.py`` plans with; the Fig. 14
     vendor-vs-XtraMAC comparison keeps the table-budget path
     (``engine_model=None``) so its density deltas stay isolated.
     """

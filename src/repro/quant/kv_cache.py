@@ -97,6 +97,7 @@ def kv_slab_spec(shape, kv_dtype):
 # ---------------------------------------------------------------------------
 # Write / read paths (jnp; used inside the jitted engine steps)
 # ---------------------------------------------------------------------------
+@jax.named_scope("kv_write")
 def cache_write_slice(slab, vals, offset):
     """Write ``vals`` [B, S, ...] into ``slab`` at sequence position
     ``offset`` (axis 1) — the prefill/prefill-chunk write.  Quantized slabs
@@ -115,6 +116,7 @@ def cache_write_slice(slab, vals, offset):
         slab, vals.astype(slab.dtype), offset, axis=1)
 
 
+@jax.named_scope("kv_write")
 def cache_write_rows(slab, vals, rows, offsets):
     """Per-row scatter (decode): row i of ``vals`` [B, 1, ...] lands at
     ``slab[i, offsets[i]]`` — every pool slot writes at its own length."""
@@ -165,6 +167,7 @@ def gather_pages(arena, table):
     return g(arena)
 
 
+@jax.named_scope("kv_write")
 def scatter_pages(arena, table, virt):
     """Write a (possibly updated) virtual slab back through the page table.
 

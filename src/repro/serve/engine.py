@@ -78,6 +78,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.models import transformer as T
 from repro.models.common import QLinear
+from repro.obs.span import span
 from repro.quant.policy import PrecisionPolicy, validate_kv_tier
 from repro.runtime.fault_tolerance import StepFault
 
@@ -266,10 +267,11 @@ class ServingEngine:
             logits, _, slot_cache = T.forward(
                 mcfg, params, {"tokens": tokens}, cache=slot_cache,
                 cache_index=offset, mode="prefill_chunk")
-            cache = jax.tree_util.tree_map(
-                lambda pool, upd: jax.lax.dynamic_update_slice_in_dim(
-                    pool, upd, slot, axis=1),
-                cache, slot_cache)
+            with jax.named_scope("kv_write"):
+                cache = jax.tree_util.tree_map(
+                    lambda pool, upd: jax.lax.dynamic_update_slice_in_dim(
+                        pool, upd, slot, axis=1),
+                    cache, slot_cache)
             return (logits[0] if with_logits else None), cache
 
         def decode_slots_logits(params, tokens, cache, lengths):
@@ -785,7 +787,10 @@ class ServingEngine:
                 self.params, jnp.asarray(chunk), pool.cache,
                 jnp.int32(slot), jnp.int32(offset), final)
         pool.lengths[slot] = offset + n
-        return jax.block_until_ready(logits) if final else None
+        if not final:
+            return None
+        with span("serve.sync", what="logits"):
+            return jax.block_until_ready(logits)
 
     def prefill_into_slots(self, pool: KVCachePool, slots: Sequence[int],
                            prompts: Sequence[np.ndarray]) -> List:
@@ -830,7 +835,8 @@ class ServingEngine:
             # page through their unmapped (entry-0) table slots.
             step_args += (jnp.asarray(pool.page_table),)
         toks, pool.cache = decode_slots(*step_args)
-        toks = np.asarray(toks)
+        with span("serve.sync", what="tokens"):
+            toks = np.asarray(toks)
         if poison is not None:
             # poisoned-output simulation: out-of-vocab ids, as a NaN-
             # saturated sampler would produce — the scheduler's validity
@@ -850,7 +856,8 @@ class ServingEngine:
         if getattr(pool, "paged", False):
             step_args += (jnp.asarray(pool.page_table),)
         logits, pool.cache = decode_logits(*step_args)
-        return jax.block_until_ready(logits)
+        with span("serve.sync", what="logits"):
+            return jax.block_until_ready(logits)
 
     def decode_burst(self, pool: KVCachePool, tokens: np.ndarray,
                      key_schedule: np.ndarray, temperatures: np.ndarray,
@@ -886,8 +893,9 @@ class ServingEngine:
             # (``pool.ensure_decode(slots, K, rems)``) before this dispatch
             step_args += (jnp.asarray(pool.page_table),)
         pool.cache, toks, valid = decode_burst(*step_args)
-        toks = np.asarray(toks)                       # the burst's one sync
-        valid = np.asarray(valid)
+        with span("serve.sync", what="tokens"):       # the burst's one sync
+            toks = np.asarray(toks)
+            valid = np.asarray(valid)
         if poison is not None:
             toks = np.full_like(toks, -1)
         pool.lengths += valid.sum(axis=0).astype(np.int32)
@@ -920,7 +928,8 @@ class ServingEngine:
             # (``pool.ensure_decode(slots, S, rems)``) before dispatch
             step_args += (jnp.asarray(pool.page_table),)
         sampled, pool.cache = vs(*step_args)
-        sampled = np.asarray(sampled)             # the round's verify sync
+        with span("serve.sync", what="tokens"):   # the round's verify sync
+            sampled = np.asarray(sampled)
         if poison is not None:
             sampled = np.full_like(sampled, -1)
         return sampled

@@ -22,7 +22,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.obs.span import span
 
+
+@jax.named_scope("sample")
 def sample_rows(logits, keys, temperatures):
     """Batched per-row sampling: logits [N, V], keys [N, 2], temps [N].
     Greedy when a row's temperature <= 0, else temperature-scaled
@@ -41,9 +44,10 @@ sample_tokens = jax.jit(sample_rows)
 def sample_one(logits, key, temperature) -> int:
     """Single-row convenience over ``sample_tokens`` (N=1), so there is
     exactly one sampling rule in the system."""
-    return int(sample_tokens(
-        logits[None], jnp.asarray(key)[None],
-        jnp.asarray([temperature], jnp.float32))[0])
+    tok = sample_tokens(logits[None], jnp.asarray(key)[None],
+                        jnp.asarray([temperature], jnp.float32))
+    with span("serve.sync", what="first_token"):
+        return int(np.asarray(tok)[0])
 
 
 def batched_step_keys(seeds, ids, starts, k: int) -> np.ndarray:
@@ -63,4 +67,6 @@ def batched_step_keys(seeds, ids, starts, k: int) -> np.ndarray:
         return jax.vmap(lambda s: jax.random.fold_in(base, s))(
             n0 + jnp.arange(k))
 
-    return np.asarray(jax.vmap(one)(seeds, ids, starts))
+    keys = jax.vmap(one)(seeds, ids, starts)
+    with span("serve.sync", what="keys"):
+        return np.asarray(keys)

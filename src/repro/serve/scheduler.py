@@ -45,15 +45,20 @@ scheduling logic itself is storage-agnostic — it sees alloc/free/lengths
 per pool, and a request's computation touches only its own tier's slab,
 so traffic at other tiers cannot perturb its tokens.
 
-**Observability** (DESIGN.md §13): ``Scheduler(engine, obs=...)`` attaches
-a ``repro.obs.Observability`` bundle — a Chrome-trace tracer (per-request
-lifecycle spans, per-dispatch prefill/burst events, queue/slot counter
-tracks), a metrics registry (the scheduler publishes gauges and counters;
-``ServeMetrics`` consumes the same registry), and a model-vs-measured
-step profiler.  All trace timestamps come from the scheduler's injectable
-clock, so two virtual-clock runs produce byte-identical trace files.
-``obs=None`` (default) is a strict no-op: zero extra clock calls, zero
-extra host syncs, zero extra dispatches (pinned by tests/test_obs.py).
+**Observability** (DESIGN.md §13): every round marks its phases with
+``repro.obs.span`` — ``serve.step`` around the round, ``serve.admit``,
+``serve.prefill``, ``serve.decode`` and ``serve.emit`` inside it (and the
+engine's ``serve.sync`` around each blocking device->host read) — which
+land in a ``jax.profiler`` trace on the device's clock.
+``Scheduler(engine, obs=...)`` attaches a ``repro.obs.Observability``
+bundle — a Chrome-trace tracer (per-request lifecycle spans, the same
+spans' per-dispatch prefill/burst events, queue/slot counter tracks) and a
+metrics registry (the scheduler publishes gauges and counters;
+``ServeMetrics`` consumes the same registry).  All Chrome timestamps come
+from the scheduler's injectable clock, so two virtual-clock runs produce
+byte-identical trace files.  ``obs=None`` (default) adds nothing the loop
+can observe: zero extra clock calls, zero extra host syncs, zero extra
+dispatches (pinned by tests/test_obs.py).
 
 **SLO-aware scheduling** (DESIGN.md §16): requests carry a priority class
 (smaller = more important) and optional TTFT / e2e deadlines.  Admission
@@ -103,6 +108,7 @@ from typing import Callable, Deque, Dict, List, Mapping, Optional, Sequence, \
 
 import numpy as np
 
+from repro.obs.span import span
 from repro.obs.trace import PID_REQUESTS, PID_SCHEDULER
 from repro.runtime.fault_tolerance import RetryBudget, StepFault
 
@@ -129,8 +135,8 @@ class Scheduler:
         one pool at the engine policy's tier.  ``pool`` injects a single
         pre-built pool instead (mutually exclusive with ``tiers``).
         ``obs``: a ``repro.obs.Observability`` bundle (tracer / registry /
-        profiler / snapshot writer, each optional); None disables all of
-        it at zero cost.  ``slo``: a ``serve.slo.SLOPolicy`` — admission
+        snapshot writer, each optional); None disables all of it at zero
+        cost.  ``slo``: a ``serve.slo.SLOPolicy`` — admission
         control, KV-tier downgrade with hysteresis, and cost-model burst/
         chunk planning (DESIGN.md §16); None keeps the policy-free
         admit-everything scheduler.  ``spec``: a ``serve.spec.SpecConfig``
@@ -196,10 +202,6 @@ class Scheduler:
         self._last_now: Optional[float] = None
         self.obs = obs
         self.tracer = obs.tracer if obs is not None else None
-        self.profiler = obs.profiler if obs is not None else None
-        # timing (clock pair around each engine dispatch) is needed iff
-        # someone consumes it; the disabled path takes neither clock call
-        self._timed = self.tracer is not None or self.profiler is not None
         # speculative decoding (DESIGN.md §17): the draft twin and its
         # K-controller exist only when asked for — spec=None adds zero
         # state, zero dispatches, zero trace events
@@ -403,6 +405,10 @@ class Scheduler:
         """One scheduling round.  Returns the tokens emitted this round
         (``emitted``: list of (request, slot, token)) and requests retired
         (``finished``)."""
+        with span("serve.step", step=self.n_steps):
+            return self._step()
+
+    def _step(self) -> Dict[str, List]:
         emitted: List = []
         finished_now: List[Request] = []
 
@@ -427,29 +433,9 @@ class Scheduler:
         # hopeless too — a backlogged queue stays O(sort) per step.
         admitted: List[Request] = []
         if self.waiting:
-            free_total = sum(p.n_free for p in self.pools.values())
-            order = sorted(self.waiting, key=self._admit_order_key)
-            run_prios = [r.priority for r in self.running.values()
-                         if r.state is RequestState.DECODE]
-            max_run_prio = max(run_prios) if run_prios else None
-            for req in order:
-                if req.hold_until_step > self.n_steps:
-                    continue           # fault backoff: not yet retryable
-                if free_total == 0 and (max_run_prio is None
-                                        or req.priority >= max_run_prio):
-                    break              # neither a slot nor a victim
-                if self._try_admit(req):
-                    admitted.append(req)
-                    free_total = sum(p.n_free
-                                     for p in self.pools.values())
-                    run_prios = [r.priority
-                                 for r in self.running.values()
-                                 if r.state is RequestState.DECODE]
-                    max_run_prio = max(run_prios) if run_prios else None
-            if admitted:
-                gone = {id(r) for r in admitted}
-                self.waiting = deque(r for r in self.waiting
-                                     if id(r) not in gone)
+            with span("serve.admit", waiting=len(self.waiting)) as sp:
+                self._admit(admitted)
+                sp.set(admitted=len(admitted))
 
         # 2. prefill chunks for the oldest mid-prefill request (one per
         # round unless the SLO policy budgets more from the cost model)
@@ -509,6 +495,31 @@ class Scheduler:
     # ------------------------------------------------------------------
     # Admission, preemption, deadline shedding (DESIGN.md §16)
     # ------------------------------------------------------------------
+    def _admit(self, admitted: List[Request]) -> None:
+        """The admission scan (see ``step``): appends each admitted
+        request to ``admitted`` and takes it off the waiting queue."""
+        free_total = sum(p.n_free for p in self.pools.values())
+        order = sorted(self.waiting, key=self._admit_order_key)
+        run_prios = [r.priority for r in self.running.values()
+                     if r.state is RequestState.DECODE]
+        max_run_prio = max(run_prios) if run_prios else None
+        for req in order:
+            if req.hold_until_step > self.n_steps:
+                continue           # fault backoff: not yet retryable
+            if free_total == 0 and (max_run_prio is None
+                                    or req.priority >= max_run_prio):
+                break              # neither a slot nor a victim
+            if self._try_admit(req):
+                admitted.append(req)
+                free_total = sum(p.n_free for p in self.pools.values())
+                run_prios = [r.priority for r in self.running.values()
+                             if r.state is RequestState.DECODE]
+                max_run_prio = max(run_prios) if run_prios else None
+        if admitted:
+            gone = {id(r) for r in admitted}
+            self.waiting = deque(r for r in self.waiting
+                                 if id(r) not in gone)
+
     @staticmethod
     def _admit_order_key(r: Request) -> Tuple[int, float]:
         """Admission scan order: priority class first, then EDF within
@@ -709,49 +720,44 @@ class Scheduler:
         self._dispatch_seq += 1
         start = req.prefill_pos
         plen = req.prefill_len
-        t0 = self._clock() if self._timed else 0.0
-        try:
-            chunk_logits = self.engine.prefill_chunk_into_slot(
-                pool, req.slot, req.prompt_padded, start,
-                prompt_len=plen, need_logits=not req.is_resuming)
-        except StepFault as f:
-            self._on_fault([req], f, finished_now)
-            return False
         C = self.engine.scfg.prefill_chunk
-        req.prefill_pos = min(start + C, plen)
-        final = req.prefill_pos >= plen
+        end = min(start + C, plen)
+        final = end >= plen
         resumed = req.is_resuming
-        if final:
-            req.state = RequestState.DECODE
-            if getattr(pool, "paged", False):
-                # publish the committed whole pages to the prefix cache —
-                # later requests (or this one, preempted again) with the
-                # same token prefix adopt them instead of re-prefilling
-                pool.register_prefix(req.slot, req.prefill_tokens)
-            if resumed:
-                # replay complete: KV now covers prompt + generated[:-1];
-                # decode continues at the preserved n_generated with
-                # output_tokens[-1] as the next input.  No logits were
-                # computed, nothing crosses the host, nothing is emitted.
-                req.resume_prompt = None
-            else:
-                # two blocking transfers: the final-chunk logits and the
-                # sampled first token
-                self.n_host_syncs += 2
-                tok = sample_one(chunk_logits[(plen - 1) % C],
-                                 req.step_key(), req.sampling.temperature)
-        if self._timed:
-            t1 = self._clock()
-            n_tok = req.prefill_pos - start
-            if self.tracer is not None:
-                self.tracer.complete(
-                    "prefill_chunk", t0, t1, pid=PID_SCHEDULER, tid=0,
-                    args={"req": req.id, "tier": req.tier, "pos": start,
-                          "tokens": n_tok, "final": final,
-                          "dispatch": self._dispatch_seq})
-            if self.profiler is not None:
-                self.profiler.record_prefill(
-                    tier=req.tier, n_tokens=n_tok, wall_s=t1 - t0)
+        with span("serve.prefill", self.tracer, self._clock,
+                  event="prefill_chunk", pid=PID_SCHEDULER, tid=0,
+                  req=req.id, tier=req.tier, pos=start, tokens=end - start,
+                  final=final, dispatch=self._dispatch_seq):
+            try:
+                chunk_logits = self.engine.prefill_chunk_into_slot(
+                    pool, req.slot, req.prompt_padded, start,
+                    prompt_len=plen, need_logits=not resumed)
+            except StepFault as f:
+                self._on_fault([req], f, finished_now)
+                return False
+            req.prefill_pos = end
+            if final:
+                req.state = RequestState.DECODE
+                if getattr(pool, "paged", False):
+                    # publish the committed whole pages to the prefix
+                    # cache — later requests (or this one, preempted
+                    # again) with the same token prefix adopt them
+                    # instead of re-prefilling
+                    pool.register_prefix(req.slot, req.prefill_tokens)
+                if resumed:
+                    # replay complete: KV now covers prompt +
+                    # generated[:-1]; decode continues at the preserved
+                    # n_generated with output_tokens[-1] as the next
+                    # input.  No logits were computed, nothing crosses
+                    # the host, nothing is emitted.
+                    req.resume_prompt = None
+                else:
+                    # two blocking transfers: the final-chunk logits and
+                    # the sampled first token
+                    self.n_host_syncs += 2
+                    tok = sample_one(chunk_logits[(plen - 1) % C],
+                                     req.step_key(),
+                                     req.sampling.temperature)
         if self._r_chunks is not None:
             self._r_chunks.inc(tier=req.tier)
         if final and not resumed:
@@ -806,44 +812,59 @@ class Scheduler:
             temps[r.slot] = r.sampling.temperature
             keys[:, r.slot] = row
 
+    def _decode_span(self, dec: List[Request], pool: KVCachePool,
+                     k: int) -> span:
+        """The ``serve.decode`` span of one tier cohort's round, and its
+        ``decode_burst`` Chrome event on the tier's lane (the span's
+        duration is the dispatch's host-visible wall, its blocking
+        device->host transfer included)."""
+        tier = pool.kv_dtype
+        return span("serve.decode", self.tracer, self._clock,
+                    event="decode_burst", pid=PID_SCHEDULER,
+                    tid=self._tier_tid[tier], tier=tier, k=k, rows=len(dec),
+                    slots=sorted(r.slot for r in dec),
+                    dispatch=self._dispatch_seq)
+
     def _decode_single(self, dec: List[Request], pool: KVCachePool,
                        emitted: List, finished_now: List[Request]) -> None:
         """K = 1: one fused decode+sample step for one tier cohort
         (sampling still on device — only [n_slots] token ids cross to the
         host)."""
         n = pool.n_slots
-        tokens = np.zeros((n,), np.int32)
-        keys = np.zeros((1, n, 2), np.uint32)    # inactive rows: key 0
-        temps = np.zeros((n,), np.float32)
-        for r in dec:
-            tokens[r.slot] = r.last_token
-        self._key_schedule(dec, 1, keys, temps)
-        if getattr(pool, "paged", False):
-            # pin every active row's write position (fresh page at a page
-            # boundary) before the dispatch writes there
-            pool.ensure_decode([r.slot for r in dec], 1)
         self._dispatch_seq += 1
-        ctx = self._cohort_context(dec, pool)
-        t0 = self._clock() if self._timed else 0.0
-        try:
-            toks = self.engine.decode_slots(pool, tokens, keys[0], temps)
-        except StepFault as f:
-            self._on_fault(dec, f, finished_now)
-            return
-        self.n_host_syncs += 1
-        if self._ft_check \
-                and self._tokens_poisoned(toks[[r.slot for r in dec]]):
-            self._on_fault(dec, StepFault("nan", "decode ids out of vocab"),
-                           finished_now)
-            return
-        if self._timed:
-            self._obs_decode(dec, pool, 1, len(dec), ctx, t0, self._clock())
+        with self._decode_span(dec, pool, 1) as sp:
+            tokens = np.zeros((n,), np.int32)
+            keys = np.zeros((1, n, 2), np.uint32)    # inactive rows: key 0
+            temps = np.zeros((n,), np.float32)
+            for r in dec:
+                tokens[r.slot] = r.last_token
+            self._key_schedule(dec, 1, keys, temps)
+            if getattr(pool, "paged", False):
+                # pin every active row's write position (fresh page at a
+                # page boundary) before the dispatch writes there
+                pool.ensure_decode([r.slot for r in dec], 1)
+            try:
+                toks = self.engine.decode_slots(pool, tokens, keys[0], temps)
+            except StepFault as f:
+                self._on_fault(dec, f, finished_now)
+                return
+            self.n_host_syncs += 1
+            if self._ft_check \
+                    and self._tokens_poisoned(toks[[r.slot for r in dec]]):
+                self._on_fault(dec, StepFault("nan",
+                                              "decode ids out of vocab"),
+                               finished_now)
+                return
+            sp.set(emitted=len(dec))
         self.metrics.on_decode_burst(1, len(dec), tier=pool.kv_dtype)
-        for r in dec:
-            # the input token's KV was just written at lengths[slot]
-            pool.lengths[r.slot] += 1
-            self._emit(r, int(toks[r.slot]), emitted, finished_now,
-                       dispatch=self._dispatch_seq)
+        with span("serve.emit", tokens=len(dec)) as sp:
+            n_done = len(finished_now)
+            for r in dec:
+                # the input token's KV was just written at lengths[slot]
+                pool.lengths[r.slot] += 1
+                self._emit(r, int(toks[r.slot]), emitted, finished_now,
+                           dispatch=self._dispatch_seq)
+            sp.set(finished=len(finished_now) - n_done)
 
     def _decode_burst(self, dec: List[Request], pool: KVCachePool, k: int,
                       emitted: List, finished_now: List[Request]) -> None:
@@ -852,56 +873,59 @@ class Scheduler:
         bookkeeping (retirement, slot free, metrics) sees exactly the
         sequence K single steps would have produced."""
         n = pool.n_slots
-        tokens = np.zeros((n,), np.int32)
-        temps = np.zeros((n,), np.float32)
-        eos = np.full((n,), -1, np.int32)
-        active = np.zeros((n,), bool)
-        rem = np.zeros((n,), np.int32)
-        keys = np.zeros((k, n, 2), np.uint32)
-        for r in dec:
-            tokens[r.slot] = r.last_token
-            eos[r.slot] = r.sampling.eos_id
-            active[r.slot] = True
-            rem[r.slot] = r.sampling.max_new_tokens - r.n_generated
-        self._key_schedule(dec, k, keys, temps)
-        if getattr(pool, "paged", False):
-            # pin the whole K-step write window per row, capped at each
-            # row's remaining budget — overshoot writes from rows that
-            # freeze mid-burst land in the garbage page via their
-            # unmapped table entries, not in allocated pages
-            pool.ensure_decode([r.slot for r in dec], k,
-                               [int(rem[r.slot]) for r in dec])
         self._dispatch_seq += 1
-        ctx = self._cohort_context(dec, pool)
-        t0 = self._clock() if self._timed else 0.0
-        try:
-            toks, valid = self.engine.decode_burst(
-                pool, tokens, keys, temps, active, rem, eos)
-        except StepFault as f:
-            self._on_fault(dec, f, finished_now)
-            return
-        self.n_host_syncs += 1
-        if self._ft_check and self._tokens_poisoned(toks[valid]):
-            # the burst committed pool.lengths before the guard tripped;
-            # preempt-and-requeue frees the slot (and its pages), so the
-            # poisoned commits never reach a served token
-            self._on_fault(dec, StepFault("nan", "burst ids out of vocab"),
-                           finished_now)
-            return
-        n_emit = int(valid.sum())
-        if self._timed:
-            self._obs_decode(dec, pool, k, n_emit, ctx, t0, self._clock())
+        with self._decode_span(dec, pool, k) as sp:
+            tokens = np.zeros((n,), np.int32)
+            temps = np.zeros((n,), np.float32)
+            eos = np.full((n,), -1, np.int32)
+            active = np.zeros((n,), bool)
+            rem = np.zeros((n,), np.int32)
+            keys = np.zeros((k, n, 2), np.uint32)
+            for r in dec:
+                tokens[r.slot] = r.last_token
+                eos[r.slot] = r.sampling.eos_id
+                active[r.slot] = True
+                rem[r.slot] = r.sampling.max_new_tokens - r.n_generated
+            self._key_schedule(dec, k, keys, temps)
+            if getattr(pool, "paged", False):
+                # pin the whole K-step write window per row, capped at each
+                # row's remaining budget — overshoot writes from rows that
+                # freeze mid-burst land in the garbage page via their
+                # unmapped table entries, not in allocated pages
+                pool.ensure_decode([r.slot for r in dec], k,
+                                   [int(rem[r.slot]) for r in dec])
+            try:
+                toks, valid = self.engine.decode_burst(
+                    pool, tokens, keys, temps, active, rem, eos)
+            except StepFault as f:
+                self._on_fault(dec, f, finished_now)
+                return
+            self.n_host_syncs += 1
+            if self._ft_check and self._tokens_poisoned(toks[valid]):
+                # the burst committed pool.lengths before the guard
+                # tripped; preempt-and-requeue frees the slot (and its
+                # pages), so the poisoned commits never reach a served
+                # token
+                self._on_fault(dec, StepFault("nan",
+                                              "burst ids out of vocab"),
+                               finished_now)
+                return
+            n_emit = int(valid.sum())
+            sp.set(emitted=n_emit)
         self.metrics.on_decode_burst(k, n_emit, tier=pool.kv_dtype)
         # slots are captured before emission: _emit may retire a request
         # mid-replay (clearing req.slot), but its already-emitted burst
         # tokens are still addressed by the slot it occupied on device
         rows = [(r, r.slot) for r in dec]
-        for t in range(k):
-            for r, slot in rows:
-                if valid[t, slot]:
-                    # engine.decode_burst already committed pool.lengths
-                    self._emit(r, int(toks[t, slot]), emitted, finished_now,
-                               dispatch=self._dispatch_seq)
+        with span("serve.emit", tokens=n_emit) as sp:
+            n_done = len(finished_now)
+            for t in range(k):
+                for r, slot in rows:
+                    if valid[t, slot]:
+                        # engine.decode_burst already committed lengths
+                        self._emit(r, int(toks[t, slot]), emitted,
+                                   finished_now, dispatch=self._dispatch_seq)
+            sp.set(finished=len(finished_now) - n_done)
 
     def _decode_spec(self, dec: List[Request], pool: KVCachePool, k: int,
                      emitted: List, finished_now: List[Request]) -> None:
@@ -933,18 +957,13 @@ class Scheduler:
         temps = np.zeros((n,), np.float32)
         self._key_schedule(dec, s, keys, temps)
         self._dispatch_seq += 1
-        t0 = self._clock() if self._timed else 0.0
-        drafts = self.draft.draft_burst(tier, pool, rows, k, keys[:k],
-                                        temps)
-        self.n_host_syncs += 1
-        t1 = self._clock() if self._timed else 0.0
-        if self.tracer is not None:
-            self.tracer.complete(
-                "spec_draft", t0, t1, pid=PID_SCHEDULER,
-                tid=self._spec_tid[tier][0],
-                args={"tier": tier, "k": k, "rows": len(dec),
-                      "catchup_chunks": n_catchup,
-                      "dispatch": self._dispatch_seq})
+        with span("serve.draft", self.tracer, self._clock, event="spec_draft",
+                  pid=PID_SCHEDULER, tid=self._spec_tid[tier][0], tier=tier,
+                  k=k, rows=len(dec), catchup_chunks=n_catchup,
+                  dispatch=self._dispatch_seq):
+            drafts = self.draft.draft_burst(tier, pool, rows, k, keys[:k],
+                                            temps)
+            self.n_host_syncs += 1
         window = np.zeros((n, s), np.int32)
         rems = np.zeros((n,), np.int32)
         for r, slot in rows:
@@ -959,90 +978,62 @@ class Scheduler:
                                [int(rems[slot]) for _, slot in rows])
         self._dispatch_seq += 1
         verify_dispatch = self._dispatch_seq
-        t2 = self._clock() if self._timed else 0.0
-        try:
-            verified = self.engine.verify_slots(pool, window, keys, temps)
-        except StepFault as f:
-            self._on_fault(dec, f, finished_now)
-            return
-        self.n_host_syncs += 1
-        if self._ft_check and self._tokens_poisoned(
-                verified[:, [slot for _, slot in rows]]):
-            # the verify's outputs are dropped whole; target lengths were
-            # never committed, so the poisoned KV writes stay masked and
-            # the preempt-recompute recovery is bit-identical
-            self._on_fault(dec, StepFault("nan", "verify ids out of vocab"),
-                           finished_now)
-            return
-        # host acceptance: longest agreeing prefix + the target's own
-        # bonus/correction sample, truncated by first-EOS and budget
-        plan: List[Tuple[Request, int, int]] = []
-        drafted = accepted = emitted_total = 0
-        for r, slot in rows:
-            n_emit, n_acc = accept_longest_prefix(
-                drafts[:, slot], verified[:, slot], r.sampling.eos_id,
-                int(rems[slot]))
-            plan.append((r, slot, n_emit))
-            drafted += k
-            accepted += n_acc
-            emitted_total += n_emit
-        # commit target lengths FIRST (the verify wrote all S positions;
-        # committing only n_emit IS the rejection rollback — everything
-        # past the committed length is garbage-but-masked), then sync the
-        # draft pool to the committed state in one length assignment
-        for r, slot, n_emit in plan:
-            pool.lengths[slot] += n_emit
-        self.draft.sync_lengths(tier, pool, rows)
-        self.spec_planner.observe(drafted, accepted)
-        if self._timed:
-            t3 = self._clock()
-            if self.tracer is not None:
-                self.tracer.complete(
-                    "spec_verify", t2, t3, pid=PID_SCHEDULER,
-                    tid=self._spec_tid[tier][1],
-                    args={"tier": tier, "k": k, "rows": len(dec),
-                          "accepted": accepted, "emitted": emitted_total,
-                          "dispatch": verify_dispatch})
+        with span("serve.verify", self.tracer, self._clock,
+                  event="spec_verify", pid=PID_SCHEDULER,
+                  tid=self._spec_tid[tier][1], tier=tier, k=k,
+                  rows=len(dec), dispatch=verify_dispatch) as sp:
+            try:
+                verified = self.engine.verify_slots(pool, window, keys, temps)
+            except StepFault as f:
+                self._on_fault(dec, f, finished_now)
+                return
+            self.n_host_syncs += 1
+            if self._ft_check and self._tokens_poisoned(
+                    verified[:, [slot for _, slot in rows]]):
+                # the verify's outputs are dropped whole; target lengths
+                # were never committed, so the poisoned KV writes stay
+                # masked and the preempt-recompute recovery is
+                # bit-identical
+                self._on_fault(dec, StepFault("nan",
+                                              "verify ids out of vocab"),
+                               finished_now)
+                return
+            # host acceptance: longest agreeing prefix + the target's own
+            # bonus/correction sample, truncated by first-EOS and budget
+            plan: List[Tuple[Request, int, int]] = []
+            drafted = accepted = emitted_total = 0
+            for r, slot in rows:
+                n_emit, n_acc = accept_longest_prefix(
+                    drafts[:, slot], verified[:, slot], r.sampling.eos_id,
+                    int(rems[slot]))
+                plan.append((r, slot, n_emit))
+                drafted += k
+                accepted += n_acc
+                emitted_total += n_emit
+            # commit target lengths FIRST (the verify wrote all S
+            # positions; committing only n_emit IS the rejection rollback
+            # — everything past the committed length is garbage-but-
+            # masked), then sync the draft pool to the committed state in
+            # one length assignment
+            for r, slot, n_emit in plan:
+                pool.lengths[slot] += n_emit
+            self.draft.sync_lengths(tier, pool, rows)
+            self.spec_planner.observe(drafted, accepted)
+            sp.set(accepted=accepted, emitted=emitted_total)
         self.metrics.on_spec_round(
             k=k, rows=len(dec), drafted=drafted, accepted=accepted,
             emitted=emitted_total, catchup_dispatches=n_catchup, tier=tier)
         # step-major emission replay — the exact sequence K+1 single
         # steps would have produced; slots captured pre-emission because
         # _emit may retire a request mid-replay
-        for t in range(s):
-            for r, slot, n_emit in plan:
-                if t < n_emit:
-                    self._emit(r, int(verified[t, slot]), emitted,
-                               finished_now, dispatch=verify_dispatch)
-
-    def _cohort_context(self, dec: List[Request], pool: KVCachePool) -> int:
-        """Mean committed context across a cohort BEFORE its dispatch —
-        what the analytical model prices the round's KV streaming at.
-        Host-side numpy only; called on the obs-enabled path."""
-        if self.profiler is None:
-            return 0
-        return int(round(float(
-            np.mean([pool.lengths[r.slot] for r in dec]))))
-
-    def _obs_decode(self, dec: List[Request], pool: KVCachePool, k: int,
-                    n_emit: int, ctx: int, t0: float, t1: float) -> None:
-        """Per-dispatch observability for one tier cohort's decode round:
-        a trace slice on the tier's lane and a profiler record (t1 - t0
-        spans the jitted dispatch INCLUDING its blocking device->host
-        transfer — the burst's true host-visible wall)."""
-        tier = pool.kv_dtype
-        if self.profiler is not None:
-            self.profiler.record_decode(
-                tier=tier, k=k, rows=len(dec), context=ctx,
-                kv_bytes_per_token=pool.bytes_per_token, wall_s=t1 - t0)
-        if self.tracer is not None:
-            self.tracer.complete(
-                "decode_burst", t0, t1, pid=PID_SCHEDULER,
-                tid=self._tier_tid[tier],
-                args={"tier": tier, "k": k, "rows": len(dec),
-                      "emitted": n_emit,
-                      "slots": sorted(r.slot for r in dec),
-                      "dispatch": self._dispatch_seq})
+        with span("serve.emit", tokens=emitted_total) as sp:
+            n_done = len(finished_now)
+            for t in range(s):
+                for r, slot, n_emit in plan:
+                    if t < n_emit:
+                        self._emit(r, int(verified[t, slot]), emitted,
+                                   finished_now, dispatch=verify_dispatch)
+            sp.set(finished=len(finished_now) - n_done)
 
     def run(self, max_steps: Optional[int] = None) -> None:
         """Step until every submitted request is FINISHED."""

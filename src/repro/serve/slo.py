@@ -3,9 +3,8 @@
 The scheduler (PRs 1-8) is work-conserving but policy-free: at sustained
 overload every request's TTFT grows without bound, because nothing ever
 says no.  ``SLOPolicy`` is the piece that says no — three levers, all
-driven by the SAME analytical cost model that prices dispatches for the
-serving profiler (``perfmodel.analytical.decode_latency``, the paper's
-two-phase streaming model):
+driven by the analytical cost model (``perfmodel.analytical.decode_latency``,
+the paper's two-phase streaming model):
 
   * **Admission control** — ``admit()`` estimates the queue's drain time
     and sheds work with a typed ``Rejection`` (queue_full / drain_time /
@@ -82,7 +81,7 @@ class SLOPolicy:
         band).  ``max_step_s``: modeled per-round latency budget that
         sizes decode bursts and prefill chunks per step (None = keep the
         scheduler's fixed caps).  ``design`` / ``scheme`` pick the
-        analytical deployment priced (see obs/profiler.py)."""
+        analytical deployment priced (``perfmodel.analytical``)."""
         if (downgrade_high_s is None) != (downgrade_low_s is None):
             raise ValueError("give both downgrade_high_s and "
                              "downgrade_low_s, or neither")

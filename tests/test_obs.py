@@ -22,10 +22,10 @@ import pytest
 from repro.configs import get_config
 from repro.models.common import InitMaker
 from repro.models import transformer as T
+from repro.launch.hlo_analysis import compiled_step_cost
 from repro.obs import (Counter, Gauge, Histogram, MetricsRegistry,
                        Observability, PID_REQUESTS, PID_SCHEDULER,
-                       SnapshotWriter, StepProfiler, Tracer,
-                       compiled_step_cost)
+                       SnapshotWriter, Tracer, span)
 from repro.serve import (Request, SamplingParams, ServeConfig, ServingEngine,
                          Scheduler)
 from repro.serve.metrics import ServeMetrics, burst_spread_itl
@@ -199,6 +199,91 @@ def test_trace_carries_request_spans_and_dispatch_events(engine):
 
 
 # ---------------------------------------------------------------------------
+# spans: the profiler sink and the Chrome sink of one helper
+# ---------------------------------------------------------------------------
+def test_span_writes_chrome_event_only_with_a_tracer():
+    """Without a tracer a span reads no clock; with one it writes its
+    Chrome event on that clock, with the args set before it closed, and
+    identical runs give identical bytes."""
+    clock = VirtualClock()
+    with span("serve.decode", None, clock, event="decode_burst",
+              k=4) as sp:
+        sp.set(emitted=3)
+    assert clock.calls == 0
+    outs = []
+    for _ in range(2):
+        tr, clock = Tracer(), VirtualClock()
+        with span("serve.step", step=7):
+            with span("serve.decode", tr, clock, event="decode_burst",
+                      pid=PID_SCHEDULER, tid=1, k=4,
+                      slots=[0, 2]) as sp:
+                sp.set(emitted=3)
+            with span("serve.emit", tr, clock, tokens=3):   # no event
+                pass
+        outs.append(tr.to_json())
+    assert outs[0] == outs[1]
+    (evt,) = json.loads(outs[0])
+    assert evt["name"] == "decode_burst" and evt["ts"] == 0.125e6
+    assert evt["dur"] == 0.125e6
+    assert evt["args"] == {"k": 4, "slots": [0, 2], "emitted": 3}
+
+
+def _profiled_run(engine, tmp_path):
+    """A scheduler run under a CPU ``jax.profiler`` trace; returns the run
+    and the serve.* events of the thread that ran it, as
+    (name, start_ns, end_ns, args)."""
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        sched, _ = _run(engine, temperature=0.7)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = list(tmp_path.rglob("*.xplane.pb"))
+    for plane in ProfileData.from_file(str(path)).planes:
+        for line in plane.lines:
+            evs = [(e.name, e.start_ns, e.end_ns, dict(e.stats))
+                   for e in line.events if e.name.startswith("serve.")]
+            if any(e[0] == "serve.step" for e in evs):
+                return sched, evs
+    raise AssertionError("no serve.step span in the trace")
+
+
+def test_scheduler_spans_nest_in_the_profiler_trace(engine, tmp_path):
+    """Every round is one ``serve.step`` (its step number as ``step_num``)
+    holding its admit / prefill / decode / emit phases; every blocking
+    device->host read (``serve.sync``) lies inside a dispatch span; the
+    dispatch spans carry the request and dispatch ids."""
+    sched, evs = _profiled_run(engine, tmp_path)
+    steps = [e for e in evs if e[0] == "serve.step"]
+    assert [e[3]["step_num"] for e in steps] == list(range(sched.n_steps))
+
+    def inside(e, outer):
+        return any(o[1] <= e[1] and e[2] <= o[2] for o in outer)
+
+    by = {n: [e for e in evs if e[0] == n] for n in
+          ("serve.admit", "serve.prefill", "serve.decode", "serve.sync",
+           "serve.emit")}
+    assert all(by.values()), {n: len(v) for n, v in by.items()}
+    for name in ("serve.admit", "serve.prefill", "serve.decode",
+                 "serve.emit"):
+        assert all(inside(e, steps) for e in by[name]), name
+    dispatches = by["serve.prefill"] + by["serve.decode"]
+    assert all(inside(e, dispatches) for e in by["serve.sync"])
+    assert {e[3]["what"] for e in by["serve.sync"]} == \
+        {"logits", "first_token", "tokens", "keys"}
+    assert len(by["serve.decode"]) == sched.n_decode_dispatches
+    assert sum(e[3]["final"] for e in by["serve.prefill"]) == \
+        len(sched.finished)
+    assert {e[3]["req"] for e in by["serve.prefill"]} == \
+        {r.id for r in sched.finished}
+    ids = [e[3]["dispatch"] for e in dispatches]
+    assert len(set(ids)) == len(ids) and max(ids) == sched._dispatch_seq
+    assert sum(e[3]["tokens"] for e in by["serve.emit"]) + \
+        len(sched.finished) == sum(r.n_generated for r in sched.finished)
+    assert all(e[3]["admitted"] >= 1 for e in by["serve.admit"][:1])
+
+
+# ---------------------------------------------------------------------------
 # the zero-overhead guard (acceptance criterion)
 # ---------------------------------------------------------------------------
 def test_disabled_obs_is_noop_and_enabled_changes_nothing(engine):
@@ -216,8 +301,7 @@ def test_disabled_obs_is_noop_and_enabled_changes_nothing(engine):
     # step-end metrics sample (1/step) — nothing else may touch the clock
     assert base_clk.calls == n_req + n_tok + base.n_steps
 
-    obs = Observability(tracer=Tracer(), registry=MetricsRegistry(),
-                        profiler=StepProfiler(engine.cfg))
+    obs = Observability(tracer=Tracer(), registry=MetricsRegistry())
     full, _ = _run(engine, obs=obs)
     assert [r.output_tokens for r in full.finished] == \
         [r.output_tokens for r in base.finished]
@@ -226,7 +310,7 @@ def test_disabled_obs_is_noop_and_enabled_changes_nothing(engine):
     assert full.n_steps == base.n_steps
     assert full.metrics.burst_hist == base.metrics.burst_hist
     # and the observed run actually observed
-    assert len(obs.tracer) > 0 and obs.profiler.n_records > 0
+    assert len(obs.tracer) > 0
 
 
 def test_token_dispatch_ids_recorded_without_obs(engine):
@@ -362,48 +446,8 @@ def test_serve_metrics_publishes_into_registry(engine):
 
 
 # ---------------------------------------------------------------------------
-# profiler
+# compiled step cost
 # ---------------------------------------------------------------------------
-def test_profiler_report_joins_model_vs_measured(engine):
-    obs = Observability(profiler=StepProfiler(engine.cfg))
-    sched, _ = _run(engine)
-    del sched
-    sched, _ = _run(engine, obs=obs)
-    rep = obs.profiler.report()
-    assert rep["design"] == "xtramac" and not rep["scheme_fallback"]
-    decode = [g for g in rep["groups"] if g["kind"] == "decode"]
-    prefill = [g for g in rep["groups"] if g["kind"] == "prefill_chunk"]
-    assert decode and prefill
-    for g in decode:
-        assert g["model_s"] > 0 and g["measured_s"] > 0
-        assert g["model_over_measured"] > 0
-        assert g["context_mean"] > 0
-    assert all(g["model_s"] is None for g in prefill)
-    pt = rep["per_tier"]["bf16"]
-    assert pt["dispatches"] == sched.n_decode_dispatches
-    assert pt["token_steps"] == sched.metrics.decode_token_steps
-    assert pt["model_over_measured"] > 0
-    assert json.loads(json.dumps(rep, allow_nan=False)) == rep
-
-
-def test_profiler_scheme_fallback():
-    cfg = get_config("granite-8b", smoke=True)
-    prof = StepProfiler(cfg, scheme="bf16")      # no _DEPLOY row for bf16
-    assert prof.scheme == "w8a8" and prof.scheme_fallback
-
-
-def test_profiler_prices_kv_tiers_differently():
-    """The per-tier join must price each tier's KV bytes: an int8 pool
-    streams ~half the bytes of bf16 per context position, so the model's
-    per-step prediction cannot be identical across tiers."""
-    cfg = get_config("granite-8b", smoke=True)
-    prof = StepProfiler(cfg)
-    a = prof._model_step_s(4, 1024, 1024)
-    b = prof._model_step_s(4, 1024, 2048)
-    assert b >= a                                 # more KV bytes, not less
-    assert prof._model_step_s(4, 1024, 1024) == a  # memoized, stable
-
-
 def test_compiled_step_cost(engine):
     pool = engine.new_pool()
     cost = compiled_step_cost(engine, pool)

@@ -95,7 +95,7 @@ def test_decode_latency_gemv_engine_pricing_is_datatype_adaptive():
     makes the compute phase per-datatype: a 4-bit scheme runs its
     projections on 4x the MAC lanes of bf16 from the same channels, and
     the memory phase is derated by the engine's measured HBM utilization
-    — the serving profiler's default pricing (obs/profiler.py)."""
+    — the pricing ``serve/slo.py`` plans with."""
     from repro.configs import get_config
     from repro.perfmodel import decode_latency, gemv_engine_for
 
