@@ -44,11 +44,12 @@ PROMPT_LENS = (128, 256, 384, 512)
 MAX_NEW = 32
 CUT_LAYERS = 4
 # Largest first-step logit difference between two paths, as a share of the
-# reference logits' largest magnitude.  The jnp path dequantizes weights
-# into bf16 before the dot and attends through bf16 einsums; the Pallas
-# kernels dequantize and accumulate in f32.  Each layer's output therefore
-# differs by about one bf16 rounding (2^-8 relative), and the bf16
-# residual stream carries those differences through every layer.
+# reference logits' largest magnitude.  Both paths give the MXU the same
+# bf16 dequantized weights, but the jnp path rounds each product's output
+# and attends through bf16 einsums, while the Pallas kernels return f32
+# products and attend in f32.  Each layer's output therefore differs by
+# about one bf16 rounding (2^-8 relative), and the bf16 residual stream
+# carries those differences through every layer.
 LOGIT_TOL = 0.1
 
 

@@ -19,8 +19,13 @@ Under a declared multi-device mesh the Pallas kernels run inside
 shard-local block (KV heads / slots for decode attention; the
 N- or K-sharded packed weight panel for the matvec path), so 'pallas' is a
 first-class mesh citizen (DESIGN.md §14) — the historical blanket
-downgrade is gone.  ``kernel: 'auto'`` resolves to the jnp reference path
-on a single device (the bit-exact baseline) and to pallas under a mesh.
+downgrade is gone.  ``kernel: 'auto'`` resolves to pallas under a mesh.
+On a single device it resolves per site: a packed quantized weight's
+product (awq_int4 / mxfp4 / fp8) takes the packed kernel where the backend
+compiles Pallas natively (a TPU), the shapes tile it and M is at most
+``AUTO_KERNEL_MAX_ROWS``; everything else — decode attention, w8a8, larger
+M, any CPU — takes the jnp reference path.  ``site_paths()`` records, at
+trace time, which path each quantized product's site took.
 
 What remains of the downgrade is PER-SITE: a call site whose shard-local
 shapes cannot tile the kernel legally — or that has no registered
@@ -47,7 +52,7 @@ import jax.numpy as jnp
 from repro.quant.schemes import (
     QuantizedLinearWeights, quantize_activations_int8,
 )
-from . import ref
+from . import pallas_native, ref
 from .decode_attention import gqa_decode_attention  # noqa: F401  (re-export)
 from .packed_matmul import (
     packed_gemv, packed_matmul, packed_shapes_legal, w8a8_matmul,
@@ -61,6 +66,16 @@ _EXEC = {"mode": "auto", "mesh": None, "partitioned": False}
 # for the shard_map'd weight kernels (partitioning.serve_weight_kernel_specs)
 _WSPECS = {"map": None}
 _WARNED_SITES: set = set()
+# quantized-product site -> 'pallas' | 'jnp': the path its last trace took
+_SITE_PATHS: dict = {}
+
+# Under kernel='auto' on one device, a packed weight's product runs the
+# Pallas kernel at up to this many rows (M: the decode slots, or the tokens
+# of a prefill chunk) and the jnp dequantize-and-dot above it: the largest
+# M timed on the chip.  One awq_int4 4096 x 14336 product in a layer scan
+# on a TPU v5e (benchmarks/packed_kernel_bench.py), ms, kernel / jnp:
+# M = 16: 0.150 / 2.340; M = 64: 0.156 / 2.344; M = 256: 0.298 / 2.375.
+AUTO_KERNEL_MAX_ROWS = 256
 
 
 class KernelFallbackWarning(UserWarning):
@@ -80,7 +95,7 @@ def declare_execution(*, kernel: Optional[str] = None,
     """Declare the execution context for subsequent traces.
 
     ``kernel``: 'jnp' | 'pallas' pin the dispatch mode; 'auto' resets it
-    to the backend default (jnp on a single device, pallas under a mesh);
+    to the default (per site on a single device, pallas under a mesh);
     None leaves it as-is (so an engine with an 'auto' policy inherits
     whatever a driver pinned).  ``mesh``: the jax.sharding.Mesh model
     steps are traced under (None = single device) — setting it also sets
@@ -130,9 +145,10 @@ def active_mesh():
 
 
 def resolved_kernel_mode() -> str:
-    """'auto' resolved: jnp on a single device (the bit-exact baseline),
-    pallas under a declared multi-device mesh (the serving fast path —
-    per-site legality still applies)."""
+    """The mode pinned, or 'auto' resolved for every site but a meshless
+    quantized product (which ``_auto_weight_kernel`` decides): jnp on a
+    single device, pallas under a declared multi-device mesh (the serving
+    fast path — per-site legality still applies)."""
     mode = _EXEC["mode"]
     if mode != "auto":
         return mode
@@ -150,8 +166,17 @@ def _warn_site(site: str, msg: str) -> None:
 
 
 def reset_site_warnings() -> None:
-    """Re-arm the per-site fallback warnings (tests)."""
+    """Re-arm the per-site fallback warnings and clear the record of each
+    site's path (tests)."""
     _WARNED_SITES.clear()
+    _SITE_PATHS.clear()
+
+
+def site_paths() -> dict:
+    """{quantized-product site: 'pallas' | 'jnp'}, the path each site took
+    when it was last traced (a site is the weight's leaf name, e.g.
+    'ffn.w_up')."""
+    return dict(_SITE_PATHS)
 
 
 def kernel_allowed(use_kernel: bool) -> bool:
@@ -325,26 +350,45 @@ def quantized_matmul(x, qw: QuantizedLinearWeights, *,
         return out.reshape(*lead, -1).astype(out_dtype)
 
 
+def _auto_weight_kernel(x2, qw: QuantizedLinearWeights) -> bool:
+    """'auto' on a single device: whether this product runs the packed
+    kernel — the backend compiles Pallas natively, the weight is packed,
+    its shapes tile the kernel and M is at most ``AUTO_KERNEL_MAX_ROWS``."""
+    k, n = qw.shape
+    m = x2.shape[0]
+    return (pallas_native() and qw.scheme.packed
+            and packed_shapes_legal(m, k, n, qw.scheme)
+            and m <= AUTO_KERNEL_MAX_ROWS)
+
+
 def _quantized_dot(x2, qw: QuantizedLinearWeights, use_kernel, interpret):
     """x2 [M, K] @ quantized W -> [M, N] (f32 or bf16) for a non-bf16
-    scheme: the dispatch of ``quantized_matmul``."""
+    scheme: the dispatch of ``quantized_matmul``.  Records the path the
+    site took (``site_paths``)."""
     scheme = qw.scheme
+    site = qw.name or f"<{scheme.name} linear K={x2.shape[-1]}>"
     if use_kernel is None:
-        use_kernel = resolved_kernel_mode() == "pallas"
-        site = qw.name or f"<{scheme.name} linear K={x2.shape[-1]}>"
-        if use_kernel:
-            mesh = _EXEC["mesh"]
-            if _EXEC["partitioned"] and mesh is None:
-                _warn_site(site, "pallas under partitioned execution with "
-                           "no declared mesh — nothing to shard_map over")
-                use_kernel = False
-            elif mesh is not None and mesh.size > 1:
-                out = _mesh_quantized_matmul(x2, qw, mesh, interpret, site)
-                if out is not None:
-                    return out
-                use_kernel = False
+        mesh = _EXEC["mesh"]
+        if _EXEC["mode"] == "auto" and active_mesh() is None \
+                and not _EXEC["partitioned"]:
+            use_kernel = _auto_weight_kernel(x2, qw)
+        elif resolved_kernel_mode() != "pallas":
+            use_kernel = False
+        elif _EXEC["partitioned"] and mesh is None:
+            _warn_site(site, "pallas under partitioned execution with "
+                       "no declared mesh — nothing to shard_map over")
+            use_kernel = False
+        elif mesh is not None and mesh.size > 1:
+            out = _mesh_quantized_matmul(x2, qw, mesh, interpret, site)
+            if out is not None:
+                _SITE_PATHS[site] = "pallas"
+                return out
+            use_kernel = False
+        else:
+            use_kernel = True
     else:
         use_kernel = kernel_allowed(use_kernel)
+    _SITE_PATHS[site] = "pallas" if use_kernel else "jnp"
 
     if scheme.name == "w8a8":
         x_codes, x_scale = quantize_activations_int8(x2)
@@ -357,8 +401,8 @@ def _quantized_dot(x2, qw: QuantizedLinearWeights, use_kernel, interpret):
             fn = packed_gemv if x2.shape[0] <= 8 else packed_matmul
             return fn(x2, qw, interpret=interpret)
         # jnp fallback: dequantize INTO bf16 — exactly the paper's
-        # Stage-1 mapping (the INTxFP product's FP side is BF16); the
-        # Pallas kernel keeps the fused f32-accumulate version
+        # Stage-1 mapping (the INTxFP product's FP side is BF16), the
+        # operand the Pallas kernel builds in VMEM
         from repro.quant.schemes import dequantize
         w = dequantize(qw, dtype=jnp.bfloat16)
         return jnp.dot(x2.astype(jnp.bfloat16), w)

@@ -3,20 +3,24 @@
 This is the TPU realization of the paper's Section VI GEMV engine: weights
 live in HBM as packed sub-byte codes (8x INT4/FP4 or 4x INT8/FP8 per int32
 word — the analogue of the 512-bit HBM channel words feeding XtraMAC
-chains), are streamed block-by-block into VMEM, unpacked + decoded with
-XtraMAC Stage-1 semantics (DAZ, implicit-one restore), scaled, and fed to
-the MXU.  Accumulation is f32 (the BF16-accumulate spec lives in core.mac;
-tensor-core-style f32 accumulation is strictly more accurate and is what
-the MXU provides natively — noted in DESIGN.md).
+chains), are streamed block-by-block into VMEM, decoded with XtraMAC
+Stage-1 semantics (DAZ, implicit-one restore) one code plane at a time,
+scaled, rounded to bf16 and fed to the MXU with f32 accumulation — the
+same bf16 weight operand the jnp path (``quant.schemes.dequantize``) makes,
+without writing it to HBM.
 
 Kernels:
   * ``packed_matmul``  A[M,K] bf16 x packed W[K,N] -> f32 [M,N]
-                       grid (M/bm, N/bn, K/bk), revisiting-accumulate on k
+                       grid (M/bm, N/bn, K/bk), revisiting-accumulate on k;
+                       inside a block, chunks of words decoded plane-major
+                       against activations permuted to the same K order
   * ``w8a8_matmul``    int8 x int8 -> int32 MXU accumulate -> scale epilogue
 
-Block shapes are MXU/VMEM aligned by default (bn multiple of 128, bk
-multiple of the packing group), validated under interpret mode on CPU and
-compiled for a v5e in ``tests/test_tpu_compile.py``.
+The default block plan (``packed_block_plan``) is sized for decode-shaped
+M: every grid step streams about ``STEP_BYTES`` of packed words.  Block
+shapes are MXU/VMEM aligned (bn a multiple of 128, bk a multiple of the
+packing group), validated under interpret mode on CPU and compiled for a
+v5e in ``tests/test_tpu_compile.py``.
 """
 from __future__ import annotations
 
@@ -71,38 +75,77 @@ def decode_codes_arith(scheme: QuantScheme, codes):
     raise ValueError(scheme.weight_format)
 
 
-def _unpack_block(words, bits: int):
-    """int32 [bkw, bn] -> codes [bkw*per, bn] (little-endian along K)."""
-    per = 32 // bits
-    mask = (1 << bits) - 1
-    parts = [(words >> (i * bits)) & mask for i in range(per)]
-    stacked = jnp.stack(parts, axis=1)                 # [bkw, per, bn]
-    return stacked.reshape(words.shape[0] * per, words.shape[1])
+def _code_plane(words, i: int, scheme: QuantScheme):
+    """Code ``i`` of every packed word (bits [i*bits, (i+1)*bits)) decoded
+    to f32.  Integer codes sign-extend by a left then an arithmetic right
+    shift; float codes go through ``decode_codes_arith``."""
+    bits = scheme.weight_bits
+    if scheme.weight_format.startswith("int"):
+        return ((words << (32 - bits * (i + 1))) >> (32 - bits)) \
+            .astype(jnp.float32)
+    return decode_codes_arith(scheme, (words >> (bits * i)) & ((1 << bits) - 1))
+
+
+def dequant_planes(words, scales, scheme: QuantScheme):
+    """One chunk's MXU operands: int32 words [cw, bn] and their scale rows
+    [ns, bn] (one row: a scale for every word) -> ``per`` bf16 [cw, bn]
+    code planes, plane ``i`` holding code ``i`` of every word.  No code is
+    interleaved back into K order; the activations are permuted to match
+    instead (``chunk_activations``).  Each decoded value is scaled in f32
+    and rounded once to bf16, as ``quant.schemes.dequantize(dtype=bf16)``
+    rounds it."""
+    cw, bn = words.shape
+    ns = scales.shape[0]
+    if ns > 1:   # every scale row covers cw // ns consecutive words
+        scales = jnp.broadcast_to(scales[:, None, :], (ns, cw // ns, bn)) \
+            .reshape(cw, bn)
+    return [(_code_plane(words, i, scheme) * scales).astype(jnp.bfloat16)
+            for i in range(32 // scheme.weight_bits)]
+
+
+def chunk_product(acc, x, words, scales, scheme: QuantScheme):
+    """acc [bm, bn] f32 + one chunk's product: x [bm, cw * per] (the chunk's
+    slab of ``chunk_activations``) against its dequantized code planes,
+    one bf16 dot per plane, summed into ``acc`` in plane order."""
+    cw = words.shape[0]
+    for i, plane in enumerate(dequant_planes(words, scales, scheme)):
+        acc = acc + jnp.dot(x[:, i * cw:(i + 1) * cw], plane,
+                            preferred_element_type=jnp.float32)
+    return acc
+
+
+def chunk_activations(x, cw: int, per: int):
+    """x [M, K] -> bf16 [K / (cw * per), M, cw * per]: one slab per chunk of
+    ``cw`` packed words, its columns in the plane-major order of
+    ``dequant_planes`` (column ``i * cw + w`` is K index ``w * per + i`` of
+    the chunk)."""
+    m, k = x.shape
+    u = cw * per
+    return x.astype(jnp.bfloat16).reshape(m, k // u, cw, per) \
+        .transpose(1, 0, 3, 2).reshape(k // u, m, u)
 
 
 # ---------------------------------------------------------------------------
 # packed matmul kernel
 # ---------------------------------------------------------------------------
 def _packed_matmul_kernel(x_ref, w_ref, s_ref, o_ref, *, scheme: QuantScheme,
-                          bk: int, group: int):
-    k = pl.program_id(2)
-
-    @pl.when(k == 0)
+                          cw: int, ns: int):
+    """One (M, N, K) block: the K block's chunks of ``cw`` words, each
+    dequantized to bf16 code planes and dotted with f32 accumulation.
+    ``ns``: scale rows per chunk, 0 when one scale row serves the whole
+    block."""
+    @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    codes = _unpack_block(w_ref[...], scheme.weight_bits)       # [bk, bn]
-    vals = decode_codes_arith(scheme, codes)                    # f32
-    ng = bk // group
-    scales = s_ref[...]                                         # [ng, bn]
-    vals = (vals.reshape(ng, group, vals.shape[-1]) * scales[:, None, :]) \
-        .reshape(bk, vals.shape[-1])
-    x = x_ref[...].astype(jnp.float32)
-    o_ref[...] += jnp.dot(x, vals, preferred_element_type=jnp.float32)
+    def chunk(c, acc):
+        words = w_ref[pl.ds(pl.multiple_of(c * cw, cw), cw), :]
+        scales = s_ref[...] if ns == 0 else \
+            s_ref[pl.ds(pl.multiple_of(c * ns, ns), ns), :]
+        return chunk_product(acc, x_ref[c], words, scales, scheme)
 
-
-def _pick(block: int, dim: int) -> int:
-    return min(block, dim)
+    o_ref[...] += jax.lax.fori_loop(0, x_ref.shape[0], chunk,
+                                    jnp.zeros(o_ref.shape, jnp.float32))
 
 
 def _fit_block(dim: int, want: int, quantum: int = 1) -> int:
@@ -117,9 +160,25 @@ def _fit_block(dim: int, want: int, quantum: int = 1) -> int:
     return dim
 
 
+# Default block plan, set for the serving shapes: M is the decode slots or
+# a prefill chunk (16 in granite-8b.batch-decode).  A grid step costs about
+# 0.35 us of its own, so a step streams STEP_BYTES of packed words.  The
+# activation block (bm x bk bf16) stays under X_BLOCK_BYTES, so at decode
+# M a block spans all of K and each output block is written once; the f32
+# output block (bm x bn) stays under OUT_BLOCK_BYTES, which keeps large M
+# inside VMEM.  CHUNK_WORDS word rows are decoded at a time.
+STEP_BYTES = 1 << 20
+X_BLOCK_BYTES = 2 << 20
+OUT_BLOCK_BYTES = 1 << 20
+MAX_BM = 256
+CHUNK_WORDS = 128
+
+
 def packed_block_plan(m: int, k: int, n: int, scheme: QuantScheme, *,
-                      bm: int = 128, bn: int = 128, bk: int = 512):
-    """The (bm, bn, bk) tiling ``packed_matmul`` uses for these shapes.
+                      bm: Optional[int] = None, bn: Optional[int] = None,
+                      bk: Optional[int] = None):
+    """The (bm, bn, bk) tiling ``packed_matmul`` uses for these shapes; a
+    block given is an upper bound, one left out takes the default plan.
 
     Exported so the bit-exact oracle (``ref.packed_matmul_tiled_ref``) can
     replay the exact same grid: per-element results depend on the K-block
@@ -127,24 +186,40 @@ def packed_block_plan(m: int, k: int, n: int, scheme: QuantScheme, *,
     must agree on the plan, not just the math."""
     group = effective_group(scheme.group_size, k)
     per = 32 // scheme.weight_bits
+    bm = _fit_block(m, bm or MAX_BM)
     # K blocks land on scale-group boundaries when the matrix has several
     # groups — eight at a time, so a block's scale rows fill the TPU's
     # 8-row tile (or the block spans all of K) — else (per-channel: one
     # global scale row) on word boundaries.  N blocks keep to the 128-lane
     # tile when N allows it.
+    if bk is None:
+        bk = X_BLOCK_BYTES // (2 * bm)
     if group < k:
         quantum = 8 * group
         bk = max(bk, quantum)
     else:
         quantum = per
-    return (_fit_block(m, bm), _fit_block(n, bn, 128 if n % 128 == 0 else 1),
-            _fit_block(k, bk, quantum))
+    bk = _fit_block(k, bk, quantum)
+    if bn is None:
+        bn = min(-(-8 * STEP_BYTES // (bk * scheme.weight_bits) // 128),
+                 max(1, OUT_BLOCK_BYTES // (4 * bm) // 128)) * 128
+    return bm, _fit_block(n, bn, 128 if n % 128 == 0 else 1), bk
+
+
+def chunk_words(bk: int, k: int, scheme: QuantScheme) -> int:
+    """Word rows per inner chunk of a K block: at most ``CHUNK_WORDS``,
+    dividing the block, and, when the block has several scale groups,
+    holding them eight at a time (the 8-row tile of the scale block)."""
+    per = 32 // scheme.weight_bits
+    group = effective_group(scheme.group_size, k)
+    return _fit_block(bk // per, CHUNK_WORDS,
+                      8 * group // per if group < bk else 1)
 
 
 def packed_shapes_legal(m: int, k: int, n: int, scheme: QuantScheme) -> bool:
     """Whether (possibly shard-local) shapes can run the packed kernel:
     K must pack whole int32 words and whole scale groups.  The per-site
-    fallback predicate for mesh dispatch (kernels/ops.py)."""
+    fallback predicate of the dispatch (kernels/ops.py)."""
     if m < 1 or n < 1 or k < 1:
         return False
     per = 32 // scheme.weight_bits
@@ -164,20 +239,21 @@ def _packed_matmul_impl(x, packed, scales, *, scheme_name: str, k: int, n: int,
     m = x.shape[0]
     per = 32 // scheme.weight_bits
     group = effective_group(scheme.group_size, k)
-    grid = (m // bm, n // bn, k // bk)
-    ng = bk // group if group <= bk else 1
-    if group > bk:  # per-channel (group == k): one scale row for all k-blocks
+    cw = chunk_words(bk, k, scheme)
+    u = cw * per
+    if group < bk:
+        ns = u // group
+        scale_spec = pl.BlockSpec((bk // group, bn), lambda i, j, l: (l, j))
+    else:   # one scale row (per-channel or a single group) for every block
+        ns = 0
         scale_spec = pl.BlockSpec((1, bn), lambda i, j, l: (0, j))
-    else:
-        scale_spec = pl.BlockSpec((ng, bn), lambda i, j, l: (l, j))
-    kernel = functools.partial(
-        _packed_matmul_kernel, scheme=scheme, bk=bk, group=min(group, bk)
-    )
+    kernel = functools.partial(_packed_matmul_kernel, scheme=scheme, cw=cw,
+                               ns=ns)
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(m // bm, n // bn, k // bk),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, l: (i, l)),
+            pl.BlockSpec((bk // u, bm, u), lambda i, j, l: (l, i, 0)),
             pl.BlockSpec((bk // per, bn), lambda i, j, l: (l, j)),
             scale_spec,
         ],
@@ -185,13 +261,15 @@ def _packed_matmul_impl(x, packed, scales, *, scheme_name: str, k: int, n: int,
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
         interpret=interpret,
         name=name,
-    )(x, packed, scales)
+    )(chunk_activations(x, cw, per), packed, scales)
 
 
-def packed_matmul(x, qw: QuantizedLinearWeights, *, bm: int = 128, bn: int = 128,
-                  bk: int = 512, interpret=None, name: str = "packed_matmul"):
-    """x [M, K] (bf16) @ packed W [K, N] -> f32 [M, N].  ``name`` is the
-    kernel's stable name in profiles and op metadata."""
+def packed_matmul(x, qw: QuantizedLinearWeights, *, bm: Optional[int] = None,
+                  bn: Optional[int] = None, bk: Optional[int] = None,
+                  interpret=None, name: str = "packed_matmul"):
+    """x [M, K] (bf16) @ packed W [K, N] -> f32 [M, N]: bf16 operands on the
+    MXU, f32 accumulation.  Blocks default to ``packed_block_plan``'s;
+    ``name`` is the kernel's stable name in profiles and op metadata."""
     k, n = qw.shape
     m = x.shape[0]
     scheme = qw.scheme
@@ -206,9 +284,10 @@ def packed_matmul(x, qw: QuantizedLinearWeights, *, bm: int = 128, bn: int = 128
     )
 
 
-def packed_gemv(x, qw: QuantizedLinearWeights, *, bn: int = 256, bk: int = 1024,
-                interpret=None):
-    """Decode-shape GEMV: x [B, K] with small B (the paper's Section VI-C)."""
+def packed_gemv(x, qw: QuantizedLinearWeights, *, bn: Optional[int] = None,
+                bk: Optional[int] = None, interpret=None):
+    """Decode-shape GEMV: x [B, K] with small B (the paper's Section VI-C),
+    all rows in one block."""
     return packed_matmul(x, qw, bm=x.shape[0], bn=bn, bk=bk,
                          interpret=interpret, name="packed_gemv")
 

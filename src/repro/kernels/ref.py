@@ -16,10 +16,12 @@ from repro.quant.schemes import QuantizedLinearWeights, dequantize
 def packed_matmul_ref(x, qw: QuantizedLinearWeights):
     """x [M, K] bf16 @ packed W [K, N] -> f32 [M, N] (dequant-then-matmul).
 
-    Dequantizes in f32 (fused-kernel semantics: decoded values are never
-    rounded to bf16 before the MXU)."""
-    w = dequantize(qw, dtype=jnp.float32)
-    return jnp.dot(x.astype(jnp.float32), w, preferred_element_type=jnp.float32)
+    The packed kernels' contract: the weight dequantized in f32 and rounded
+    once to bf16 (``dequantize(dtype=bf16)``), bf16 operands, f32
+    accumulation."""
+    w = dequantize(qw, dtype=jnp.bfloat16)
+    return jnp.dot(x.astype(jnp.bfloat16), w,
+                   preferred_element_type=jnp.float32)
 
 
 def packed_gemv_ref(x, qw: QuantizedLinearWeights):
@@ -29,28 +31,29 @@ def packed_gemv_ref(x, qw: QuantizedLinearWeights):
 
 # Kernel vs ``packed_matmul_tiled_ref`` agree to this share of the output's
 # largest magnitude.  Interpret mode compiles the kernel body as one XLA
-# CPU program, which may fold a scale that is constant along K out of the
-# dot and reorder a tile's f32 sums, while the oracle runs op by op; a
-# shard-local tile shape compiles to yet another program.  Observed
-# differences reach about 2^-20 of the largest output (K = 512, sharded);
-# a wrong plan, scale or decode is off by orders of magnitude more.
+# CPU program, which may reorder a tile's f32 sums, while the oracle runs
+# op by op; a shard-local tile shape compiles to yet another program.
+# Observed differences reach about 2^-20 of the largest output (K = 512,
+# sharded); a wrong plan, scale or decode is off by orders of magnitude
+# more.
 TILED_REF_ATOL = 2.0 ** -16
 
 
-def packed_matmul_tiled_ref(x, qw: QuantizedLinearWeights, *, bm: int = 128,
-                            bn: int = 128, bk: int = 512):
+def packed_matmul_tiled_ref(x, qw: QuantizedLinearWeights, *, bm=None,
+                            bn=None, bk=None):
     """BIT-exact oracle for ``packed_matmul``: replays the kernel's grid.
 
-    Same tiling (``packed_block_plan``), same arithmetic decode
-    (``decode_codes_arith`` — shift/mask, DAZ, shared with the kernel
-    body), same per-group scaling, same per-tile f32 dot shapes and same
-    K-block accumulation order, as plain jnp loops.  f32 sums are not
-    associative, so agreeing on the *plan* is what upgrades the
-    dequant-LUT ``packed_matmul_ref`` tolerance contract to a bitwise one
-    (the DESIGN.md §14 analogue of the §9 decode-attention contract).
+    Same tiling (``packed_block_plan``, ``chunk_words``), same chunked
+    activations (``chunk_activations``), the same chunk product
+    (``chunk_product`` — shift/mask decode, DAZ, per-group scaling, one bf16
+    dot per code plane, shared with the kernel body) and the same chunk
+    and K-block accumulation order, as plain jnp loops.  f32
+    sums are not associative, so agreeing on the *plan* is what upgrades
+    the dequant-LUT ``packed_matmul_ref`` tolerance contract to a bitwise
+    one (the DESIGN.md §14 analogue of the §9 decode-attention contract).
     """
-    from .packed_matmul import (_unpack_block, decode_codes_arith,
-                                packed_block_plan)
+    from .packed_matmul import (chunk_activations, chunk_product,
+                                chunk_words, packed_block_plan)
     from repro.quant.schemes import effective_group
 
     scheme = qw.scheme
@@ -59,29 +62,26 @@ def packed_matmul_tiled_ref(x, qw: QuantizedLinearWeights, *, bm: int = 128,
     bm, bn, bk = packed_block_plan(m, k, n, scheme, bm=bm, bn=bn, bk=bk)
     per = 32 // scheme.weight_bits
     group = effective_group(scheme.group_size, k)
-    g = min(group, bk)
-    ng = bk // g
+    cw = chunk_words(bk, k, scheme)
+    u = cw * per
+    ns = u // group if group < bk else 0
+    xc = chunk_activations(x, cw, per)                    # [K/u, M, u]
     out = jnp.zeros((m, n), jnp.float32)
     for i in range(m // bm):
+        rows = slice(i * bm, (i + 1) * bm)
         for j in range(n // bn):
-            acc = jnp.zeros((bm, bn), jnp.float32)
+            cols = slice(j * bn, (j + 1) * bn)
+            tile = jnp.zeros((bm, bn), jnp.float32)
             for l in range(k // bk):
-                words = qw.packed[l * bk // per:(l + 1) * bk // per,
-                                  j * bn:(j + 1) * bn]
-                vals = decode_codes_arith(
-                    scheme, _unpack_block(words, scheme.weight_bits))
-                if group > bk:   # per-channel: one global scale row
-                    scales = qw.scales[0:1, j * bn:(j + 1) * bn]
-                else:
-                    scales = qw.scales[l * ng:(l + 1) * ng,
-                                       j * bn:(j + 1) * bn]
-                vals = (vals.reshape(ng, g, bn) * scales[:, None, :]) \
-                    .reshape(bk, bn)
-                xt = x[i * bm:(i + 1) * bm,
-                       l * bk:(l + 1) * bk].astype(jnp.float32)
-                acc = acc + jnp.dot(xt, vals,
-                                    preferred_element_type=jnp.float32)
-            out = out.at[i * bm:(i + 1) * bm, j * bn:(j + 1) * bn].set(acc)
+                acc = jnp.zeros((bm, bn), jnp.float32)
+                for c in range(l * bk // u, (l + 1) * bk // u):
+                    words = qw.packed[c * cw:(c + 1) * cw, cols]
+                    scales = qw.scales[c * ns:(c + 1) * ns, cols] if ns \
+                        else qw.scales[0:1, cols]
+                    acc = chunk_product(acc, xc[c, rows], words, scales,
+                                        scheme)
+                tile = tile + acc
+            out = out.at[rows, cols].set(tile)
     return out
 
 
@@ -104,8 +104,7 @@ def _shard_qw(qw: QuantizedLinearWeights, tp: int, j: int, dim: int):
 
 
 def sharded_packed_matmul_ref(x, qw: QuantizedLinearWeights, *, tp: int,
-                              shard_dim: int, bm: int = 128, bn: int = 128,
-                              bk: int = 512):
+                              shard_dim: int, bm=None, bn=None, bk=None):
     """Oracle for the shard_map'd weight-path kernel (kernels/ops.py).
 
     Decomposes exactly as the mesh dispatch does — N sharded over 'model'

@@ -13,10 +13,14 @@ JSON-serializable object:
     Maker walk and the partitioning rules already share ("attn.wq",
     "ffn.*", "moe.w_up", ...).  An unmatched name keeps its config default.
   * ``kv``      — KV-cache storage tier: 'bf16' | 'int8' | 'fp8'.
-  * ``kernel``  — execution dispatch: 'auto' (backend decides; today the
-    jnp reference path unless a driver opted into Pallas), 'jnp' (force
-    the reference path), 'pallas' (force the fused kernels; invalid under
-    a multi-device mesh — they are not GSPMD-partitionable).
+  * ``kernel``  — execution dispatch: 'auto' (decided per site: under a
+    multi-device mesh the kernels run shard_map'd; on one device a packed
+    quantized weight's product — awq_int4 / mxfp4 / fp8 — runs the packed
+    Pallas kernel where the backend compiles Pallas natively (a TPU) at up
+    to ``kernels.ops.AUTO_KERNEL_MAX_ROWS`` rows, while decode attention,
+    w8a8 and every CPU product take the jnp reference path), 'jnp' (force
+    the reference path), 'pallas' (force the fused kernels, shard_map'd
+    under a mesh).
 
 Everything downstream derives from the policy instead of carrying its own
 knob: ``QuantMaker`` consumes ``resolved_plan(cfg)``,
@@ -183,8 +187,8 @@ class PrecisionPolicy:
         resolved quantized leaf's K is divisible by the scheme's packing
         word and scale group; a quantized KV tier needs a GQA cache with
         ``d_head % 4 == 0`` (MLA latents stay bf16, DESIGN.md §9);
-        ``kernel='pallas'`` is rejected under a multi-device mesh (the
-        kernels are not GSPMD-partitionable — 'auto' downgrades instead).
+        ``kernel='pallas'`` is accepted under a multi-device mesh (the
+        kernels run shard_map'd, falling back to jnp per site).
         ``strict_tp=True`` additionally rejects policies whose packed-K
         grouping FORCES replication of a leaf the name rules would
         otherwise K-shard over the model axis (word/scale-group

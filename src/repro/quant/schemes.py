@@ -198,8 +198,9 @@ def quantize_weights_jnp(scheme: QuantScheme, w):
 def dequantize(qw: QuantizedLinearWeights, dtype=jnp.bfloat16):
     """Packed codes + scales -> dense weights [K, N].
 
-    dtype=bf16 is the 'upcast' baseline materialization; dtype=f32 matches
-    the fused kernels (which never round the dequantized value).
+    dtype=bf16 is the operand both product paths give the MXU (the jnp
+    path materializes it in HBM, the packed kernels build it in VMEM);
+    dtype=f32 keeps the unrounded values.
     """
     scheme = qw.scheme
     if scheme.name == "bf16":

@@ -131,7 +131,7 @@ def test_packed_kernels_bitexact_vs_tiled_ref(scheme):
         x = jnp.asarray(RNG.normal(size=(m, k)), jnp.bfloat16)
         if m <= 8:   # the GEMV dispatch predicate in kernels/ops.py
             got = packed_gemv(x, qw, interpret=True)
-            want = ref.packed_matmul_tiled_ref(x, qw, bm=m, bn=256, bk=1024)
+            want = ref.packed_matmul_tiled_ref(x, qw, bm=m)
         else:
             got = packed_matmul(x, qw, interpret=True)
             want = ref.packed_matmul_tiled_ref(x, qw)
@@ -160,6 +160,70 @@ def test_packed_block_plan_bitexact(scheme, bm, bn, bk):
     g = effective_group(s.group_size, k)
     assert 16 % fbm == 0 and 96 % fbn == 0 and k % fbk == 0
     assert fbk % min(g, fbk) == 0
+
+
+# (k, n) at M = 16 against a scaled-down plan (``_small_plan``): several N
+# blocks, several K blocks, several chunks per block, and dims where a
+# block or a chunk must fall back to the whole dim
+_EDGE_SHAPES = {"awq_int4": [(4096, 256), (1024, 160), (384, 256)],
+                "mxfp4": [(4096, 384), (768, 160), (96, 256)],
+                "fp8": [(4096, 384), (1020, 160), (64, 256)]}
+
+
+@pytest.fixture
+def _small_plan(monkeypatch):
+    """The default plan's sizes cut so that small shapes cross its block
+    and chunk edges: 4 KB steps, 64 KB activation blocks, 128-word
+    chunks."""
+    from repro.kernels import packed_matmul as pm
+    monkeypatch.setattr(pm, "STEP_BYTES", 4 << 10)
+    monkeypatch.setattr(pm, "X_BLOCK_BYTES", 64 << 10)
+    monkeypatch.setattr(pm, "CHUNK_WORDS", 128)
+    jax.clear_caches()   # the kernel's jit holds traces of the full plan
+    yield pm
+    jax.clear_caches()
+
+
+@pytest.mark.parametrize("case", range(3))
+@pytest.mark.parametrize("scheme", ["awq_int4", "mxfp4", "fp8"])
+def test_packed_kernel_bitexact_at_plan_edges(_small_plan, scheme, case):
+    """At M = 16 (the serving cell's decode slots and prefill chunk) the
+    kernel equals the tiled oracle BITWISE across the default plan's block
+    and chunk edges, and the LUT oracle to accumulation order."""
+    pm = _small_plan
+    s = get_scheme(scheme)
+    k, n = _EDGE_SHAPES[scheme][case]
+    _, qw = _qw(scheme, k, n)
+    x = jnp.asarray(RNG.normal(size=(16, k)), jnp.bfloat16)
+    got = np.asarray(packed_matmul(x, qw, interpret=True))
+    np.testing.assert_array_equal(got, np.asarray(
+        ref.packed_matmul_tiled_ref(x, qw)))
+    np.testing.assert_allclose(got, np.asarray(ref.packed_matmul_ref(x, qw)),
+                               rtol=2e-3, atol=1e-3)
+    bm, bn, bk = pm.packed_block_plan(16, k, n, s)
+    cw = pm.chunk_words(bk, k, s)
+    assert 16 % bm == 0 and n % bn == 0 and k % bk == 0
+    assert (bk // (32 // s.weight_bits)) % cw == 0
+
+
+GRANITE_WIDTHS = [(4096, 4096), (4096, 1024), (4096, 14336), (14336, 4096)]
+
+
+@pytest.mark.parametrize("k,n", GRANITE_WIDTHS)
+@pytest.mark.parametrize("scheme", ["awq_int4", "mxfp4", "fp8"])
+def test_default_plan_at_decode_rows(scheme, k, n):
+    """At M = 16 the default plan spans all of K (each output block written
+    once), streams at least 512 KB of packed words a grid step, and
+    decodes whole 8-row tiles of scales per chunk."""
+    from repro.kernels.packed_matmul import chunk_words
+    s = get_scheme(scheme)
+    bm, bn, bk = packed_block_plan(16, k, n, s)
+    assert (bm, bk) == (16, k)
+    assert bk * bn * s.weight_bits // 8 >= 512 << 10
+    cw = chunk_words(bk, k, s)
+    per = 32 // s.weight_bits
+    g = effective_group(s.group_size, k)
+    assert cw % 16 == 0 and (g >= bk or (cw * per // g) % 8 == 0)
 
 
 @pytest.mark.parametrize("m,k,n", [(1, 4, 8), (20, 400, 312), (7, 52, 8)])

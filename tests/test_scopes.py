@@ -163,3 +163,81 @@ def test_kernels_carry_their_names(name):
     txt = jax.jit(fn).lower(*args).compile().as_text()
     paths = re.findall(r'op_name="([^"]*)"', txt)
     assert any(f"/{name}/" in p for p in paths), sorted(set(paths))[:5]
+
+
+# ---------------------------------------------------------------------------
+# kernel='auto' on one device: the packed kernel for quantized products on
+# a backend that compiles Pallas, the jnp path everywhere else
+# ---------------------------------------------------------------------------
+@pytest.fixture
+def native_pallas(monkeypatch):
+    """The dispatch sees a backend that compiles Pallas natively (a TPU);
+    the kernels themselves still run in interpret mode on the CPU."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "pallas_native", lambda: True)
+    return ops
+
+
+@pytest.mark.parametrize("program", PROGRAMS)
+def test_auto_on_tpu_runs_packed_kernel(engine, native_pallas, program):
+    ops, _ = compiled_ops(engine, program, "auto")
+    paths = native_pallas.site_paths()
+    assert set(paths) >= {"attn.wq", "attn.wk", "attn.wv", "attn.wo",
+                          "ffn.w_gate", "ffn.w_up", "ffn.w_down"}, paths
+    assert set(paths.values()) == {"pallas"}, paths
+    names = [n for _, _, n, _ in ops]
+    assert any(re.search(r"/qmatmul/.*packed_(gemv|matmul)/", n)
+               for n in names)
+    # the dequantize graph is gone: no integer unpack outside the kernel
+    assert not [n for op, t, n, s in ops if "qmatmul" in s
+                and op in ("and", "shift-right-logical", "shift-left")
+                and _INT.match(t) and "/packed_" not in n]
+    # decode attention keeps its einsum path on one chip
+    assert not any("/decode_attention/" in n for n in names)
+    if program != "prefill_chunk":
+        dots = [s for op, _, _, s in ops if op == "dot"]
+        assert sum("attn_core" in s for s in dots) == 2
+
+
+def test_auto_on_cpu_stays_jnp(engine):
+    from repro.kernels.ops import site_paths
+    ops, _ = compiled_ops(engine, "decode_burst", "auto")
+    paths = site_paths()
+    assert paths and set(paths.values()) == {"jnp"}, paths
+    assert not any("/packed_" in n for _, _, n, _ in ops)
+
+
+def _traced_path(x_rows, scheme="awq_int4", k=256, n=128):
+    """Trace one product of ``x_rows`` rows and return its site's path."""
+    import dataclasses
+    import numpy as np
+    from repro.kernels.ops import quantized_matmul, site_paths
+    from repro.quant.schemes import get_scheme, quantize_weights
+    w = np.random.default_rng(0).normal(size=(k, n)).astype(np.float32)
+    qw = dataclasses.replace(quantize_weights(get_scheme(scheme), w),
+                             name="site")
+    jax.jit(lambda x: quantized_matmul(x, qw)).lower(
+        jax.ShapeDtypeStruct((x_rows, k), jnp.bfloat16))
+    return site_paths()["site"]
+
+
+def test_auto_row_bound(native_pallas):
+    """'auto' takes the kernel up to ``AUTO_KERNEL_MAX_ROWS`` rows and the
+    jnp path above them; w8a8 (not packed) stays jnp."""
+    bound = native_pallas.AUTO_KERNEL_MAX_ROWS
+    assert _traced_path(16) == "pallas"
+    assert _traced_path(bound) == "pallas"
+    assert _traced_path(bound + 1) == "jnp"
+    assert _traced_path(16, scheme="w8a8") == "jnp"
+
+
+@pytest.mark.parametrize("native", [False, True])
+def test_pins_unchanged(monkeypatch, native):
+    """'jnp' and 'pallas' pin every quantized product, whatever the
+    backend."""
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "pallas_native", lambda: native)
+    for pin in ("jnp", "pallas"):
+        ops.declare_execution(kernel=pin)
+        assert _traced_path(16) == pin
+        assert _traced_path(1024) == pin

@@ -22,15 +22,19 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.configs import get_config
 from repro.kernels.decode_attention import gqa_decode_attention
+from repro.kernels.ops import AUTO_KERNEL_MAX_ROWS
 from repro.kernels.packed_matmul import packed_gemv, packed_matmul
 from repro.quant.kv_cache import QuantizedKV
-from repro.quant.schemes import QuantizedLinearWeights, get_scheme
+from repro.quant.schemes import (QuantizedLinearWeights, effective_group,
+                                 get_scheme)
 from repro.serve.engine import ServeConfig
 
 GRANITE = get_config("granite-8b")
 QWEN_MOE = get_config("qwen3-moe-30b-a3b")
 DECODE_SLOTS = 8
 DECODE_CONTEXT = 2048
+# granite-8b.batch-decode: 16 decode slots and 16-token prefill chunks
+CELL_ROWS = ServeConfig().prefill_chunk
 
 
 @pytest.fixture(scope="module")
@@ -97,22 +101,36 @@ GRANITE_WIDTHS = [
 def _packed_weight_shapes(scheme_name: str, k: int, n: int):
     s = get_scheme(scheme_name)
     return (((k // (32 // s.weight_bits), n), jnp.int32),
-            ((k // s.group_size, n), jnp.float32))
+            ((k // effective_group(s.group_size, k), n), jnp.float32))
 
 
-@pytest.mark.parametrize("k,n", GRANITE_WIDTHS,
-                         ids=[f"{k}x{n}" for k, n in GRANITE_WIDTHS])
-@pytest.mark.parametrize("m", [DECODE_SLOTS, ServeConfig().prefill_chunk],
-                         ids=["decode", "prefill-chunk"])
-def test_packed_awq_int4_compiles(one_chip, m, k, n):
-    """The decode GEMV (M <= 8) and the prefill-chunk matmul, at every
-    granite-8b projection width, as ``quantized_matmul`` dispatches them."""
-    scheme = get_scheme("awq_int4")
+def _compile_packed(one_chip, scheme_name, m, k, n):
+    scheme = get_scheme(scheme_name)
     kernel = packed_gemv if m <= 8 else packed_matmul
 
     def fn(x, packed, scales):
         qw = QuantizedLinearWeights(scheme, packed, scales, (k, n))
         return kernel(x, qw, interpret=False)
-    hlo = _compile(fn, one_chip, ((m, k), jnp.bfloat16),
-                   *_packed_weight_shapes("awq_int4", k, n))
-    assert "tpu_custom_call" in hlo
+    return _compile(fn, one_chip, ((m, k), jnp.bfloat16),
+                    *_packed_weight_shapes(scheme_name, k, n))
+
+
+@pytest.mark.parametrize("k,n", GRANITE_WIDTHS,
+                         ids=[f"{k}x{n}" for k, n in GRANITE_WIDTHS])
+@pytest.mark.parametrize("m", [DECODE_SLOTS, CELL_ROWS, AUTO_KERNEL_MAX_ROWS],
+                         ids=["decode", "prefill-chunk", "auto-bound"])
+def test_packed_awq_int4_compiles(one_chip, m, k, n):
+    """The GEMV (M <= 8), the products of a 16-token prefill chunk (and of
+    the benchmark cell's 16 decode slots) and the largest M that
+    ``kernel='auto'`` sends to the kernel, at every granite-8b projection
+    width, as ``quantized_matmul`` dispatches them."""
+    assert "tpu_custom_call" in _compile_packed(one_chip, "awq_int4", m, k, n)
+
+
+@pytest.mark.parametrize("k,n", GRANITE_WIDTHS,
+                         ids=[f"{k}x{n}" for k, n in GRANITE_WIDTHS])
+@pytest.mark.parametrize("scheme", ["mxfp4", "fp8"])
+def test_packed_schemes_compile(one_chip, scheme, k, n):
+    """The other packed schemes' plans compile at the cell's M."""
+    assert "tpu_custom_call" in _compile_packed(one_chip, scheme, CELL_ROWS,
+                                                k, n)
