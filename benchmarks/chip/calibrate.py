@@ -46,8 +46,8 @@ def main(argv=None) -> int:
     controls = (args.ref_control,) if args.ref_control else ()
     mutate = faults.FAULTS[args.fault] if args.fault else None
     for seed in [int(s) for s in args.seeds.split(",")]:
-        r = run_cell(args.workload, seed, args.seconds, False,
-                     controls=controls, mutate=mutate)
+        r, _ = run_cell(args.workload, seed, args.seconds, False,
+                        controls=controls, mutate=mutate)
         print(json.dumps({"seed": seed, "fault": args.fault,
                           "correct": r["correct"],
                           "program": r.get("gaps", {}),

@@ -18,11 +18,11 @@ import shutil
 import sys
 import tempfile
 import time
-from typing import Callable, Dict, List, Optional
+from typing import Callable, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from . import check, spec, trace as tr
+from . import check, scopes, spec, trace as tr
 from .traffic import Traffic
 
 
@@ -98,12 +98,16 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
              root: str = spec.ROOT, bench_dir: str = spec.BENCH_DIR,
              mutate: Optional[Callable] = None,
              t_start: Optional[float] = None,
-             controls: tuple = ()) -> Dict:
-    """One run; returns the result line's object.  ``mutate(engine)``, when
-    given, is applied to the engine before warm-up (``chipbench.faults``
-    break the timed path with it); ``controls`` (``calibrate.py``) are
-    lower precisions of the reference whose readings are added to the
-    result beside the program's."""
+             controls: tuple = (), trace_dir: Optional[str] = None
+             ) -> Tuple[Dict, Dict]:
+    """One run; returns the result line's object and the run's record,
+    which the per-layer readers read.  ``mutate(engine)``, when given, is
+    applied to the engine before warm-up (``chipbench.faults`` break the
+    timed path with it); ``controls`` (``calibrate.py``) are lower
+    precisions of the reference whose readings are added to the result
+    beside the program's.  A traced run writes its trace under
+    ``trace_dir`` and leaves it there (the record's ``trace_file``), or,
+    when None, in a temporary directory that it removes."""
     t_start = time.perf_counter() if t_start is None else t_start
     import jax
     from repro.serve import Request, SamplingParams, Scheduler, ServingEngine
@@ -118,7 +122,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     traffic = Traffic(mix, seed, cfg.vocab)
     counter = CompileCounter()
 
-    params = weights.make_params(cfg_file, cfg, seed)
+    params = weights.make_params(cfg_file, cfg, seed, bench_dir)
     jax.block_until_ready(params)
     engine = ServingEngine(cfg, params, spec.serve_config(
         cfg_file, max_len=traffic.max_len))
@@ -162,7 +166,9 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         for _ in out["finished"]:
             submit(*traffic.next())
 
-    trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-") if trace else None
+    keep_trace = trace_dir is not None
+    if trace and not keep_trace:
+        trace_dir = tempfile.mkdtemp(prefix="chipbench-trace-")
     # the first fill, until every client's request is decoding
     t_fill = clock()
     for prompt, max_new in traffic.fill(pool.n_slots):
@@ -197,7 +203,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
 
     rec = {"workload": workload, "n_slots": pool.n_slots, "t0": t0,
            "t_end": t_end, "window_s": window_s, "steps": steps,
-           "served": served, "cfg_file": cfg_file,
+           "served": served, "cfg_file": cfg_file, "bench_dir": bench_dir,
            "max_len": traffic.max_len, "device_kind": dev.device_kind,
            "seconds": seconds, "prefill_partial": partial}
     log(f"cell {workload}: seed {seed}, {pool.n_slots} slots x "
@@ -210,10 +216,20 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
     red = None
     if trace:
         try:
-            red = tr.reduce(tr.find_xplane(trace_dir))
+            path = tr.find_xplane(trace_dir)
+            t = clock()
+            red = tr.reduce(path)
+            t_mid = clock()
+            rec["scopes"] = scopes.reduce(path)
+            log(f"trace reduced: by module in {t_mid - t!r} s, by scope "
+                f"and span in {clock() - t_mid!r} s")
         finally:
-            shutil.rmtree(trace_dir, ignore_errors=True)
+            if not keep_trace:
+                shutil.rmtree(trace_dir, ignore_errors=True)
         rec["trace"] = red
+        if keep_trace:
+            rec["trace_file"] = path
+        log(scopes.table(rec["scopes"]))
     finished = [s for s in served.values() if s.req.is_finished]
     attempted = [s for s in served.values() if s.sent <= t_end]
     failed = [s for s in finished if s.req.finish_reason != "length"]
@@ -266,7 +282,7 @@ def run_cell(workload: str, seed: int, seconds: float, trace: bool, *,
         result["gaps"] = verdict["gaps"]
         result["controls"] = verdict["controls"]
     result["checks"] = checks
-    return result
+    return result, rec
 
 
 def end_to_end(rec: Dict, setup_s: float) -> Dict[str, float]:

@@ -7,7 +7,12 @@ cell lives in a file of its own under the benchmark's directory:
     traffic/<mix>.json           parameters of the traffic generator
     limits/<workload>.json       the correctness limit of one cell
     layer_metrics/<metric>.py    one reader per per-layer metric
-    references/<name>.py         the plain reference a configuration names
+    references/<name>.py         the plain reference a configuration names:
+                                 its forward pass, its linears, and rules
+                                 for any leaf the harness does not draw
+    formats/<format>.py          one weight format: the program's leaves,
+                                 the reference's floats, the bytes a read
+                                 costs
 
 ``BENCHMARK.json`` at the checkout's root names the cells; this module only
 looks files up by those names, so a new cell is new files and entries.
@@ -83,16 +88,32 @@ def load_limits(name: str, bench_dir: str = BENCH_DIR) -> Dict[str, Any]:
     return _json(os.path.join(bench_dir, "limits", name + ".json"))
 
 
+_MODULES: Dict[str, Any] = {}
+
+
 def load_module(kind: str, name: str, bench_dir: str = BENCH_DIR):
-    """Import ``<bench_dir>/<kind>/<name>.py`` (names may hold dots)."""
-    path = os.path.join(bench_dir, kind, name + ".py")
+    """Import ``<bench_dir>/<kind>/<name>.py`` (names may hold dots), once
+    per process and file; None when there is no such file."""
+    path = os.path.realpath(os.path.join(bench_dir, kind, name + ".py"))
+    if path in _MODULES:
+        return _MODULES[path]
     if not os.path.exists(path):
         return None
     spec = importlib.util.spec_from_file_location(
         f"chipbench_{kind}_{name.replace('.', '_').replace('-', '_')}", path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
+    _MODULES[path] = mod
     return mod
+
+
+def layer_format(w: Dict[str, Any], k: int) -> Dict[str, Any]:
+    """The weight format ``w`` as it applies to a linear of ``k`` inputs:
+    one scale per ``group`` inputs, or one per column where the file
+    states -1 or the linear has fewer inputs than a group, as the program
+    holds them (``repro.quant.schemes.effective_group``)."""
+    g = w["group"]
+    return dict(w, group=k if g == -1 or g > k else g)
 
 
 def model_config(cfg_file: Dict[str, Any]):

@@ -17,6 +17,7 @@ def read(rec):
     if not runs or not secs or not subs:
         return None
     pk = costs.peak(rec["device_kind"])
-    need = sum(costs.roofline_seconds(costs.decode_step(rec["cfg_file"], lv),
-                                      pk) for lv in subs)
+    need = sum(costs.roofline_seconds(
+        costs.decode_step(rec["cfg_file"], lv, rec.get("bench_dir")), pk)
+        for lv in subs)
     return 100.0 * need / secs

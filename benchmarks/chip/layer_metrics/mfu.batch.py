@@ -10,20 +10,21 @@ def read(rec):
     red = rec.get("trace")
     if not red or not red["window_s"]:
         return None
-    cfg = rec["cfg_file"]
+    cfg, bench_dir = rec["cfg_file"], rec.get("bench_dir")
     flops = 0.0
     for lives in records.decode_substeps(rec):
-        flops += costs.decode_step(cfg, lives)["flops"]
+        flops += costs.decode_step(cfg, lives, bench_dir)["flops"]
     t0, t1 = rec["t0"], rec["t_end"]
     for s in rec["served"].values():
         done = s.deliveries and t0 <= s.deliveries[0][0] <= t1 \
             and s.sent >= t0
         if done:
             n = s.req.prompt_len
-            flops += sum(costs.prefill_token_flops(cfg, p, p == n - 1)
+            flops += sum(costs.prefill_token_flops(cfg, p, p == n - 1,
+                                                 bench_dir)
                          for p in range(n))
     for n, pos in rec["prefill_partial"]:
-        flops += sum(costs.prefill_token_flops(cfg, p, False)
+        flops += sum(costs.prefill_token_flops(cfg, p, False, bench_dir)
                      for p in range(pos))
     if not flops:
         return None

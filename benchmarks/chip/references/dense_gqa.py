@@ -15,8 +15,13 @@ read from the program under test.
                           from the file; positions 0, 1, ...)
                 x += softmax(q k^T / sqrt(d_head) + causal) v  Wo
                 h = rms(x) * g2
-                x += (silu(h Wg) * h Wu) Wd      gated SiLU FFN
+                x += (silu(h Wg) * h Wu) Wd      gated SiLU FFN, or
+                x += gelu_tanh(h Win) Wout       the plain GELU FFN
     logits = (rms(x) * g_f) W_lm
+
+``linears`` lists one layer's linears as the program names them, which
+are the leaves the weights are drawn from and what ``chipbench.costs``
+counts.  Any other FFN, norm or a tied lm_head is refused.
 
 ``lower="fp8"`` computes the same forward with every activation that
 enters a product (the inputs of each linear, q, k and v) rounded to FP8
@@ -25,6 +30,7 @@ E4M3 with one scale per row: the control of the correctness check.
 from __future__ import annotations
 
 import functools
+import os
 from typing import Dict, Optional
 
 import jax
@@ -34,17 +40,42 @@ import numpy as np
 from chipbench import weights as W
 
 HI = jax.lax.Precision.HIGHEST
+# the benchmark directory this module lies in, where the weight formats'
+# rules are looked up
+BENCH_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+FFNS = {("silu", True): "gated", ("gelu", False): "plain"}
+
+
+def _ffn(m: Dict) -> str:
+    ffn = FFNS.get((m["activation"], m["gated_ffn"]))
+    if ffn is None or m["norm"] != "rms" or m["tie_embeddings"]:
+        raise ValueError("the reference has RMSNorm, an untied lm_head and "
+                         "the gated SiLU or plain GELU FFN only")
+    return ffn
+
+
+def linears(m: Dict) -> Dict[str, Dict[str, tuple]]:
+    """(K, N) of each linear of one layer, by weight kind and by the
+    program's name for it."""
+    d, h, hk, dh, f = (m["d_model"], m["n_heads"], m["n_kv_heads"],
+                       m["d_head"], m["d_ff"])
+    attn = {"attn/wq": (d, h * dh), "attn/wk": (d, hk * dh),
+            "attn/wv": (d, hk * dh), "attn/wo": (h * dh, d)}
+    if _ffn(m) == "gated":
+        ffn = {"ffn/w_gate": (d, f), "ffn/w_up": (d, f), "ffn/w_down": (f, d)}
+    else:
+        ffn = {"ffn/w_in": (d, f), "ffn/w_out": (f, d)}
+    return {"proj": attn, "ffn": ffn}
 
 
 def _static(cfg_file: Dict) -> tuple:
     m, w = cfg_file["model"], cfg_file["weights"]
-    if (m["activation"], m["gated_ffn"]) != ("silu", True):
-        raise ValueError("the reference has the gated SiLU FFN only")
     return (m["n_layers"], m["d_model"], m["n_heads"], m["n_kv_heads"],
             m["d_head"], m["d_ff"], m["vocab"], float(m["norm_eps"]),
             float(m["rope_theta"]),
             (w["proj"]["format"], w["proj"]["group"], w["proj"]["scale"]),
-            (w["ffn"]["format"], w["ffn"]["group"], w["ffn"]["scale"]))
+            (w["ffn"]["format"], w["ffn"]["group"], w["ffn"]["scale"]),
+            _ffn(m))
 
 
 def _round8(x, lower):
@@ -83,11 +114,11 @@ def _embed(key, tokens, st):
 
 @functools.partial(jax.jit, static_argnums=(3, 4))
 def _layer(x, key, layer, st, lower):
-    (_, d, h, hk, dh, f, _, eps, theta, wp, wf) = st
+    (_, d, h, hk, dh, f, _, eps, theta, wp, wf, ffn) = st
     wp = {"format": wp[0], "group": wp[1], "scale": wp[2]}
     wf = {"format": wf[0], "group": wf[1], "scale": wf[2]}
     lin = lambda name, k, n, fmt: W.dense_weight(  # noqa: E731
-        W.leaf_key(key, "layers/" + name), layer, k, n, fmt)
+        W.leaf_key(key, "layers/" + name), layer, k, n, fmt, BENCH_DIR)
     b, s, _ = x.shape
     a = _rms(x, W.vector(W.leaf_key(key, "layers/ln1/g"), layer, d), eps)
     q = _lin(a, lin("attn/wq", d, h * dh, wp), lower).reshape(b, s, h, dh)
@@ -103,6 +134,10 @@ def _layer(x, key, layer, st, lower):
     x = x + _lin(o.reshape(b, s, h * dh), lin("attn/wo", h * dh, d, wp),
                  lower)
     a = _rms(x, W.vector(W.leaf_key(key, "layers/ln2/g"), layer, d), eps)
+    if ffn == "plain":
+        hid = jax.nn.gelu(_lin(a, lin("ffn/w_in", d, f, wf), lower),
+                          approximate=True)
+        return x + _lin(hid, lin("ffn/w_out", f, d, wf), lower)
     g = _lin(a, lin("ffn/w_gate", d, f, wf), lower)
     u = _lin(a, lin("ffn/w_up", d, f, wf), lower)
     hid = g * jax.nn.sigmoid(g) * u

@@ -54,8 +54,8 @@ def main(argv=None) -> int:
         return 1
     spec.use_compile_cache()
     from chipbench.cell import run_cell
-    result = run_cell(args.workload, args.seed, args.seconds,
-                      bool(args.trace), t_start=T_START)
+    result, _ = run_cell(args.workload, args.seed, args.seconds,
+                         bool(args.trace), t_start=T_START)
     print(json.dumps(result))
     return 0
 

@@ -1,6 +1,6 @@
 """The correctness check fails what it must: its control and planted faults.
 
-At the cut-down granite configuration on the CPU, a whole run (everything
+At cut-down configurations on the CPU, a whole run (everything
 but the look for a chip) is driven with the timed path broken underneath
 (``chipbench.faults``), and ``correct`` must come out false; the control —
 the reference computed with FP8 activations in place of the program — must
@@ -9,7 +9,9 @@ it must be false.  The number compared is the mean gap of the served tokens
 below the reference's best logit.  The test cell's limit was set the way
 the chip cell's is, from readings on the CPU over seeds 1-12 (1 s windows,
 every finished request compared): above the largest mean gap sound runs
-gave (0.00023) and below the smallest the control gave (0.00313).
+gave (granite-smoke 0.00023, starcoder2-smoke's plain GELU FFN 0.000126)
+and below the smallest the control gave (0.00313, 0.00178).  A plain FFN
+reference with SiLU in place of GELU reads 0.016-0.018 on starcoder2-smoke.
 """
 import pytest
 
@@ -17,46 +19,48 @@ import smoke_cell
 from chipbench import faults
 from chipbench.cell import run_cell
 
-LIMITS = {"smoke-closed": 0.001}
-SEEDS = {"smoke-closed": 1}
+# cell: (configuration, traffic mix)
+CELLS = {"smoke-closed": ("granite-smoke", "smoke-closed"),
+         "starcoder2-smoke-closed": ("starcoder2-smoke", "smoke-closed")}
+LIMIT = 0.001
+SEED = 1
 
 
 @pytest.fixture(scope="module")
 def copies(tmp_path_factory):
     made = {}
 
-    def get(mix):
-        if mix not in made:
+    def get(cell):
+        if cell not in made:
             root, bench, names = smoke_cell.make_copy(
-                tmp_path_factory.mktemp(mix), [("granite-smoke", mix)],
-                limit=LIMITS[mix])
-            made[mix] = (mix, root, bench, names[0])
-        return made[mix]
+                tmp_path_factory.mktemp(cell), [CELLS[cell]], limit=LIMIT)
+            made[cell] = (root, bench, names[0])
+        return made[cell]
     return get
 
 
 def _run(cell, mutate=None, controls=()):
-    mix, root, bench, name = cell
-    return run_cell(name, SEEDS[mix], 1.0, False, root=root,
-                    bench_dir=bench, mutate=mutate, controls=controls)
+    root, bench, name = cell
+    return run_cell(name, SEED, 1.0, False, root=root, bench_dir=bench,
+                    mutate=mutate, controls=controls)[0]
 
 
-@pytest.mark.parametrize("mix", ["smoke-closed"])
-def test_sound_run_passes_and_control_fails(copies, mix):
-    r = _run(copies(mix), controls=("fp8",))
+@pytest.mark.parametrize("cell", ["smoke-closed", "starcoder2-smoke-closed"])
+def test_sound_run_passes_and_control_fails(copies, cell):
+    r = _run(copies(cell), controls=("fp8",))
     gap = r["checks"]["mean_logit_gap"]
     assert r["correct"] is True and gap["value"] <= gap["limit"]
     control = r["controls"]["ref_fp8"]
     assert control["mean"] > gap["limit"] and control["ok"] is False
 
 
-@pytest.mark.parametrize("mix,fault", [
+@pytest.mark.parametrize("cell,fault", [
     ("smoke-closed", "state_unchanged"), ("smoke-closed", "half_batch"),
     ("smoke-closed", "token_altered")],
     ids=["closed-state_unchanged", "closed-half_batch",
          "closed-token_altered"])
-def test_broken_timed_path_is_not_correct(copies, mix, fault):
-    r = _run(copies(mix), mutate=faults.FAULTS[fault])
+def test_broken_timed_path_is_not_correct(copies, cell, fault):
+    r = _run(copies(cell), mutate=faults.FAULTS[fault])
     gap = r["checks"]["mean_logit_gap"]
     assert r["correct"] is False, gap
     assert gap["value"] > gap["limit"]

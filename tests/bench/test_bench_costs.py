@@ -55,8 +55,8 @@ def test_roofline_takes_the_larger_bound():
 
 
 @pytest.mark.parametrize("fmt", [
-    {"format": "e2m1", "group": 32, "scale": "pow2"},
-    {"format": "int4", "group": 128, "scale": "bf16"}], ids=["e2m1", "int4-bf16"])
+    {"format": "int3", "group": 32, "scale": "f32"},
+    {"format": "int4", "group": 128, "scale": "bf16"}], ids=["int3", "int4-bf16"])
 def test_formats_without_a_rule_are_refused(granite, fmt):
     """A configuration whose weights have no drawing or counting rule fails
     loudly instead of being measured against the wrong yardstick."""
