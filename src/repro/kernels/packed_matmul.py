@@ -3,8 +3,9 @@
 This is the TPU realization of the paper's Section VI GEMV engine: weights
 live in HBM as packed sub-byte codes (8x INT4/FP4 or 4x INT8/FP8 per int32
 word — the analogue of the 512-bit HBM channel words feeding XtraMAC
-chains), are streamed block-by-block into VMEM, decoded with XtraMAC
-Stage-1 semantics (DAZ, implicit-one restore) one code plane at a time,
+chains), are streamed block-by-block into VMEM, decoded one code plane at
+a time (integer and FP8 codes with XtraMAC Stage-1 semantics: DAZ,
+implicit-one restore; FP4 codes as OCP MX E2M1, subnormals included),
 scaled, rounded to bf16 and fed to the MXU with f32 accumulation — the
 same bf16 weight operand the jnp path (``quant.schemes.dequantize``) makes,
 without writing it to HBM.
@@ -38,7 +39,7 @@ from repro.quant.schemes import (
 
 
 # ---------------------------------------------------------------------------
-# In-kernel arithmetic decode (no gathers — TPU-friendly), DAZ semantics
+# In-kernel arithmetic decode (no gathers — TPU-friendly)
 # ---------------------------------------------------------------------------
 def _decode_int(codes, bits: int):
     half = 1 << (bits - 1)
@@ -46,10 +47,12 @@ def _decode_int(codes, bits: int):
 
 
 def _decode_fp4_e2m1(codes):
+    """OCP MX v1.0 E2M1: exponent field 0 holds 0 and the subnormal 0.5
+    (codes 1 and 9 read +-0.5, not the FPGA MAC model's DAZ zero)."""
     s = (codes >> 3) & 1
     e = (codes >> 1) & 3
     m = codes & 1
-    mag = jnp.where(e == 0, 0.0,
+    mag = jnp.where(e == 0, 0.5 * m.astype(jnp.float32),
                     (2 + m).astype(jnp.float32) * jnp.exp2((e - 2).astype(jnp.float32)))
     return jnp.where(s == 1, -mag, mag)
 

@@ -54,6 +54,7 @@ class AttnConfig:
     causal: bool = True
     qkv_scheme: Optional[str] = None    # quantization scheme for projections
     kv_chunk: int = 512
+    use_bias: bool = False              # a bias on each of q, k, v and o
 
 
 # ---------------------------------------------------------------------------
@@ -62,16 +63,27 @@ class AttnConfig:
 def attn_params(mk: Maker, cfg: AttnConfig, stack: Tuple[int, ...]) -> Dict[str, Any]:
     d, h, hk, dh = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.d_head
     s = cfg.qkv_scheme
-    return {
+    p = {
         "wq": mk.dense("attn.wq", stack, d, h * dh, scheme=s),
         "wk": mk.dense("attn.wk", stack, d, hk * dh, scheme=s),
         "wv": mk.dense("attn.wv", stack, d, hk * dh, scheme=s),
         "wo": mk.dense("attn.wo", stack, h * dh, d, scheme=s),
     }
+    if cfg.use_bias:
+        for w, n in (("q", h * dh), ("k", hk * dh), ("v", hk * dh),
+                     ("o", d)):
+            p["b" + w] = mk.bias(f"attn.w{w}.b", stack, n)
+    return p
 
 
 def cross_attn_params(mk: Maker, cfg: AttnConfig, stack) -> Dict[str, Any]:
     return attn_params(mk, cfg, stack)
+
+
+def _proj(params, w: str, x):
+    """x @ projection ``w`` ('q', 'k', 'v', 'o'), plus its bias if it has
+    one."""
+    return apply_linear(params["w" + w], x, bias=params.get("b" + w))
 
 
 # ---------------------------------------------------------------------------
@@ -225,9 +237,9 @@ def gqa_forward(params, cfg: AttnConfig, x, *, positions=None,
         arena = cache
         with jax.named_scope("attn_core"):
             cache = tuple(gather_pages(a, page_table) for a in arena)
-    q = shard_act(apply_linear(params["wq"], x).reshape(b, s, h, dh), "bthd")
-    k = shard_act(apply_linear(params["wk"], x).reshape(b, s, hk, dh), "bthd")
-    v = shard_act(apply_linear(params["wv"], x).reshape(b, s, hk, dh), "bthd")
+    q = shard_act(_proj(params, "q", x).reshape(b, s, h, dh), "bthd")
+    k = shard_act(_proj(params, "k", x).reshape(b, s, hk, dh), "bthd")
+    v = shard_act(_proj(params, "v", x).reshape(b, s, hk, dh), "bthd")
 
     per_row = cache_index is not None and jnp.ndim(cache_index) == 1
     if positions is None:
@@ -277,7 +289,7 @@ def gqa_forward(params, cfg: AttnConfig, x, *, positions=None,
             new_cache = tuple(scatter_pages(a, page_table, v)
                               for a, v in zip(arena, new_cache))
     out = out.reshape(b, s, h * dh)
-    return apply_linear(params["wo"], out), new_cache
+    return _proj(params, "o", out), new_cache
 
 
 def gqa_cache_spec(cfg: AttnConfig, batch: int, max_len: int, dtype=jnp.bfloat16):
@@ -304,11 +316,11 @@ def cross_attn_forward(params, cfg: AttnConfig, x, enc):
     b, sq, d = x.shape
     sk = enc.shape[1]
     h, hk, dh = cfg.n_heads, cfg.n_kv_heads, cfg.d_head
-    q = shard_act(apply_linear(params["wq"], x).reshape(b, sq, h, dh), "bthd")
-    k = shard_act(apply_linear(params["wk"], enc).reshape(b, sk, hk, dh), "bthd")
-    v = shard_act(apply_linear(params["wv"], enc).reshape(b, sk, hk, dh), "bthd")
+    q = shard_act(_proj(params, "q", x).reshape(b, sq, h, dh), "bthd")
+    k = shard_act(_proj(params, "k", enc).reshape(b, sk, hk, dh), "bthd")
+    v = shard_act(_proj(params, "v", enc).reshape(b, sk, hk, dh), "bthd")
     out = attend(q, k, v, causal=False, kv_chunk=cfg.kv_chunk)
-    return apply_linear(params["wo"], out.reshape(b, sq, h * dh))
+    return _proj(params, "o", out.reshape(b, sq, h * dh))
 
 
 # ---------------------------------------------------------------------------

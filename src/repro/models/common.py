@@ -114,13 +114,20 @@ def shard_act(x, kind: str):
     return jax.lax.with_sharding_constraint(x, s)
 
 
-def apply_linear(leaf, x, out_dtype=jnp.bfloat16):
-    """x [..., K] @ linear leaf -> [..., N]; dispatches dense vs quantized.
+def apply_linear(leaf, x, out_dtype=jnp.bfloat16, bias=None):
+    """x [..., K] @ linear leaf (+ ``bias`` [N]) -> [..., N]; dispatches
+    dense vs quantized.
 
     Dots are bf16-storage: the TPU MXU accumulates in f32 natively, and
     requesting an f32 result dtype makes the CPU backend (the dry-run
-    instrument) materialize f32 copies of the weights per use.
+    instrument) materialize f32 copies of the weights per use.  A bias is
+    added to the product's f32 result under the ``bias`` scope, outside
+    ``qmatmul``, and the sum is rounded once to ``out_dtype``.
     """
+    if bias is not None:
+        y = apply_linear(leaf, x, jnp.float32)
+        with jax.named_scope("bias"):
+            return (y + bias).astype(out_dtype)
     if isinstance(leaf, QLinear):
         qw = QuantizedLinearWeights(
             get_scheme(leaf.scheme_name), leaf.packed, leaf.scales,
@@ -154,6 +161,10 @@ class Maker:
                init: float = 0.0):
         raise NotImplementedError
 
+    def bias(self, name: str, stack: Tuple[int, ...], n: int):
+        """The f32 [*stack, n] bias of linear ``name`` (its output axis)."""
+        raise NotImplementedError
+
 
 class InitMaker(Maker):
     """Real dense bf16 parameters (ignores quantization schemes)."""
@@ -178,6 +189,9 @@ class InitMaker(Maker):
 
     def vector(self, name, stack, dim, init=0.0):
         return jnp.full(stack + (dim,), init, jnp.float32)
+
+    def bias(self, name, stack, n):
+        return _draw_bias(self._next(), stack + (n,))
 
 
 class QuantMaker(InitMaker):
@@ -260,6 +274,17 @@ class QuantMaker(InitMaker):
                         device=self._placement("vector", name, stack, dim,
                                                init))
 
+    def bias(self, name, stack, n):
+        key = self._next()
+        return jax.jit(lambda: _draw_bias(key, stack + (n,)),
+                       out_shardings=self._placement("bias", name, stack,
+                                                     n))()
+
+
+def _draw_bias(key, shape):
+    """N(0, 0.02^2) f32 biases: non-zero, so a dropped bias shows."""
+    return jax.random.normal(key, shape, jnp.float32) * 0.02
+
 
 def _draw_dense(key, shape, dtype):
     """N(0, 1/k) weights [..., k, n] in ``dtype``."""
@@ -323,6 +348,9 @@ class AbstractMaker(Maker):
     def vector(self, name, stack, dim, init=0.0):
         return jax.ShapeDtypeStruct(stack + (dim,), jnp.float32)
 
+    def bias(self, name, stack, n):
+        return jax.ShapeDtypeStruct(stack + (n,), jnp.float32)
+
 
 class PspecMaker(Maker):
     """PartitionSpec trees.  Axis names resolved via a rule callback
@@ -354,6 +382,9 @@ class PspecMaker(Maker):
         return P(*([None] * len(stack) + [self.rule(name, 0)]))
 
     def vector(self, name, stack, dim, init=0.0):
+        return P(*([None] * len(stack) + [self.rule(name, 0)]))
+
+    def bias(self, name, stack, n):
         return P(*([None] * len(stack) + [self.rule(name, 0)]))
 
 

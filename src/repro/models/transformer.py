@@ -53,6 +53,7 @@ class ModelConfig:
     rope_theta: float = 10000.0
     use_rope: bool = True
     tie_embeddings: bool = True
+    use_bias: bool = False      # a bias on every attention and FFN linear
     # --- quantization plan (the paper's technique) ---
     scheme_proj: Optional[str] = None    # attention/ssm projection weights
     scheme_ffn: Optional[str] = None     # FFN / expert weights
@@ -86,6 +87,11 @@ class ModelConfig:
     logit_softcap: float = 0.0
     microbatches: int = 1   # gradient-accumulation splits of the train batch
 
+    def __post_init__(self):
+        if self.use_bias and (self.use_mla or self.n_experts):
+            raise ValueError(f"{self.name}: use_bias covers GQA attention "
+                             "and the dense FFN, not MLA or expert layers")
+
     @property
     def head_dim(self) -> int:
         return self.d_head or self.d_model // self.n_heads
@@ -104,7 +110,8 @@ class ModelConfig:
             n_kv_heads=self.n_kv_heads, d_head=self.head_dim,
             rope_theta=self.rope_theta,
             use_rope=self.use_rope if use_rope is None else use_rope,
-            causal=causal, qkv_scheme=self.scheme_proj, kv_chunk=self.kv_chunk)
+            causal=causal, qkv_scheme=self.scheme_proj, kv_chunk=self.kv_chunk,
+            use_bias=self.use_bias)
 
     def mla_cfg(self) -> A.MLAConfig:
         return A.MLAConfig(
@@ -150,6 +157,7 @@ def _norm_params(mk: Maker, cfg: ModelConfig, name: str, stack, dim=None):
     return p
 
 
+@jax.named_scope("norm")
 def _apply_norm(cfg: ModelConfig, p, x):
     if cfg.norm == "layer":
         return layer_norm(x, p["g"], p["b"])
@@ -161,26 +169,32 @@ def _apply_norm(cfg: ModelConfig, p, x):
 # ---------------------------------------------------------------------------
 def _ffn_params(mk: Maker, cfg: ModelConfig, stack):
     d, f, s = cfg.d_model, cfg.d_ff, cfg.scheme_ffn
-    if cfg.gated_ffn:
-        return {"w_gate": mk.dense("ffn.w_gate", stack, d, f, scheme=s),
-                "w_up": mk.dense("ffn.w_up", stack, d, f, scheme=s),
-                "w_down": mk.dense("ffn.w_down", stack, f, d, scheme=s)}
-    return {"w_in": mk.dense("ffn.w_in", stack, d, f, scheme=s),
-            "w_out": mk.dense("ffn.w_out", stack, f, d, scheme=s)}
+    mats = ({"gate": (d, f), "up": (d, f), "down": (f, d)} if cfg.gated_ffn
+            else {"in": (d, f), "out": (f, d)})
+    p = {}
+    for w, (k, n) in mats.items():
+        p["w_" + w] = mk.dense("ffn.w_" + w, stack, k, n, scheme=s)
+        if cfg.use_bias:
+            p["b_" + w] = mk.bias(f"ffn.w_{w}.b", stack, n)
+    return p
+
+
+def _ffn_linear(p, w: str, x):
+    """x @ FFN linear ``w``, plus its bias if it has one."""
+    return apply_linear(p["w_" + w], x, bias=p.get("b_" + w))
 
 
 def _ffn_apply(cfg: ModelConfig, p, x):
     if cfg.gated_ffn:
-        g = shard_act(apply_linear(p["w_gate"], x), "btf")
-        u = shard_act(apply_linear(p["w_up"], x), "btf")
+        g = shard_act(_ffn_linear(p, "gate", x), "btf")
+        u = shard_act(_ffn_linear(p, "up", x), "btf")
         h = (activate(cfg.activation, g.astype(jnp.float32))
              * u.astype(jnp.float32)).astype(jnp.bfloat16)
-        return apply_linear(p["w_down"], h)
+        return _ffn_linear(p, "down", h)
     # non-gated path: activation math stays bf16 — relu^2/gelu are stable in
     # bf16 and the f32 cast otherwise stacks f32 saved-residuals per layer
-    h = activate(cfg.activation,
-                 shard_act(apply_linear(p["w_in"], x), "btf"))
-    return apply_linear(p["w_out"], h.astype(jnp.bfloat16))
+    h = activate(cfg.activation, shard_act(_ffn_linear(p, "in", x), "btf"))
+    return _ffn_linear(p, "out", h.astype(jnp.bfloat16))
 
 
 # ---------------------------------------------------------------------------

@@ -9,12 +9,17 @@ weights are quantized into it:
   mxfp4      MXFP4: FP4 E2M1 + UE8M0 power-of-2 scale  -> FP4 x BF16 + BF16
   bf16       no quantization (attention MACs)          -> BF16 x BF16 + BF16
 
-The dequant LUTs are generated from core.formats codecs, so kernel-side
-decode is bit-identical to the XtraMAC Stage-1 mapping semantics (DAZ,
-implicit-one restore).  ``quantize_weights`` is the numpy quantizer
-(offline checkpoint prep, and the oracle); ``quantize_weights_jnp`` is its
-traceable twin, which builds serving weights on the device slice by slice;
-dequantize() has a jnp path used inside models and kernels.
+The FP8 dequant LUT is generated from the core.formats codec, so
+kernel-side decode is bit-identical to the XtraMAC Stage-1 mapping
+semantics (DAZ, implicit-one restore).  MXFP4 weights are served as the
+published format, OCP MX v1.0 E2M1, whose exponent-zero codes 1 and 9 are
+the subnormals +-0.5: the serving codec (``FP4_LUT``, ``encode_e2m1`` and
+the packed kernel's decode) reads and writes them, while the FPGA MAC
+model in ``core/`` keeps its denormals-as-zero rule.  ``quantize_weights``
+is the numpy quantizer (offline checkpoint prep, and the oracle);
+``quantize_weights_jnp`` is its traceable twin, which builds serving
+weights on the device slice by slice; dequantize() has a jnp path used
+inside models and kernels.
 """
 from __future__ import annotations
 
@@ -87,8 +92,24 @@ def dequant_lut(fmt_name: str) -> np.ndarray:
     return np.nan_to_num(vals, nan=0.0).astype(np.float32)
 
 
-FP4_LUT = dequant_lut("fp4_e2m1")
+# OCP MX v1.0 E2M1, codes 0..7 (code 8 + c is the negative of code c)
+E2M1_VALUES = (0.0, 0.5, 1.0, 1.5, 2.0, 3.0, 4.0, 6.0)
+FP4_LUT = np.array(E2M1_VALUES + tuple(-v for v in E2M1_VALUES), np.float32)
 FP8_LUT = dequant_lut("fp8_e4m3")
+# the midpoint between neighbouring E2M1 magnitudes, and whether a value
+# exactly on it rounds up (to-nearest-even: up where the upper code is even)
+_E2M1_MIDPOINTS = ((0.25, False), (0.75, True), (1.25, False), (1.75, True),
+                   (2.5, False), (3.5, True), (5.0, False))
+
+
+def encode_e2m1(x, xp=jnp):
+    """f32 values -> OCP E2M1 codes (int32), rounded to nearest even and
+    saturating at +-6, +-0.5 kept as the subnormal codes 1 and 9; ``xp`` is
+    ``jnp`` or ``numpy``, which give the same codes."""
+    mag = xp.abs(x)
+    code = sum((mag >= t if up else mag > t).astype(xp.int32)
+               for t, up in _E2M1_MIDPOINTS)
+    return code | (xp.signbit(x).astype(xp.int32) << 3)
 
 
 def _int_decode(codes, bits: int):
@@ -148,7 +169,10 @@ def quantize_weights(scheme: QuantScheme, w: np.ndarray) -> QuantizedLinearWeigh
         if scheme.scale_pow2:  # UE8M0: scale = 2^ceil(log2(absmax / max_finite))
             scales = np.exp2(np.ceil(np.log2(scales)))
         wg = w.reshape(k // g, g, n) / scales[:, None, :]
-        codes = F.quantize_f64(fmt, wg.astype(np.float64)).reshape(k, n)
+        if scheme.weight_format == "fp4_e2m1":
+            codes = encode_e2m1(wg, np).reshape(k, n)
+        else:
+            codes = F.quantize_f64(fmt, wg.astype(np.float64)).reshape(k, n)
 
     if scheme.packed:
         packed = pack_codes_np(codes.astype(np.int64), scheme.weight_bits)
@@ -181,8 +205,12 @@ def quantize_weights_jnp(scheme: QuantScheme, w):
         scales = absmax * jnp.float32(1.0 / fmt.max_finite)
         if scheme.scale_pow2:
             scales = jnp.exp2(jnp.ceil(jnp.log2(scales)))
-        codes = _encode_minifloat(fmt, jnp.clip(
-            wg / scales[:, None, :], -fmt.max_finite, fmt.max_finite))
+        scaled = jnp.clip(wg / scales[:, None, :], -fmt.max_finite,
+                          fmt.max_finite)
+        if scheme.weight_format == "fp4_e2m1":
+            codes = encode_e2m1(scaled)
+        else:
+            codes = _encode_minifloat(fmt, scaled)
     codes = codes.reshape(k, n)
     if scheme.packed:
         packed = pack_codes(codes, scheme.weight_bits)
@@ -280,7 +308,7 @@ def kv_unpack_codes(words):
 
 def _encode_minifloat(fmt, x):
     """jnp RN-even encode of f32 (already clipped to the format's max
-    finite) -> uint codes of the minifloat ``fmt`` (E4M3, E2M1), FTZ on
+    finite) -> uint codes of the minifloat ``fmt`` (E4M3), FTZ on
     underflow — bit-identical to ``core.formats.quantize_f64`` on this
     domain.  NOT the XLA float8 cast: that double-rounds through f16 on
     CPU, flipping round-to-nearest-even ties (e.g. 61.99 -> 64 instead of

@@ -143,6 +143,9 @@ def make_param_rule(cfg: ModelConfig, rules: AxisRules, dim_sizes):
 
     def rule(name: str, dim: int):
         base = name.split("@")[0]
+        if base.endswith(".b") and base[:-2] in _W_RULES:
+            # a linear's bias lies along its weight's output (N) axis
+            return rule(base[:-2], 1)
         roles = _W_RULES.get(base)
         if roles is None:
             # norms / vectors / tables: replicate (small), except conv

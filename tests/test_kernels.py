@@ -292,3 +292,39 @@ def test_interpret_mode_follows_platform(monkeypatch):
     assert resolve_interpret(False) is False
     with pytest.raises(ValueError, match="never runs on a TPU"):
         resolve_interpret(True)
+
+
+# OCP MX v1.0 E2M1, codes 0..15: the subnormals 0.5 (code 1) and -0.5
+# (code 9) are values, not zeros, and code 8 is -0
+OCP_E2M1 = (0.0, 0.5, 1.0, 1.5, 2.0, 3.0, 4.0, 6.0,
+            -0.0, -0.5, -1.0, -1.5, -2.0, -3.0, -4.0, -6.0)
+
+
+@pytest.mark.parametrize("code", range(16))
+def test_mxfp4_codes_read_and_write_as_ocp_e2m1(code):
+    """The serving codec of each E2M1 code: the packed kernel's decode, the
+    jnp LUT behind ``decode_codes`` / ``dequantize``, and the packed kernel
+    run on a weight holding the code all read the OCP value; both weight
+    quantizers write the code for that value times the group's scale."""
+    from repro.kernels.packed_matmul import _decode_fp4_e2m1
+    from repro.quant.schemes import decode_codes, quantize_weights_jnp
+    scheme = get_scheme("mxfp4")
+    want = np.float32(OCP_E2M1[code])
+    c = jnp.asarray([code], jnp.int32)
+    for got in (_decode_fp4_e2m1(c), decode_codes(scheme, c)):
+        got = np.asarray(got)[0]
+        assert got == want and np.signbit(got) == np.signbit(want)
+    # row 0 holds the code's value times 4; row 1 the largest magnitude,
+    # 6 * 4, which sets the first group's power-of-two scale to 4
+    w = np.zeros((256, 128), np.float32)
+    w[0], w[1] = 4.0 * want, 24.0
+    qw = quantize_weights(scheme, w)
+    assert float(qw.scales[0, 0]) == 4.0
+    packed_jnp, _ = quantize_weights_jnp(scheme, jnp.asarray(w))
+    np.testing.assert_array_equal(np.asarray(packed_jnp),
+                                  np.asarray(qw.packed))
+    assert int(np.asarray(qw.packed)[0, 0]) & 15 == code
+    x = jnp.zeros((8, 256), jnp.bfloat16).at[:, 0].set(1.0)
+    out = packed_gemv(x, qw, interpret=True)
+    np.testing.assert_array_equal(np.asarray(out)[:, 0],
+                                  np.full(8, 4.0 * want, np.float32))

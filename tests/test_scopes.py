@@ -119,6 +119,35 @@ def test_step_programs_carry_the_scopes(engine, program, kernel):
                        for n in names)
 
 
+@pytest.fixture(scope="module")
+def biased_engine():
+    """starcoder2's block: LayerNorm with beta, a bias on every linear."""
+    cfg = get_config("starcoder2-15b", smoke=True)
+    assert cfg.use_bias and cfg.norm == "layer"
+    params = T.build_params(cfg, QuantMaker(jax.random.PRNGKey(0)))
+    return ServingEngine(cfg, params, ServeConfig(
+        max_len=48, n_slots=4, prefill_chunk=8, max_burst=8))
+
+
+@pytest.mark.parametrize("kernel", ["jnp", "pallas"])
+def test_bias_and_norm_ops_land_in_their_blocks(biased_engine, kernel):
+    """Each bias add and each norm carries its own scope (``bias``,
+    ``norm``) inside ``attn`` / ``ffn`` (the final norm inside
+    ``lm_head``) and outside ``qmatmul``, so the benchmark's reduction
+    credits them to the block's own time and ``qmatmul`` stays the weight
+    products'."""
+    ops, _ = compiled_ops(biased_engine, "decode_burst", kernel)
+    for part, blocks in (("bias", {"attn", "ffn"}),
+                         ("norm", {"attn", "ffn", "lm_head"})):
+        found = [s for _, _, n, s in ops if f"/{part}/" in n]
+        assert found and all(s & blocks and "qmatmul" not in s
+                             for s in found), (part, found)
+        assert any("attn" in s for s in found)
+        assert any("ffn" in s for s in found)
+    adds = [s for op, _, n, s in ops if op == "add" and "/bias/" in n]
+    assert any("attn" in s for s in adds) and any("ffn" in s for s in adds)
+
+
 def _kernel_call(name):
     """(function, args) calling the Pallas kernel ``name`` in interpret
     mode at a small legal shape."""

@@ -150,6 +150,25 @@ def test_serve_pool_pspec_axes_and_structure():
         PT.serve_pool_pspec(get_config("xlstm-350m"), mesh, 8)
 
 
+def test_bias_specs_follow_their_weights_output_axis():
+    """starcoder2-15b's biases at tp=8: a bias shards like its weight's N
+    axis (q's 48 heads and the FFN's 24,576 columns over 'model'; k and
+    v's 4 heads, like their weights, replicated; o's and the FFN output's
+    d_model, the weight's un-sharded N, replicated); LayerNorm betas stay
+    whole."""
+    cfg = get_config("starcoder2-15b")
+    assert cfg.use_bias and cfg.norm == "layer"
+    specs = PT.param_specs(cfg, _amesh(1, 8), train=False, quantize=True)
+    attn, ffn = specs["layers"]["attn"], specs["layers"]["ffn"]
+    for b, w in (("bq", "wq"), ("bk", "wk"), ("bv", "wv"), ("bo", "wo")):
+        assert attn[b] == P(None, attn[w].packed[-1]), b
+    for b, w in (("b_in", "w_in"), ("b_out", "w_out")):
+        assert ffn[b] == P(None, ffn[w].packed[-1]), b
+    assert attn["bq"] == ffn["b_in"] == P(None, "model")
+    assert attn["bk"] == attn["bo"] == ffn["b_out"] == P(None, None)
+    assert specs["layers"]["ln1"]["b"] == P(None, None)
+
+
 def test_mla_pool_pspec_latent_stays_whole():
     """MLA pools shard slots only: the compressed latent is consumed whole
     by every head's absorbed contraction."""
@@ -318,6 +337,23 @@ def test_mesh_engine_single_device_bit_identical():
     assert pool.shardings is not None
 
 
+def test_mesh_engine_single_device_bit_identical_with_biases():
+    """The same on starcoder2's block: a (1, 1) mesh places the bias and
+    LayerNorm beta leaves with their specs and emits the meshless
+    tokens."""
+    cfg = get_config("starcoder2-15b", smoke=True)
+    params = T.build_params(cfg, QuantMaker(jax.random.PRNGKey(0)))
+    batch = {"tokens": np.random.default_rng(2).integers(
+        1, cfg.vocab, (3, 9)).astype(np.int32)}
+    scfg = dict(max_len=32, n_slots=4, prefill_chunk=8)
+    ref = ServingEngine(cfg, params, ServeConfig(**scfg)).generate(
+        batch, max_new_tokens=5)["generated"]
+    eng = ServingEngine(cfg, params, ServeConfig(
+        **scfg, mesh=make_mesh((1, 1), ("data", "model"))))
+    np.testing.assert_array_equal(
+        ref, eng.generate(batch, max_new_tokens=5)["generated"])
+
+
 # ---------------------------------------------------------------------------
 # Multi-device: the acceptance contract (CI multi-device job)
 # ---------------------------------------------------------------------------
@@ -363,6 +399,30 @@ def test_dp2_tp4_bit_identical_greedy_with_mid_flight_admission():
     assert got == ref
     assert sched.metrics.report()["topology"] == \
         {"n_devices": 8, "dp": 2, "tp": 4}
+
+
+@multi_device
+def test_dp2_tp4_bit_identical_greedy_with_biases():
+    """The contract on starcoder2's block (mxfp4 weights, LayerNorm with
+    beta, a bias on every linear): the q and FFN-input biases shard over
+    'model' with their weights' outputs, and greedy output on dp=2 x tp=4
+    with a mid-flight admission equals the meshless run's."""
+    from repro.quant.policy import PrecisionPolicy
+    cfg = get_config("starcoder2-15b", smoke=True)
+    params = T.build_params(cfg, QuantMaker(jax.random.PRNGKey(0)))
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(1, cfg.vocab, (n,)).astype(np.int32)
+               for n in (9, 6, 11, 8)]
+
+    def engine(mesh, kernel="auto"):
+        return ServingEngine(cfg, params, ServeConfig(
+            max_len=32, n_slots=8, prefill_chunk=8,
+            policy=PrecisionPolicy(kernel=kernel), mesh=mesh))
+
+    ref, _ = _run_workload(engine(None, "pallas"), prompts)
+    got, _ = _run_workload(engine(make_mesh((2, 4), ("data", "model"))),
+                           prompts)
+    assert got == ref
 
 
 @multi_device
