@@ -31,6 +31,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import resolve_interpret
 from repro.quant.schemes import (
@@ -48,13 +49,17 @@ def _decode_int(codes, bits: int):
 
 def _decode_fp4_e2m1(codes):
     """OCP MX v1.0 E2M1: exponent field 0 holds 0 and the subnormal 0.5
-    (codes 1 and 9 read +-0.5, not the FPGA MAC model's DAZ zero)."""
-    s = (codes >> 3) & 1
-    e = (codes >> 1) & 3
-    m = codes & 1
-    mag = jnp.where(e == 0, 0.5 * m.astype(jnp.float32),
-                    (2 + m).astype(jnp.float32) * jnp.exp2((e - 2).astype(jnp.float32)))
-    return jnp.where(s == 1, -mag, mag)
+    (codes 1 and 9 read +-0.5, not the FPGA MAC model's DAZ zero).
+
+    Built as f32 bits with integer ops on the int32 codes (bits above the
+    code's four are ignored): the magnitude bits (e, m) at bits 22-24 plus
+    the exponent bias 126 read 2^(e-1) * (1 + m/2) for e >= 1; for
+    exponent field 0 the select takes (m << 22) * 252 instead: 0, or 0.5
+    as the f32 bits 126 << 23, never an f32 subnormal, which the TPU
+    flushes; the sign bit goes to bit 31."""
+    mag = (codes & 7) << 22
+    mag = jnp.where(mag >= 2 << 22, mag + (126 << 23), mag * 252)
+    return jax.lax.bitcast_convert_type(mag | (codes & 8) << 28, jnp.float32)
 
 
 def _decode_fp8_e4m3(codes):
@@ -81,12 +86,24 @@ def decode_codes_arith(scheme: QuantScheme, codes):
 def _code_plane(words, i: int, scheme: QuantScheme):
     """Code ``i`` of every packed word (bits [i*bits, (i+1)*bits)) decoded
     to f32.  Integer codes sign-extend by a left then an arithmetic right
-    shift; float codes go through ``decode_codes_arith``."""
+    shift; E2M1 codes are shifted down and decoded without a mask (the
+    decode reads only their low four bits); FP8 codes go through
+    ``decode_codes_arith``."""
     bits = scheme.weight_bits
     if scheme.weight_format.startswith("int"):
         return ((words << (32 - bits * (i + 1))) >> (32 - bits)) \
             .astype(jnp.float32)
+    if scheme.weight_format == "fp4_e2m1":
+        return _decode_fp4_e2m1(words >> (bits * i))
     return decode_codes_arith(scheme, (words >> (bits * i)) & ((1 << bits) - 1))
+
+
+def _scale_rows(scales, cw: int):
+    """[ns, bn] scale rows -> [cw, bn]: each row repeated for the cw // ns
+    consecutive words it covers."""
+    ns, bn = scales.shape
+    return jnp.broadcast_to(scales[:, None, :], (ns, cw // ns, bn)) \
+        .reshape(cw, bn)
 
 
 def dequant_planes(words, scales, scheme: QuantScheme):
@@ -97,11 +114,9 @@ def dequant_planes(words, scales, scheme: QuantScheme):
     instead (``chunk_activations``).  Each decoded value is scaled in f32
     and rounded once to bf16, as ``quant.schemes.dequantize(dtype=bf16)``
     rounds it."""
-    cw, bn = words.shape
-    ns = scales.shape[0]
-    if ns > 1:   # every scale row covers cw // ns consecutive words
-        scales = jnp.broadcast_to(scales[:, None, :], (ns, cw // ns, bn)) \
-            .reshape(cw, bn)
+    cw = words.shape[0]
+    if scales.shape[0] > 1:
+        scales = _scale_rows(scales, cw)
     return [(_code_plane(words, i, scheme) * scales).astype(jnp.bfloat16)
             for i in range(32 // scheme.weight_bits)]
 
@@ -131,12 +146,16 @@ def chunk_activations(x, cw: int, per: int):
 # ---------------------------------------------------------------------------
 # packed matmul kernel
 # ---------------------------------------------------------------------------
-def _packed_matmul_kernel(x_ref, w_ref, s_ref, o_ref, *, scheme: QuantScheme,
-                          cw: int, ns: int):
+def _packed_matmul_kernel(x_ref, w_ref, s_ref, o_ref, *scratch,
+                          scheme: QuantScheme, cw: int, ns: int):
     """One (M, N, K) block: the K block's chunks of ``cw`` words, each
     dequantized to bf16 code planes and dotted with f32 accumulation.
     ``ns``: scale rows per chunk, 0 when one scale row serves the whole
-    block."""
+    block.  ``scratch``: a VMEM [cw, bn] f32 buffer when a scale row
+    covers fewer words than a vreg has sublanes (mxfp4: 4 of 8); the
+    chunk's rows are broadcast into it once and read by every plane,
+    where a broadcast in registers is redone, through strided stores and
+    shuffled loads, for each plane."""
     @pl.when(pl.program_id(2) == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
@@ -145,6 +164,9 @@ def _packed_matmul_kernel(x_ref, w_ref, s_ref, o_ref, *, scheme: QuantScheme,
         words = w_ref[pl.ds(pl.multiple_of(c * cw, cw), cw), :]
         scales = s_ref[...] if ns == 0 else \
             s_ref[pl.ds(pl.multiple_of(c * ns, ns), ns), :]
+        if scratch:
+            scratch[0][...] = _scale_rows(scales, cw)
+            scales = scratch[0][...]
         return chunk_product(acc, x_ref[c], words, scales, scheme)
 
     o_ref[...] += jax.lax.fori_loop(0, x_ref.shape[0], chunk,
@@ -262,6 +284,8 @@ def _packed_matmul_impl(x, packed, scales, *, scheme_name: str, k: int, n: int,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, l: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((cw, bn), jnp.float32)]
+        if ns and cw // ns < 8 else [],
         interpret=interpret,
         name=name,
     )(chunk_activations(x, cw, per), packed, scales)

@@ -328,3 +328,54 @@ def test_mxfp4_codes_read_and_write_as_ocp_e2m1(code):
     out = packed_gemv(x, qw, interpret=True)
     np.testing.assert_array_equal(np.asarray(out)[:, 0],
                                   np.full(8, 4.0 * want, np.float32))
+
+
+@pytest.mark.parametrize("code", range(16))
+def test_mxfp4_planes_read_the_published_table(code):
+    """Against the OCP table (``FP4_LUT``), not the kernel's arithmetic:
+    ``_decode_fp4_e2m1`` reads the code's f32 bits, and every code plane
+    ``dequant_planes`` builds from words holding it (other codes in the
+    other nibbles), scaled by each power of two 2^-20..2^20, is
+    bf16(FP4_LUT[code] * scale) bit for bit: compared as int16, so -0
+    counts."""
+    from repro.kernels.packed_matmul import _decode_fp4_e2m1, dequant_planes
+    from repro.quant.schemes import FP4_LUT
+    got = np.asarray(_decode_fp4_e2m1(jnp.asarray([code], jnp.int32)))
+    assert got.view(np.int32)[0] == FP4_LUT.view(np.int32)[code]
+    # word w holds ``code`` in nibble w and (code + w + i + 1) % 16 in the
+    # other nibbles i, so each plane reads the code once among neighbours
+    w, i = np.arange(8)[:, None], np.arange(8)[None, :]
+    nib = np.where(w == i, code, (code + w + i + 1) % 16).astype(np.uint32)
+    words = (nib << (4 * i).astype(np.uint32)).sum(1, dtype=np.uint32)
+    scales = np.exp2(np.arange(-20, 21)).astype(np.float32)[None, :]
+    words = jnp.broadcast_to(jnp.asarray(words.view(np.int32))[:, None],
+                             (8, scales.shape[1]))
+    planes = dequant_planes(words, jnp.asarray(scales), get_scheme("mxfp4"))
+    for i, plane in enumerate(planes):
+        want = jnp.asarray(FP4_LUT[nib[:, i]][:, None] * scales) \
+            .astype(jnp.bfloat16)
+        np.testing.assert_array_equal(np.asarray(plane).view(np.int16),
+                                      np.asarray(want).view(np.int16))
+
+
+def test_patched_e2m1_decode_reaches_the_kernel(monkeypatch):
+    """The packed kernel reads E2M1 through ``_decode_fp4_e2m1``: with it
+    patched to read codes 1 and 9 as zero (the FPGA MAC model's DAZ rule,
+    the benchmark's flushed-decode fault), ``packed_gemv`` on a weight
+    holding code 1 returns 0, not 0.5 times the scale."""
+    from repro.kernels import packed_matmul as pm
+    orig = pm._decode_fp4_e2m1
+    monkeypatch.setattr(pm, "_decode_fp4_e2m1", lambda codes: jnp.where(
+        (codes & 7) == 1, 0.0, orig(codes)))
+    # row 0 holds code 1 (0.5 x the group's scale 4); row 1 sets the scale
+    w = np.zeros((256, 128), np.float32)
+    w[0], w[1] = 2.0, 24.0
+    qw = quantize_weights(get_scheme("mxfp4"), w)
+    assert int(np.asarray(qw.packed)[0, 0]) & 15 == 1
+    x = jnp.zeros((8, 256), jnp.bfloat16).at[:, 0].set(1.0)
+    pm._packed_matmul_impl.clear_cache()   # trace the patched decode
+    try:
+        out = np.asarray(packed_gemv(x, qw, interpret=True))
+    finally:
+        pm._packed_matmul_impl.clear_cache()
+    np.testing.assert_array_equal(out[:, 0], np.zeros(8, np.float32))

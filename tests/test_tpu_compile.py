@@ -13,7 +13,10 @@ imports this file.
 """
 from __future__ import annotations
 
+import base64
+import collections
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -30,10 +33,12 @@ from repro.quant.schemes import (QuantizedLinearWeights, effective_group,
 from repro.serve.engine import ServeConfig
 
 GRANITE = get_config("granite-8b")
+STARCODER2 = get_config("starcoder2-15b")
 QWEN_MOE = get_config("qwen3-moe-30b-a3b")
 DECODE_SLOTS = 8
 DECODE_CONTEXT = 2048
-# granite-8b.batch-decode: 16 decode slots and 16-token prefill chunks
+# granite-8b.batch-decode and starcoder2-15b.batch-decode: 16 decode slots
+# and 16-token prefill chunks
 CELL_ROWS = ServeConfig().prefill_chunk
 
 
@@ -127,10 +132,63 @@ def test_packed_awq_int4_compiles(one_chip, m, k, n):
     assert "tpu_custom_call" in _compile_packed(one_chip, "awq_int4", m, k, n)
 
 
-@pytest.mark.parametrize("k,n", GRANITE_WIDTHS,
-                         ids=[f"{k}x{n}" for k, n in GRANITE_WIDTHS])
-@pytest.mark.parametrize("scheme", ["mxfp4", "fp8"])
+# starcoder2-15b projection widths (K, N): wq/wo, wk/wv, w_up, w_down
+STARCODER2_WIDTHS = [
+    (STARCODER2.d_model, STARCODER2.n_heads * STARCODER2.d_head),
+    (STARCODER2.d_model, STARCODER2.n_kv_heads * STARCODER2.d_head),
+    (STARCODER2.d_model, STARCODER2.d_ff),
+    (STARCODER2.d_ff, STARCODER2.d_model),
+]
+
+
+def _mosaic_op_counts(hlo: str) -> collections.Counter:
+    """Op names in the Mosaic kernel bodies of a compiled program (each
+    ``tpu_custom_call`` carries its lowered module as base64 bytecode),
+    counted, without the serializer's dialect prefix."""
+    from jax.extend.mlir import ir
+    counts = collections.Counter()
+
+    def count(op):
+        counts[op.name.removeprefix("stable_mosaic.")] += 1
+        return ir.WalkResult.ADVANCE
+    for body in re.findall(r'"body": ?"([^"]+)"', hlo):
+        with ir.Context() as ctx:
+            ctx.allow_unregistered_dialects = True
+            ir.Module.parse(base64.b64decode(body)).operation.walk(count)
+    return counts
+
+
+SCHEME_WIDTHS = [("mxfp4", k, n)
+                 for k, n in GRANITE_WIDTHS + STARCODER2_WIDTHS] \
+    + [("fp8", k, n) for k, n in GRANITE_WIDTHS]
+
+
+@pytest.mark.parametrize("scheme,k,n", SCHEME_WIDTHS,
+                         ids=[f"{s}-{k}x{n}" for s, k, n in SCHEME_WIDTHS])
 def test_packed_schemes_compile(one_chip, scheme, k, n):
-    """The other packed schemes' plans compile at the cell's M."""
-    assert "tpu_custom_call" in _compile_packed(one_chip, scheme, CELL_ROWS,
-                                                k, n)
+    """The other packed schemes' plans compile at the cells' M (16 decode
+    slots, 16-token prefill chunks), mxfp4 also at every starcoder2-15b
+    width.  The mxfp4 kernel builds E2M1 values from bits: its lowered
+    body holds no ``exp`` / ``exp2``."""
+    hlo = _compile_packed(one_chip, scheme, CELL_ROWS, k, n)
+    assert "tpu_custom_call" in hlo
+    if scheme == "mxfp4":
+        ops = _mosaic_op_counts(hlo)
+        assert ops["tpu.matmul"] > 0
+        assert not {"math.exp", "math.exp2"} & set(ops), ops
+
+
+def test_packed_awq_int4_lowering_is_pinned(one_chip):
+    """The int4 kernel at granite-8b's FFN width and the cell's M lowers to
+    a fixed 172 ops: per code plane a shift pair, one int-to-f32 convert,
+    the scale multiply, the bf16 round and one MXU product.  The float
+    decodes share the kernel's code; a change to them that reaches the
+    int branch shows here."""
+    k, n = GRANITE.d_model, GRANITE.d_ff
+    ops = _mosaic_op_counts(_compile_packed(one_chip, "awq_int4", CELL_ROWS,
+                                            k, n))
+    assert sum(ops.values()) == 172, ops
+    per = {name: ops[name] for name in (
+        "arith.shli", "arith.shrsi", "arith.sitofp", "arith.mulf",
+        "arith.truncf", "tpu.matmul")}
+    assert per == dict.fromkeys(per, 8), per
