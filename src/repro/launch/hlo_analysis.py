@@ -419,26 +419,21 @@ def compiled_step_cost(engine, pool, k: int = 1) -> Dict:
     cache = jax.tree_util.tree_map(
         lambda a: spec(a.shape, a.dtype), pool.cache)
     row_i32 = spec((n,), jnp.int32)
-    paged = getattr(pool, "paged", False)
-    table = (spec(pool.page_table.shape, jnp.int32),) if paged else ()
+    operands = [spec(a.shape, a.dtype) for a in pool.step_operands()]
     if k <= 1:
-        fn = engine._decode_slots_paged_fn if paged \
-            else engine._decode_slots_fn
-        lowered = jax.jit(fn).lower(
+        lowered = jax.jit(engine._decode_slots_fn).lower(
             engine.params, spec((n, 1), jnp.int32), cache, row_i32,
-            spec((n, 2), jnp.uint32), spec((n,), f32), *table)
+            spec((n, 2), jnp.uint32), spec((n,), f32), *operands)
     else:
-        fn = engine._decode_burst_paged_fn if paged \
-            else engine._decode_burst_fn
-        lowered = jax.jit(fn).lower(
+        lowered = jax.jit(engine._decode_burst_fn).lower(
             engine.params, cache, row_i32, row_i32, spec((n,), jnp.bool_),
             row_i32, spec((k, n, 2), jnp.uint32), spec((n,), f32), row_i32,
-            jnp.int32(pool.max_len), *table)
+            jnp.int32(pool.max_len), *operands)
     cost = analyze(lowered.compile().as_text())
     steps = k * n
     return {"k": k, "n_slots": n, "kv_dtype": pool.kv_dtype,
-            "paged": paged,
-            **({"n_pages": pool.n_pages} if paged else {}),
+            "paged": pool.paged,
+            **({"n_pages": pool.n_pages} if pool.paged else {}),
             "flops": cost.flops, "hbm_bytes": cost.hbm_bytes,
             "collective_bytes": cost.collective_bytes,
             "flops_per_token_step": round(cost.flops / steps, 1),

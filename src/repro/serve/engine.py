@@ -49,7 +49,7 @@ along N on the model axis; K only where the split lands on word AND
 scale-group boundaries), the pool cache via
 ``partitioning.serve_pool_pspec`` (slots on 'data', KV heads on 'model').
 The scheduler stays host-side and byte-identical: it sees the same
-alloc/free/lengths interface whether the slab under it lives on one chip
+admit/free/lengths interface whether the pool under it lives on one chip
 or thirty-two.  Buffer donation survives because the cache's in- and
 out-shardings are pinned equal.
 
@@ -256,11 +256,35 @@ class ServingEngine:
         # tree (the dominant memory/memcpy cost of the serving loop).
         # Under a mesh the bare jits below are replaced per pool geometry by
         # ``_steps_for`` with explicit in/out shardings.
-        def prefill_chunk(params, tokens, cache, slot, offset, with_logits):
+        #
+        # Every step ends in the pool's trailing operands
+        # (``pool.step_operands``): none for a slab, whose slot axis IS the
+        # layout; the page table for a ``PagedKVPool`` (DESIGN.md §15), whose
+        # cache is a page arena [L, n_pages, page_size, ...].  The table goes
+        # to ``T.forward(page_table=)``: each attention layer gathers its
+        # slots' virtual slabs from the arena, runs the UNCHANGED slab math
+        # and scatters the updated rows back — identical bytes in the
+        # identical [slot, pos] layout at every attended position.  Every
+        # page a dispatch writes is pinned through the pool (``ensure`` /
+        # ``ensure_decode``) before it, so the table is invariant inside a
+        # dispatch and a scan body closes over it.
+        def prefill_chunk(params, tokens, cache, slot, offset, with_logits,
+                          table=None):
             """tokens [1, C] into pool slot ``slot`` at position ``offset``;
             returns ([C, V] logits, updated pool cache).  ``with_logits=False``
             (non-final chunks, whose logits the caller discards) returns None
-            logits — XLA dead-code-eliminates the whole lm-head matmul."""
+            logits — XLA dead-code-eliminates the whole lm-head matmul.
+
+            A slab slot is sliced out, written and put back; a paged slot
+            is the table row ``table`` [1, pages_per_slot] (its pages are
+            scattered over the arena, so the whole arena rides through and
+            only this slot's virtual slab is gathered and scattered)."""
+            if table is not None:
+                logits, _, cache = T.forward(
+                    mcfg, params, {"tokens": tokens}, cache=cache,
+                    cache_index=offset, mode="prefill_chunk",
+                    page_table=table)
+                return (logits[0] if with_logits else None), cache
             slot_cache = jax.tree_util.tree_map(
                 lambda a: jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=1),
                 cache)
@@ -274,27 +298,28 @@ class ServingEngine:
                     cache, slot_cache)
             return (logits[0] if with_logits else None), cache
 
-        def decode_slots_logits(params, tokens, cache, lengths):
+        def decode_slots_logits(params, tokens, cache, lengths, table=None):
             """tokens [n_slots, 1]; row i writes/attends at lengths[i].
             Returns the full [n_slots, V] logits — the diagnostic / scoring
             variant; the serving hot path uses the fused ``decode_slots``."""
             logits, _, cache = T.forward(mcfg, params, {"tokens": tokens},
                                          cache=cache, cache_index=lengths,
-                                         mode="decode")
+                                         mode="decode", page_table=table)
             return logits[:, -1], cache
 
-        def decode_slots(params, tokens, cache, lengths, keys, temps):
+        def decode_slots(params, tokens, cache, lengths, keys, temps,
+                         table=None):
             """Fused decode + sample: one step for all slots, sampling on
             device (keys [n_slots, 2], temps [n_slots]).  Only the
             [n_slots] int32 sampled ids cross to the host — the logits are
             dead past ``sample_rows`` and never materialize off-device."""
             logits, _, cache = T.forward(mcfg, params, {"tokens": tokens},
                                          cache=cache, cache_index=lengths,
-                                         mode="decode")
+                                         mode="decode", page_table=table)
             return sample_rows(logits[:, -1], keys, temps), cache
 
         def decode_burst(params, cache, tokens, lengths, active, rem, keys,
-                         temps, eos_ids, max_len):
+                         temps, eos_ids, max_len, table=None):
             """K consecutive fused decode steps as one ``lax.scan``
             (DESIGN.md §11).  K is the leading dim of ``keys``
             [K, n_slots, 2] — the per-(request, step) key schedule the host
@@ -318,7 +343,7 @@ class ServingEngine:
                 cache, tokens, lengths, active, rem = carry
                 logits, _, cache = T.forward(
                     mcfg, params, {"tokens": tokens[:, None]}, cache=cache,
-                    cache_index=lengths, mode="decode")
+                    cache_index=lengths, mode="decode", page_table=table)
                 sampled = sample_rows(logits[:, -1], step_keys, temps)
                 act = active.astype(jnp.int32)
                 lengths = lengths + act
@@ -333,7 +358,7 @@ class ServingEngine:
             return cache, toks, valid
 
         def verify_slots(params, tokens, cache, lengths, key_schedule,
-                         temps):
+                         temps, table=None):
             """Speculative verify (DESIGN.md §17): score ALL K+1 window
             positions of every row in ONE dispatch.  ``tokens``
             [n_slots, S] is each row's [last_committed, d_1..d_K] window;
@@ -364,87 +389,6 @@ class ServingEngine:
                 tok, keys = xs
                 logits, _, cache = T.forward(
                     mcfg, params, {"tokens": tok[:, None]}, cache=cache,
-                    cache_index=idx, mode="decode")
-                return ((cache, idx + 1),
-                        sample_rows(logits[:, -1], keys, temps))
-            (cache, _), sampled = jax.lax.scan(
-                step, (cache, lengths), (tokens.T, key_schedule))
-            return sampled, cache
-
-        # ---- paged-pool steps (DESIGN.md §15) --------------------------
-        # Same step semantics over a PagedKVPool: ``cache`` is the page
-        # arena [L, n_pages, page_size, ...] and each step additionally
-        # takes the page table mapping slots to arena pages.  Inside the
-        # step, every attention layer gathers its slots' virtual slabs
-        # from the arena, runs the UNCHANGED slab attention math (einsum
-        # oracle or Pallas decode kernel), and scatters the updated slab
-        # back through the table — which is the paged pool's bit-identity
-        # contract: identical bytes in the identical [slot, pos] layout at
-        # every attended position.  The arena is donated exactly like the
-        # slab; the table is a tiny int32 array rebuilt from host state
-        # per dispatch (page mappings change between steps, not within).
-        def prefill_chunk_paged(params, tokens, cache, table_row, offset,
-                                with_logits):
-            """tokens [1, C] through the single slot whose page-table row
-            is ``table_row`` [1, pages_per_slot].  The whole arena rides
-            through (pages of one slot are scattered across it — there is
-            no contiguous sub-slab to slice out), but only this slot's
-            virtual slab is gathered/computed/scattered inside."""
-            logits, _, cache = T.forward(
-                mcfg, params, {"tokens": tokens}, cache=cache,
-                cache_index=offset, mode="prefill_chunk",
-                page_table=table_row)
-            return (logits[0] if with_logits else None), cache
-
-        def decode_slots_logits_paged(params, tokens, cache, lengths, table):
-            logits, _, cache = T.forward(mcfg, params, {"tokens": tokens},
-                                         cache=cache, cache_index=lengths,
-                                         mode="decode", page_table=table)
-            return logits[:, -1], cache
-
-        def decode_slots_paged(params, tokens, cache, lengths, keys, temps,
-                               table):
-            logits, _, cache = T.forward(mcfg, params, {"tokens": tokens},
-                                         cache=cache, cache_index=lengths,
-                                         mode="decode", page_table=table)
-            return sample_rows(logits[:, -1], keys, temps), cache
-
-        def decode_burst_paged(params, cache, tokens, lengths, active, rem,
-                               keys, temps, eos_ids, max_len, table):
-            """Paged twin of ``decode_burst``: the page table is loop-
-            invariant across the K scanned steps (the scheduler pins every
-            written page via ``ensure_decode`` BEFORE dispatch), so the
-            scan body closes over it and the carry stays identical to the
-            slab burst's."""
-            def step(carry, step_keys):
-                cache, tokens, lengths, active, rem = carry
-                logits, _, cache = T.forward(
-                    mcfg, params, {"tokens": tokens[:, None]}, cache=cache,
-                    cache_index=lengths, mode="decode", page_table=table)
-                sampled = sample_rows(logits[:, -1], step_keys, temps)
-                act = active.astype(jnp.int32)
-                lengths = lengths + act
-                rem = rem - act
-                stop_eos = (eos_ids >= 0) & (sampled == eos_ids)
-                still = active & ~stop_eos & (rem > 0) \
-                    & (lengths < max_len - 1)
-                tokens = jnp.where(active, sampled, tokens)
-                return (cache, tokens, lengths, still, rem), (sampled, active)
-            (cache, _, _, _, _), (toks, valid) = jax.lax.scan(
-                step, (cache, tokens, lengths, active, rem), keys)
-            return cache, toks, valid
-
-        def verify_slots_paged(params, tokens, cache, lengths, key_schedule,
-                               temps, table):
-            """Paged twin of ``verify_slots``: the caller pins the whole
-            S-wide write window (``ensure_decode(slots, S, rems)``) before
-            dispatch, so the table is invariant across the window's
-            in-dispatch scan steps."""
-            def step(carry, xs):
-                cache, idx = carry
-                tok, keys = xs
-                logits, _, cache = T.forward(
-                    mcfg, params, {"tokens": tok[:, None]}, cache=cache,
                     cache_index=idx, mode="decode", page_table=table)
                 return ((cache, idx + 1),
                         sample_rows(logits[:, -1], keys, temps))
@@ -458,36 +402,18 @@ class ServingEngine:
         self._decode_slots_fn = decode_slots
         self._decode_slots_logits_fn = decode_slots_logits
         self._decode_burst_fn = decode_burst
+        self._verify_slots_fn = verify_slots
         # single-device jits (mesh=None path; also the tracing baseline).
-        # The burst jit re-lowers per distinct K (the scan length is part
-        # of the traced shape); the scheduler's power-of-two K policy
-        # bounds that to log2(max_burst) variants.
+        # The burst and verify jits re-lower per distinct K (the scan
+        # length is part of the traced shape); the scheduler's power-of-two
+        # K policy bounds that to log2(max_burst) variants each.
         self._prefill_chunk = jax.jit(prefill_chunk, donate_argnums=(2,),
                                       static_argnums=(5,))
         self._decode_slots = jax.jit(decode_slots, donate_argnums=(2,))
         self._decode_slots_logits = jax.jit(decode_slots_logits,
                                             donate_argnums=(2,))
         self._decode_burst = jax.jit(decode_burst, donate_argnums=(1,))
-        self._verify_slots_fn = verify_slots
-        # the verify jit re-lowers per distinct window width S = K+1 (the
-        # planner's power-of-two K ladder bounds that to log2(max_burst)
-        # variants, same argument as the burst jit)
         self._verify_slots = jax.jit(verify_slots, donate_argnums=(2,))
-        self._prefill_chunk_paged_fn = prefill_chunk_paged
-        self._decode_slots_paged_fn = decode_slots_paged
-        self._decode_slots_logits_paged_fn = decode_slots_logits_paged
-        self._decode_burst_paged_fn = decode_burst_paged
-        self._prefill_chunk_paged = jax.jit(
-            prefill_chunk_paged, donate_argnums=(2,), static_argnums=(5,))
-        self._decode_slots_paged = jax.jit(decode_slots_paged,
-                                           donate_argnums=(2,))
-        self._decode_slots_logits_paged = jax.jit(decode_slots_logits_paged,
-                                                  donate_argnums=(2,))
-        self._decode_burst_paged = jax.jit(decode_burst_paged,
-                                           donate_argnums=(1,))
-        self._verify_slots_paged_fn = verify_slots_paged
-        self._verify_slots_paged = jax.jit(verify_slots_paged,
-                                           donate_argnums=(2,))
 
     # ------------------------------------------------------------------
     # Mesh-aware step construction (DESIGN.md §10)
@@ -520,151 +446,77 @@ class ServingEngine:
     def pool_shardings(self, pool: KVCachePool):
         """NamedSharding tree for ``pool``'s cache under this engine's
         mesh (None when meshless).  Derived from the pool's KV tier — the
-        per-pool component of the precision policy."""
+        per-pool component of the precision policy — and its row count:
+        a page arena's pages ride where a slab's slots do, so dp x tp
+        sharding and donation survive the paging indirection unchanged."""
         if self.mesh is None:
             return None
         from repro.runtime import partitioning as PT
-        # Paged pools shard the page arena: the page axis takes the slab's
-        # slot (data) axis — pages ride where slots used to, so dp x tp
-        # sharding and donation survive the paging indirection unchanged.
-        rows = pool.n_pages if getattr(pool, "paged", False) else pool.n_slots
-        spec = PT.serve_pool_pspec(self.cfg, self.mesh, rows,
+        spec = PT.serve_pool_pspec(self.cfg, self.mesh, pool.n_rows,
                                    kv_dtype=pool.kv_dtype)
         return PT.named(self.mesh, spec)
 
     def _steps_for(self, pool: KVCachePool):
-        """(prefill_chunk, decode_slots, decode_slots_logits, decode_burst)
-        jits for ``pool``'s geometry.
+        """(prefill_chunk, decode_slots, decode_slots_logits, decode_burst,
+        verify_slots) jits for ``pool``'s geometry.
 
         Meshless: the bare jits.  Under a mesh: jits carrying explicit
         in/out shardings — cache in-sharding == out-sharding keeps donation
         alive; tokens / lengths / stop masks / sampled ids ride the slot
-        (data) axis; the [K, n_slots, 2] burst key schedule and the
-        [K, n_slots] burst outputs carry the slot axis at position 1
+        (data) axis; the [K, n_slots, 2] burst and verify key schedules
+        and their [K, n_slots] outputs carry the slot axis at position 1
         (``partitioning.serve_burst_pspec``); scalars and the [1, C] chunk
-        tokens are replicated.  Cached per ``(n_slots, capacity, tier)`` —
-        the pool-varying components of the precision policy — so ONE
-        engine holds compiled step sets for several KV tiers at once and
-        per-request tier switching never recompiles a tier it has already
-        served (DESIGN.md §12).  (Meshless, the bare jits below do the
-        same thing through jax.jit's own signature cache: a bf16 slab and
-        a packed int8 slab are different pytree structures, hence
-        different compiled specializations of one wrapper.)
+        tokens are replicated.  The pool's trailing step operands (the page
+        table) ride the slot axis like the per-row keys; prefill's
+        single-slot row is replicated like its chunk.  Cached per pool
+        geometry and tier — the pool-varying components of the precision
+        policy — so ONE engine holds compiled step sets for several KV
+        tiers at once and per-request tier switching never recompiles a
+        tier it has already served (DESIGN.md §12).  (Meshless, the bare
+        jits do the same thing through jax.jit's own signature cache: a
+        bf16 slab and a packed int8 slab are different pytree structures,
+        hence different compiled specializations of one wrapper.)  Jits
+        compile on first call, so a plain serving path never pays for
+        the verify step.
         """
         self._declare_execution()
-        paged = getattr(pool, "paged", False)
         if self.mesh is None:
-            if paged:
-                return (self._prefill_chunk_paged, self._decode_slots_paged,
-                        self._decode_slots_logits_paged,
-                        self._decode_burst_paged)
             return (self._prefill_chunk, self._decode_slots,
-                    self._decode_slots_logits, self._decode_burst)
-        key = (pool.n_slots, pool.capacity, pool.kv_dtype, paged,
-               getattr(pool, "n_pages", 0), getattr(pool, "page_size", 0))
-        steps = self._sharded_steps.get(key)
+                    self._decode_slots_logits, self._decode_burst,
+                    self._verify_slots)
+        steps = self._sharded_steps.get(pool.geometry)
         if steps is None:
             from repro.runtime import partitioning as PT
-            cache_sh = self.pool_shardings(pool)
-            rep = NamedSharding(self.mesh, P())
-            burst = PT.serve_burst_pspec(self.mesh, pool.n_slots)
-            tok_sh = NamedSharding(self.mesh, P(burst["row"][0], None))
-            len_sh = NamedSharding(self.mesh, burst["row"])
-            keys_sh = NamedSharding(self.mesh, burst["row_keys"])
-            sched_sh = NamedSharding(self.mesh, burst["key_schedule"])
-            out_sh = NamedSharding(self.mesh, burst["burst_out"])
-            if paged:
-                # the page table rides the slot (data) axis like lengths;
-                # the single-row prefill table is replicated like its chunk
-                table_sh = NamedSharding(self.mesh, burst["row_keys"])
-                pc = jax.jit(
-                    self._prefill_chunk_paged_fn, donate_argnums=(2,),
-                    static_argnums=(5,),
-                    in_shardings=(self._param_shardings, rep, cache_sh,
-                                  rep, rep),
-                    out_shardings=(None, cache_sh))
-                ds = jax.jit(
-                    self._decode_slots_paged_fn, donate_argnums=(2,),
-                    in_shardings=(self._param_shardings, tok_sh, cache_sh,
-                                  len_sh, keys_sh, len_sh, table_sh),
-                    out_shardings=(len_sh, cache_sh))
-                dl = jax.jit(
-                    self._decode_slots_logits_paged_fn, donate_argnums=(2,),
-                    in_shardings=(self._param_shardings, tok_sh, cache_sh,
-                                  len_sh, table_sh),
-                    out_shardings=(None, cache_sh))
-                db = jax.jit(
-                    self._decode_burst_paged_fn, donate_argnums=(1,),
-                    in_shardings=(self._param_shardings, cache_sh, len_sh,
-                                  len_sh, len_sh, len_sh, sched_sh, len_sh,
-                                  len_sh, rep, table_sh),
-                    out_shardings=(cache_sh, out_sh, out_sh))
-            else:
-                pc = jax.jit(
-                    self._prefill_chunk_fn, donate_argnums=(2,),
-                    static_argnums=(5,),
-                    in_shardings=(self._param_shardings, rep, cache_sh, rep,
-                                  rep),
-                    out_shardings=(None, cache_sh))
-                ds = jax.jit(
-                    self._decode_slots_fn, donate_argnums=(2,),
-                    in_shardings=(self._param_shardings, tok_sh, cache_sh,
-                                  len_sh, keys_sh, len_sh),
-                    out_shardings=(len_sh, cache_sh))
-                dl = jax.jit(
-                    self._decode_slots_logits_fn, donate_argnums=(2,),
-                    in_shardings=(self._param_shardings, tok_sh, cache_sh,
-                                  len_sh),
-                    out_shardings=(None, cache_sh))
-                db = jax.jit(
-                    self._decode_burst_fn, donate_argnums=(1,),
-                    in_shardings=(self._param_shardings, cache_sh, len_sh,
-                                  len_sh, len_sh, len_sh, sched_sh, len_sh,
-                                  len_sh, rep),
-                    out_shardings=(cache_sh, out_sh, out_sh))
-            steps = self._sharded_steps[key] = (pc, ds, dl, db)
-        return steps
+            mesh = self.mesh
+            cache = self.pool_shardings(pool)
+            spec = PT.serve_burst_pspec(mesh, pool.n_slots)
+            rep = NamedSharding(mesh, P())
+            tok = NamedSharding(mesh, P(spec["row"][0], None))
+            row = NamedSharding(mesh, spec["row"])
+            keys = NamedSharding(mesh, spec["row_keys"])
+            sched = NamedSharding(mesh, spec["key_schedule"])
+            out = NamedSharding(mesh, spec["burst_out"])
+            n_ops = len(pool.step_operands())
 
-    def _verify_for(self, pool: KVCachePool):
-        """The speculative-verify jit for ``pool``'s geometry (DESIGN.md
-        §17) — kept out of ``_steps_for``'s 4-tuple so the plain serving
-        paths never pay for it.  Under a mesh the [n_slots, S] window
-        tokens ride the slot (data) axis like decode tokens, the
-        [S, n_slots, 2] key schedule and [S, n_slots] sampled output reuse
-        the burst's schedule/output shardings (slot axis at position 1),
-        and the cache in==out sharding keeps donation alive."""
-        self._declare_execution()
-        paged = getattr(pool, "paged", False)
-        if self.mesh is None:
-            return self._verify_slots_paged if paged else self._verify_slots
-        key = (pool.n_slots, pool.capacity, pool.kv_dtype, paged,
-               getattr(pool, "n_pages", 0), getattr(pool, "page_size", 0),
-               "verify")
-        vs = self._sharded_steps.get(key)
-        if vs is None:
-            from repro.runtime import partitioning as PT
-            cache_sh = self.pool_shardings(pool)
-            rep = NamedSharding(self.mesh, P())
-            burst = PT.serve_burst_pspec(self.mesh, pool.n_slots)
-            tok_sh = NamedSharding(self.mesh, P(burst["row"][0], None))
-            len_sh = NamedSharding(self.mesh, burst["row"])
-            sched_sh = NamedSharding(self.mesh, burst["key_schedule"])
-            out_sh = NamedSharding(self.mesh, burst["burst_out"])
-            if paged:
-                table_sh = NamedSharding(self.mesh, burst["row_keys"])
-                vs = jax.jit(
-                    self._verify_slots_paged_fn, donate_argnums=(2,),
-                    in_shardings=(self._param_shardings, tok_sh, cache_sh,
-                                  len_sh, sched_sh, len_sh, table_sh),
-                    out_shardings=(out_sh, cache_sh))
-            else:
-                vs = jax.jit(
-                    self._verify_slots_fn, donate_argnums=(2,),
-                    in_shardings=(self._param_shardings, tok_sh, cache_sh,
-                                  len_sh, sched_sh, len_sh),
-                    out_shardings=(out_sh, cache_sh))
-            self._sharded_steps[key] = vs
-        return vs
+            def jit(fn, donate, ins, outs, operand, **kw):
+                return jax.jit(
+                    fn, donate_argnums=(donate,),
+                    in_shardings=(self._param_shardings, *ins)
+                    + (operand,) * n_ops,
+                    out_shardings=outs, **kw)
+            steps = self._sharded_steps[pool.geometry] = (
+                jit(self._prefill_chunk_fn, 2, (rep, cache, rep, rep),
+                    (None, cache), rep, static_argnums=(5,)),
+                jit(self._decode_slots_fn, 2, (tok, cache, row, keys, row),
+                    (row, cache), keys),
+                jit(self._decode_slots_logits_fn, 2, (tok, cache, row),
+                    (None, cache), keys),
+                jit(self._decode_burst_fn, 1,
+                    (cache, row, row, row, row, sched, row, row, rep),
+                    (cache, out, out), keys),
+                jit(self._verify_slots_fn, 2,
+                    (tok, cache, row, sched, row), (out, cache), keys))
+        return steps
 
     # ------------------------------------------------------------------
     # Pool-based step primitives (the scheduler's interface)
@@ -697,20 +549,16 @@ class ServingEngine:
             pool = PagedKVPool(self.cfg, n_slots, max_len, kv_dtype=tier,
                                align=self.scfg.prefill_chunk,
                                page_size=page_size, n_pages=n_pages)
-            if self.mesh is not None:
-                pool.place(self.pool_shardings(pool))
-            return pool
-        if n_slots is None:
-            if self.scfg.cache_budget_bytes is not None:
-                n_slots = slots_for_budget(
-                    self.cfg, max_len, self.scfg.cache_budget_bytes,
-                    kv_dtype=tier,
-                    align=self.scfg.prefill_chunk)
-            else:
-                n_slots = self.scfg.n_slots
-        pool = KVCachePool(self.cfg, n_slots, max_len,
-                           kv_dtype=tier,
-                           align=self.scfg.prefill_chunk)
+        else:
+            if n_slots is None:
+                if self.scfg.cache_budget_bytes is not None:
+                    n_slots = slots_for_budget(
+                        self.cfg, max_len, self.scfg.cache_budget_bytes,
+                        kv_dtype=tier, align=self.scfg.prefill_chunk)
+                else:
+                    n_slots = self.scfg.n_slots
+            pool = KVCachePool(self.cfg, n_slots, max_len, kv_dtype=tier,
+                               align=self.scfg.prefill_chunk)
         if self.mesh is not None:
             pool.place(self.pool_shardings(pool))
         return pool
@@ -774,18 +622,14 @@ class ServingEngine:
         final = (offset + n >= prompt_len) and need_logits
         self._inject_fault("prefill")
         prefill_chunk = self._steps_for(pool)[0]
-        if getattr(pool, "paged", False):
-            # pin the chunk's write window (fresh pages / COW of a shared
-            # page on a full-cover prefix hit) before the jitted write
-            pool.ensure(slot, offset + C)
-            logits, pool.cache = prefill_chunk(
-                self.params, jnp.asarray(chunk), pool.cache,
-                jnp.asarray(pool.page_table[slot:slot + 1]),
-                jnp.int32(offset), final)
-        else:
-            logits, pool.cache = prefill_chunk(
-                self.params, jnp.asarray(chunk), pool.cache,
-                jnp.int32(slot), jnp.int32(offset), final)
+        # pin the chunk's write window before the jitted write (paged
+        # pools: fresh pages, or COW of a shared page on a full-cover
+        # prefix hit)
+        pool.ensure(slot, offset + C)
+        logits, pool.cache = prefill_chunk(
+            self.params, jnp.asarray(chunk), pool.cache, jnp.int32(slot),
+            jnp.int32(offset), final,
+            *map(jnp.asarray, pool.step_operands(slot)))
         pool.lengths[slot] = offset + n
         if not final:
             return None
@@ -815,8 +659,9 @@ class ServingEngine:
         ON DEVICE (``keys`` [n_slots, 2] uint32 / ``temperatures``
         [n_slots]; both default to zeros = greedy) and only the [n_slots]
         int32 sampled ids come back — the logits never leave the device.
-        The caller commits the write by incrementing ``pool.lengths`` for
-        the rows it considers active."""
+        The caller pins the rows' write positions first
+        (``pool.ensure_decode``) and commits the write by incrementing
+        ``pool.lengths`` for the rows it considers active."""
         n = pool.n_slots
         tokens = np.asarray(tokens, np.int32).reshape(n, 1)
         if keys is None:
@@ -825,16 +670,11 @@ class ServingEngine:
             temperatures = np.zeros((n,), np.float32)
         poison = self._inject_fault("decode")
         decode_slots = self._steps_for(pool)[1]
-        step_args = (self.params, jnp.asarray(tokens), pool.cache,
-                     jnp.asarray(pool.lengths), jnp.asarray(keys, jnp.uint32),
-                     jnp.asarray(temperatures, jnp.float32))
-        if getattr(pool, "paged", False):
-            # paged pools: the caller (scheduler) must have pinned every
-            # active row's write position via ``pool.ensure_decode`` —
-            # inactive rows' garbage writes flow to the reserved garbage
-            # page through their unmapped (entry-0) table slots.
-            step_args += (jnp.asarray(pool.page_table),)
-        toks, pool.cache = decode_slots(*step_args)
+        toks, pool.cache = decode_slots(
+            self.params, jnp.asarray(tokens), pool.cache,
+            jnp.asarray(pool.lengths), jnp.asarray(keys, jnp.uint32),
+            jnp.asarray(temperatures, jnp.float32),
+            *map(jnp.asarray, pool.step_operands()))
         with span("serve.sync", what="tokens"):
             toks = np.asarray(toks)
         if poison is not None:
@@ -851,11 +691,10 @@ class ServingEngine:
         [n_slots, V] logits — one host transfer of the whole logit block."""
         tokens = np.asarray(tokens, np.int32).reshape(pool.n_slots, 1)
         decode_logits = self._steps_for(pool)[2]
-        step_args = (self.params, jnp.asarray(tokens), pool.cache,
-                     jnp.asarray(pool.lengths))
-        if getattr(pool, "paged", False):
-            step_args += (jnp.asarray(pool.page_table),)
-        logits, pool.cache = decode_logits(*step_args)
+        logits, pool.cache = decode_logits(
+            self.params, jnp.asarray(tokens), pool.cache,
+            jnp.asarray(pool.lengths),
+            *map(jnp.asarray, pool.step_operands()))
         with span("serve.sync", what="logits"):
             return jax.block_until_ready(logits)
 
@@ -871,6 +710,8 @@ class ServingEngine:
         ``remaining`` [n_slots] int32 is each row's max-new-tokens budget
         left; ``eos_ids`` [n_slots] int32 (-1 = never).  Rows that hit a
         stop condition freeze mid-burst (their lengths stop advancing).
+        The caller pins each row's K-step write window first
+        (``pool.ensure_decode(slots, K, rems)``).
 
         Returns (tokens [K, n_slots] int32, valid [K, n_slots] bool) as
         host arrays; token (t, i) was emitted iff valid[t, i].  Commits
@@ -881,18 +722,14 @@ class ServingEngine:
         tokens = np.asarray(tokens, np.int32).reshape(n)
         poison = self._inject_fault("burst")
         decode_burst = self._steps_for(pool)[3]
-        step_args = (
+        pool.cache, toks, valid = decode_burst(
             self.params, pool.cache, jnp.asarray(tokens),
             jnp.asarray(pool.lengths), jnp.asarray(active, bool),
             jnp.asarray(remaining, jnp.int32),
             jnp.asarray(key_schedule, jnp.uint32),
             jnp.asarray(temperatures, jnp.float32),
-            jnp.asarray(eos_ids, jnp.int32), jnp.int32(pool.max_len))
-        if getattr(pool, "paged", False):
-            # write windows for the whole K-step burst must be pinned
-            # (``pool.ensure_decode(slots, K, rems)``) before this dispatch
-            step_args += (jnp.asarray(pool.page_table),)
-        pool.cache, toks, valid = decode_burst(*step_args)
+            jnp.asarray(eos_ids, jnp.int32), jnp.int32(pool.max_len),
+            *map(jnp.asarray, pool.step_operands()))
         with span("serve.sync", what="tokens"):       # the burst's one sync
             toks = np.asarray(toks)
             valid = np.asarray(valid)
@@ -906,9 +743,10 @@ class ServingEngine:
                      temperatures: np.ndarray) -> np.ndarray:
         """Speculative verify over every pool slot (DESIGN.md §17).
         ``tokens`` [n_slots, S]: row i's window [last_committed, d_1..d_K]
-        written at pool.lengths[i]..+S-1; ``key_schedule`` [S, n_slots, 2]
-        carries each row's real step keys for tokens n_generated..+K.
-        Returns the target's sampled ids [S, n_slots] int32 — g_j at
+        written at pool.lengths[i]..+S-1 (the caller pins that window,
+        ``pool.ensure_decode(slots, S, rems)``); ``key_schedule``
+        [S, n_slots, 2] carries each row's real step keys for tokens
+        n_generated..+K.  Returns the target's sampled ids [S, n_slots] int32 — g_j at
         position j.  Does NOT commit ``pool.lengths``: the caller accepts
         the longest agreeing prefix and sets lengths to the emitted count
         (which IS the rollback — positions past the committed length are
@@ -918,16 +756,13 @@ class ServingEngine:
         s = tokens.shape[1]
         assert key_schedule.shape == (s, n, 2), key_schedule.shape
         poison = self._inject_fault("verify")
-        vs = self._verify_for(pool)
-        step_args = (self.params, jnp.asarray(tokens), pool.cache,
-                     jnp.asarray(pool.lengths),
-                     jnp.asarray(key_schedule, jnp.uint32),
-                     jnp.asarray(temperatures, jnp.float32))
-        if getattr(pool, "paged", False):
-            # the S-wide write window must be pinned by the caller
-            # (``pool.ensure_decode(slots, S, rems)``) before dispatch
-            step_args += (jnp.asarray(pool.page_table),)
-        sampled, pool.cache = vs(*step_args)
+        verify_slots = self._steps_for(pool)[4]
+        sampled, pool.cache = verify_slots(
+            self.params, jnp.asarray(tokens), pool.cache,
+            jnp.asarray(pool.lengths),
+            jnp.asarray(key_schedule, jnp.uint32),
+            jnp.asarray(temperatures, jnp.float32),
+            *map(jnp.asarray, pool.step_operands()))
         with span("serve.sync", what="tokens"):   # the round's verify sync
             sampled = np.asarray(sampled)
         if poison is not None:

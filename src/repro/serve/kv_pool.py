@@ -12,8 +12,12 @@ and slot reuse are pure host-side bookkeeping plus in-place
 This is paging at slot granularity: the unit of allocation is a whole
 ``max_len`` row rather than a fixed-size token block.  That forgoes
 vLLM-style fine-grained page sharing but needs no gather indirection inside
-the kernels — the right trade at the current scale, and the pool interface
-(alloc/free/lengths) is what a block-paged backend would slot in behind.
+the kernels.  ``PagedKVPool`` below is the block-paged backend behind the
+same interface: ``admit`` / ``ensure`` / ``ensure_decode`` /
+``register_prefix`` / ``free`` / ``lengths`` for the scheduler, and
+``step_operands`` — the trailing arguments of the engine's step programs
+(none for a slab, the page table for pages) — for the engine.  Which pool
+a deployment runs is ``ServeConfig.paged``; nothing above the pool asks.
 
 Slot hygiene: freed slots are NOT zeroed.  Every read is masked by the
 explicit per-row valid length the scheduler passes to the model
@@ -95,9 +99,12 @@ class KVCachePool:
         assert n_slots >= 1 and max_len >= 1 and align >= 1
         self.cfg = cfg
         self.n_slots = n_slots
+        self.n_rows = n_slots            # rows of the cache's slot axis
         self.max_len = max_len                            # logical capacity
         self.capacity = -(-max_len // align) * align      # allocated positions
         self.kv_dtype = kv_dtype_name(kv_dtype)
+        # what the engine's compiled steps are keyed on: shapes and tier
+        self.geometry = (n_slots, self.capacity, self.kv_dtype)
         self.cache = T.init_cache(cfg, n_slots, self.capacity,
                                   kv_dtype=kv_dtype)
         self.shardings = None           # set by place() under a device mesh
@@ -160,6 +167,31 @@ class KVCachePool:
     def room(self, slot: int) -> int:
         """Cache positions still writable in ``slot``."""
         return self.max_len - int(self.lengths[slot])
+
+    # -- the write path (no-ops: every slab position is already private) --
+    def admit(self, tokens: Sequence[int], max_new: int
+              ) -> Optional[Tuple[int, int, int]]:
+        """``(slot, prefill_pos, hit_tokens)`` = (lowest free slot, 0, 0),
+        or None when the pool is full.  A slot already holds its worst
+        case and shares nothing, so the request itself does not matter."""
+        slot = self.alloc()
+        return None if slot is None else (slot, 0, 0)
+
+    def ensure(self, slot: int, upto: int) -> None:
+        """Nothing to pin: the slot owns every position it may write."""
+
+    def ensure_decode(self, slots: Sequence[int], k: int = 1,
+                      rems: Optional[Sequence[int]] = None) -> None:
+        """Nothing to pin: the slot owns every position it may write."""
+
+    def register_prefix(self, slot: int, tokens: Sequence[int]) -> int:
+        """A slab shares no prefix; returns the 0 pages registered."""
+        return 0
+
+    def step_operands(self, slot: Optional[int] = None) -> tuple:
+        """Trailing host arguments of the engine's step programs: none —
+        the slab's slot axis is its layout."""
+        return ()
 
 
 # ===========================================================================
@@ -498,11 +530,11 @@ class PageAllocator:
 
 
 class PagedKVPool:
-    """Paged drop-in for ``KVCachePool``: same scheduler-facing surface
-    (lengths / free / room / occupancy / place), plus page-aware admission
-    (``admit`` instead of bare ``alloc``), write-window pinning
-    (``ensure`` / ``ensure_decode``) and prefix publication
-    (``register_prefix``).  Device state is the per-layer page arena
+    """Paged drop-in for ``KVCachePool``: the same scheduler- and
+    engine-facing surface, where page-aware admission (``admit``),
+    write-window pinning (``ensure`` / ``ensure_decode``), prefix
+    publication (``register_prefix``) and the page-table step operand do
+    real work.  Device state is the per-layer page arena
     ``[L, n_pages, page_size, ...]`` and the host-side ``page_table`` that
     the engine ships to its jitted steps; all paging policy lives in the
     ``PageAllocator``."""
@@ -527,8 +559,10 @@ class PagedKVPool:
         self.pages_per_slot = self.capacity // page_size
         if n_pages is None:       # full provisioning: slab parity + garbage
             n_pages = 1 + n_slots * self.pages_per_slot
-        self.n_pages = n_pages
+        self.n_pages = self.n_rows = n_pages
         self.kv_dtype = kv_dtype_name(kv_dtype)
+        self.geometry = (n_slots, self.capacity, self.kv_dtype, n_pages,
+                         page_size)
         self.allocator = PageAllocator(n_pages, page_size, n_slots,
                                        self.pages_per_slot, align=align)
         self.cache = T.init_cache(cfg, n_pages, page_size, kv_dtype=kv_dtype)
@@ -550,6 +584,13 @@ class PagedKVPool:
     @property
     def page_table(self) -> np.ndarray:
         return self.allocator.table
+
+    def step_operands(self, slot: Optional[int] = None) -> tuple:
+        """Trailing host arguments of the engine's step programs: the page
+        table [n_slots, pages_per_slot], or ``slot``'s row
+        [1, pages_per_slot] for a prefill chunk."""
+        table = self.page_table
+        return (table if slot is None else table[slot:slot + 1],)
 
     # -- memory accounting -------------------------------------------------
     @property

@@ -537,27 +537,21 @@ class Scheduler:
         the request is PREFILL-state and registered in ``running``."""
         pool = self.pools[req.tier]
         max_new = req.sampling.max_new_tokens - max(req.n_generated - 1, 0)
-        paged = getattr(pool, "paged", False)
         while True:
-            if paged:
-                # paged admission (DESIGN.md §15): a slot AND enough
-                # arena pages for the request's worst-case growth; a
-                # prefix-cache hit adopts shared pages and resumes
-                # prefill past them — which is what makes a preempted
-                # request's resume re-prefill only its generated tail
-                adm = pool.admit(req.prefill_tokens, max_new)
-                if adm is not None:
-                    req.slot, req.prefill_pos, req.prefix_hit_tokens = adm
-                    break
-            elif pool.n_free:
-                req.slot = pool.alloc()
-                req.prefill_pos = 0
+            # a slot (paged pools, DESIGN.md §15: AND enough arena pages
+            # for the request's worst-case growth; a prefix-cache hit
+            # adopts shared pages and resumes prefill past them — which
+            # is what makes a preempted request's resume re-prefill only
+            # its generated tail)
+            adm = pool.admit(req.prefill_tokens, max_new)
+            if adm is not None:
+                req.slot, req.prefill_pos, req.prefix_hit_tokens = adm
                 break
             victim = self._pick_victim(req.tier, req.priority)
             if victim is None:
                 return False
             self._preempt(victim, reason="priority")
-        if paged and self._r_hits is not None and req.prefix_hit_tokens > 0:
+        if self._r_hits is not None and req.prefix_hit_tokens > 0:
             self._r_hits.inc(tier=req.tier)
             self._r_hit_tokens.inc(req.prefix_hit_tokens, tier=req.tier)
         req.state = RequestState.PREFILL
@@ -738,12 +732,11 @@ class Scheduler:
             req.prefill_pos = end
             if final:
                 req.state = RequestState.DECODE
-                if getattr(pool, "paged", False):
-                    # publish the committed whole pages to the prefix
-                    # cache — later requests (or this one, preempted
-                    # again) with the same token prefix adopt them
-                    # instead of re-prefilling
-                    pool.register_prefix(req.slot, req.prefill_tokens)
+                # publish the committed whole pages to the prefix cache
+                # (paged pools) — later requests (or this one, preempted
+                # again) with the same token prefix adopt them instead
+                # of re-prefilling
+                pool.register_prefix(req.slot, req.prefill_tokens)
                 if resumed:
                     # replay complete: KV now covers prompt +
                     # generated[:-1]; decode continues at the preserved
@@ -839,10 +832,9 @@ class Scheduler:
             for r in dec:
                 tokens[r.slot] = r.last_token
             self._key_schedule(dec, 1, keys, temps)
-            if getattr(pool, "paged", False):
-                # pin every active row's write position (fresh page at a
-                # page boundary) before the dispatch writes there
-                pool.ensure_decode([r.slot for r in dec], 1)
+            # pin every active row's write position (paged pools: a
+            # fresh page at a page boundary) before the dispatch writes
+            pool.ensure_decode([r.slot for r in dec], 1)
             try:
                 toks = self.engine.decode_slots(pool, tokens, keys[0], temps)
             except StepFault as f:
@@ -887,13 +879,12 @@ class Scheduler:
                 active[r.slot] = True
                 rem[r.slot] = r.sampling.max_new_tokens - r.n_generated
             self._key_schedule(dec, k, keys, temps)
-            if getattr(pool, "paged", False):
-                # pin the whole K-step write window per row, capped at each
-                # row's remaining budget — overshoot writes from rows that
-                # freeze mid-burst land in the garbage page via their
-                # unmapped table entries, not in allocated pages
-                pool.ensure_decode([r.slot for r in dec], k,
-                                   [int(rem[r.slot]) for r in dec])
+            # pin the whole K-step write window per row, capped at each
+            # row's remaining budget — on a paged pool, overshoot writes
+            # from rows that freeze mid-burst land in the garbage page via
+            # their unmapped table entries, not in allocated pages
+            pool.ensure_decode([r.slot for r in dec], k,
+                               [int(rem[r.slot]) for r in dec])
             try:
                 toks, valid = self.engine.decode_burst(
                     pool, tokens, keys, temps, active, rem, eos)
@@ -970,12 +961,11 @@ class Scheduler:
             window[slot, 0] = r.last_token
             window[slot, 1:] = drafts[:, slot]
             rems[slot] = r.sampling.max_new_tokens - r.n_generated
-        if getattr(pool, "paged", False):
-            # pin the S-wide verify window per row (planner capped K by
-            # each row's budget, so rem >= S and nothing lands in the
-            # garbage page on the accepted path)
-            pool.ensure_decode([slot for _, slot in rows], s,
-                               [int(rems[slot]) for _, slot in rows])
+        # pin the S-wide verify window per row (planner capped K by each
+        # row's budget, so rem >= S and, on a paged pool, nothing lands in
+        # the garbage page on the accepted path)
+        pool.ensure_decode([slot for _, slot in rows], s,
+                           [int(rems[slot]) for _, slot in rows])
         self._dispatch_seq += 1
         verify_dispatch = self._dispatch_seq
         with span("serve.verify", self.tracer, self._clock,

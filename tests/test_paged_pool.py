@@ -323,3 +323,62 @@ def test_paged_pool_accounting(cfg, engines):
     assert pool.pages_free == pool.n_pages - 1
     assert pool.bytes_per_token * pool.n_pages * pool.page_size \
         <= pool.cache_bytes
+
+
+@pytest.mark.parametrize("case", ["admit", "ensure", "ensure_decode",
+                                  "register_prefix", "double_free"])
+def test_slab_pool_write_path(engines, case):
+    """The slab's side of the one pool interface the scheduler drives:
+    admission hands out the lowest free slot with prefill from 0 and no
+    prefix hit, pinning and prefix publication touch nothing on the
+    device, the steps take no trailing operand, and a double free still
+    raises."""
+    slab, _ = engines
+    pool = slab.new_pool()
+    cache = pool.cache
+    assert pool.admit([5, 6, 7], 4) == (0, 0, 0)
+    assert pool.admit([5, 6, 7], 4) == (1, 0, 0)
+    if case == "admit":
+        pool.free(0)
+        assert pool.admit([9], 1) == (0, 0, 0)   # lowest free id, reused
+        assert pool.admit([9], 1) == (2, 0, 0)
+        assert pool.admit([9], 1) == (3, 0, 0)
+        assert pool.admit([9], 1) is None        # every slot checked out
+    elif case == "ensure":
+        pool.lengths[0] = 5
+        pool.ensure(0, 5 + 8)
+        assert pool.lengths[0] == 5
+    elif case == "ensure_decode":
+        pool.ensure_decode([0, 1], 4, [4, 2])
+    elif case == "register_prefix":
+        assert pool.register_prefix(0, [5, 6, 7]) == 0
+    else:
+        pool.free(1)
+        with pytest.raises(AssertionError):
+            pool.free(1)
+    assert pool.cache is cache                   # no device op ran
+    assert pool.step_operands() == () and pool.step_operands(0) == ()
+
+
+@pytest.mark.parametrize("layout", ["slab", "paged"])
+def test_decode_logits_step_bit_identical(cfg, engines, layout):
+    """``decode_slots_with_logits`` after the same prefill returns the
+    slab's logits bit for bit on either layout, and on each layout its
+    greedy pick is the fused decode step's token."""
+    def logits_after_prefill(engine):
+        pool = engine.new_pool()
+        prompts = _prompts(cfg, [8, 6, 10, 13], seed=12)
+        slots = [pool.admit(p, 4)[0] for p in prompts]
+        engine.prefill_into_slots(pool, slots, prompts)
+        tokens = np.zeros((pool.n_slots,), np.int32)
+        tokens[slots] = [p[-1] for p in prompts]
+        pool.ensure_decode(slots, 1)
+        logits = np.asarray(engine.decode_slots_with_logits(pool, tokens))
+        # lengths were not committed: the fused step rewrites the same rows
+        return logits, engine.decode_slots(pool, tokens)
+
+    want, _ = logits_after_prefill(engines[0])
+    got, toks = logits_after_prefill(engines[layout == "paged"])
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(got.argmax(-1), toks)
